@@ -21,7 +21,9 @@ if "xla_force_host_platform_device_count" not in _flags:
 os.environ.setdefault("HORAEDB_AGG_CALIB_N", "20000")
 
 import asyncio
+import faulthandler
 import functools
+import signal
 
 import pytest
 
@@ -30,6 +32,32 @@ import pytest
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+
+
+# pytest-timeout is not installed: one hung test must fail alone, with a
+# stack, instead of costing the whole run its time limit.
+TEST_WATCHDOG_S = 180
+
+
+@pytest.fixture(autouse=True)
+def _watchdog(request):
+    def on_alarm(signum, frame):
+        pytest.fail(
+            f"{request.node.nodeid} ran over {TEST_WATCHDOG_S} s (watchdog)",
+            pytrace=True,
+        )
+
+    # the stacks of every thread go to stderr just before the alarm fails
+    # the test from the main thread
+    faulthandler.dump_traceback_later(TEST_WATCHDOG_S - 1, exit=False)
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_WATCHDOG_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+        faulthandler.cancel_dump_traceback_later()
 
 
 def async_test(fn):

@@ -1,8 +1,7 @@
-"""The TaskGroup shim (horaedb_tpu/common/aio.py) honors the
-structured-concurrency contract the engine relies on — on Python 3.10
-this exercises the backport, on >= 3.11 the same assertions hold for
-the real asyncio.TaskGroup (the properties below are the shared
-subset both implement)."""
+"""The TaskGroup (horaedb_tpu/common/aio.py) honors the
+structured-concurrency contract the engine relies on: children are
+joined or reaped before the block exits, and a lone failure (child or
+body) re-raises the exception itself."""
 
 import asyncio
 import builtins

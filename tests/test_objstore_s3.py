@@ -221,8 +221,13 @@ class TestTimeouts:
 
         from horaedb_tpu.common.time_ext import ReadableDuration
 
+        release = asyncio.Event()
+
         async def swallow(reader, writer):
-            await asyncio.sleep(3600)  # never respond
+            try:
+                await release.wait()  # never respond while the store tries
+            finally:
+                writer.close()
 
         server = await asyncio.start_server(swallow, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -240,6 +245,9 @@ class TestTimeouts:
             assert time.perf_counter() - t0 < 10.0
         finally:
             await store.close()
+            # let the handlers go first: wait_closed() waits for every
+            # open connection
+            release.set()
             server.close()
             await server.wait_closed()
 
