@@ -1,6 +1,6 @@
 # Build / test / bench entry points (reference: Makefile targets fmt/clippy/test)
 
-.PHONY: test native bench baselines serve lint jaxlint typecheck smoke-metrics bench-smoke mem-smoke chaos-smoke cluster-smoke clean soak dryruns tpu-suite
+.PHONY: test native bench baselines serve lint jaxlint typecheck smoke-metrics bench-smoke mem-smoke chaos-smoke cluster-smoke clean soak dryruns
 
 test:
 	python -m pytest tests/ -x -q
@@ -22,7 +22,7 @@ serve:
 # (reference Makefile:37-53); ruff/mypy are not in the image, the linter
 # is stdlib. compileall still guards syntax across every file.
 lint:
-	python -m compileall -q horaedb_tpu tests benchmarks bench.py __graft_entry__.py
+	python -m compileall -q horaedb_tpu tests benchmarks bench.py chip_smoke.py __graft_entry__.py
 	python tools/lint.py
 	$(MAKE) jaxlint
 	$(MAKE) typecheck
@@ -115,9 +115,6 @@ dryruns:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	  python -c "import jax; jax.config.update('jax_platforms','cpu'); \
 	  import __graft_entry__ as g; g.dryrun_multichip(8)"
-
-tpu-suite:
-	bash benchmarks/run_tpu_suite.sh
 
 clean:
 	$(MAKE) -C horaedb_tpu/native clean

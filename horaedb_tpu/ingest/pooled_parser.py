@@ -77,27 +77,51 @@ POOL_WAIT_SECONDS = GLOBAL_METRICS.histogram(
 )
 
 
-def _new_backend():
-    """Backend chain: C++ parser -> protobuf-runtime PyParser -> hand-rolled
-    pure-Python WireParser (no native code, no protoc codegen; lacks the
-    hash lanes, so the engine takes its slow path). Native backends get a
-    DecodeArena so pooled parses reuse their scratch lane buffers."""
-    from horaedb_tpu.ingest import native
+_BACKEND: str | None = None
 
-    if native.load() is not None:
+
+def parser_backend() -> str:
+    """Which rung of the backend chain this process parses with, resolved
+    once and logged once: `native` (C++ parser) -> `protobuf` (protobuf
+    runtime PyParser) -> `wire` (hand-rolled pure-Python WireParser: no
+    native code, no protoc codegen; lacks the hash lanes, so the engine
+    takes its slow path). The server exposes it on
+    /api/v1/status/buildinfo."""
+    global _BACKEND
+    if _BACKEND is None:
+        from horaedb_tpu.ingest import native
+
+        if native.load() is not None:
+            _BACKEND = "native"
+        else:
+            try:
+                import horaedb_tpu.ingest.py_parser  # noqa: F401
+
+                _BACKEND = "protobuf"
+            except ImportError:
+                _BACKEND = "wire"
+        log = logger.info if _BACKEND == "native" else logger.warning
+        log("remote-write parser backend: %s", _BACKEND)
+    return _BACKEND
+
+
+def _new_backend():
+    """One parser of the chosen backend. The native one gets a DecodeArena
+    so pooled parses reuse their scratch lane buffers."""
+    backend = parser_backend()
+    if backend == "native":
+        from horaedb_tpu.ingest import native
+
         p = native.NativeParser()
         p.arena = DecodeArena()
         return p
-    try:
+    if backend == "protobuf":
         from horaedb_tpu.ingest.py_parser import PyParser
 
-        logger.warning("native remote-write parser unavailable; using protobuf runtime")
         return PyParser()
-    except ImportError:
-        from horaedb_tpu.ingest.wire_parser import WireParser
+    from horaedb_tpu.ingest.wire_parser import WireParser
 
-        logger.warning("protobuf runtime unavailable; using pure-Python wire decoder")
-        return WireParser()
+    return WireParser()
 
 
 class ParserPool:

@@ -19,8 +19,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from horaedb_tpu.common.xprof import xjit
+from horaedb_tpu.ops.sort import f64_order_i64
 
 
 def _masked_index(index: jax.Array, valid: jax.Array, num_segments: int) -> jax.Array:
@@ -146,6 +148,46 @@ def bucket_of(ts: jax.Array, t0, bucket_ms) -> jax.Array:
     return ((ts - t0) // bucket_ms).astype(jnp.int32)
 
 
+_I64_MAX = np.iinfo(np.int64).max
+_I64_MIN = np.iinfo(np.int64).min
+# NaN rows: smallest key on the min lane, largest on the max lane, so a NaN
+# in a cell wins both reductions exactly as it propagates through float
+# min/max; one step inside the empty-cell fills of segment_min/segment_max
+_NAN_LOW, _NAN_HIGH = _I64_MIN + 1, _I64_MAX - 1
+
+
+def device_f64_is_exact() -> bool:
+    """Only the CPU backend holds f64 as f64; see `f64_order_keys`."""
+    return jax.devices()[0].platform == "cpu"
+
+
+def f64_order_keys(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Host side of the exact device min/max: (min-lane, max-lane) i64 keys
+    whose signed order is the f64 total order of `values`. An accelerator
+    holds 64-bit integers exactly but emulates f64 as a pair of f32 (about
+    48 mantissa bits, f32's exponent range: measured on a TPU v5e, PR 25),
+    so a selection that must return a stored sample bit for bit is reduced
+    over these keys and mapped back by `f64_from_order_keys`. The two lanes
+    are one array unless the block holds a NaN."""
+    keys = f64_order_i64(np.asarray(values))
+    nan = np.isnan(values)
+    if not nan.any():
+        return keys, keys
+    return np.where(nan, _NAN_LOW, keys), np.where(nan, _NAN_HIGH, keys)
+
+
+def f64_from_order_keys(keys: np.ndarray) -> np.ndarray:
+    """Inverse of `f64_order_keys` over reduced cells: empty cells (the
+    integer reduction's fill) read +inf on the min lane and -inf on the max
+    lane, NaN markers read NaN."""
+    keys = np.asarray(keys, dtype=np.int64)
+    out = (keys ^ ((keys >> 63) & _I64_MAX)).view(np.float64)  # self-inverse
+    out[(keys == _NAN_LOW) | (keys == _NAN_HIGH)] = np.nan
+    out[keys == _I64_MAX] = np.inf
+    out[keys == _I64_MIN] = -np.inf
+    return out
+
+
 def downsample_sorted(
     ts,
     series_idx,
@@ -186,7 +228,7 @@ def downsample_sorted(
     # reductions below — re-resolving per reduction would triple-count
     # horaedb_agg_impl_total and re-read env/cache on the scan hot path
     choice: str | None = None
-    if not traced and jax.devices()[0].platform == "cpu":
+    if not traced and device_f64_is_exact():
         from horaedb_tpu.ops import agg_registry
 
         choice = agg_registry.choose_sorted(
@@ -209,6 +251,12 @@ def downsample_sorted(
         if not with_minmax:
             out = {k: out[k] for k in ("sum", "count", "mean")}
         return out
+    order_keys = None
+    if (
+        with_minmax and isinstance(values, np.ndarray)
+        and values.dtype == np.float64 and not device_f64_is_exact()
+    ):
+        order_keys = f64_order_keys(values)
     ts = jnp.asarray(ts)
     series_idx = jnp.asarray(series_idx)
     values = jnp.asarray(values)
@@ -235,9 +283,23 @@ def downsample_sorted(
     if with_minmax:
         from horaedb_tpu.ops.blockagg import sorted_segment_min_max
 
-        mn, mx = sorted_segment_min_max(
-            safe, values, num_cells, impl=choice, valid=ok
-        )
+        if order_keys is not None:
+            # exact selection on an accelerator: reduce the i64 order keys
+            # (64-bit integers are exact there, f64 is not) and map the
+            # winners back to their f64 bit patterns on the host
+            kmin, kmax = order_keys
+            mn, mx = sorted_segment_min_max(
+                safe, kmin, num_cells, impl=choice, valid=ok
+            )
+            if kmax is not kmin:  # the block holds a NaN: its own max lane
+                mx = sorted_segment_min_max(
+                    safe, kmax, num_cells, impl=choice, valid=ok
+                )[1]
+            mn, mx = f64_from_order_keys(mn), f64_from_order_keys(mx)
+        else:
+            mn, mx = sorted_segment_min_max(
+                safe, values, num_cells, impl=choice, valid=ok
+            )
         out["min"] = mn.reshape(shape)
         out["max"] = mx.reshape(shape)
     return out
@@ -289,6 +351,7 @@ def stacked_downsample(
     bucket_ms,
     num_series: int,
     num_buckets: int,
+    order_keys=None,
 ) -> dict[str, jax.Array]:
     """Downsample grids for a STACK of coalesced queries in one launch —
     the query batcher's device lane (server/batching.py): inputs carry a
@@ -309,7 +372,11 @@ def stacked_downsample(
     shared across launches and retraces stay caught by xprof.
 
     Accumulation dtype follows the inputs (f64 on the x64 CPU path, the
-    engine's precision contract — see SampleManager.query_downsample)."""
+    engine's precision contract — see SampleManager.query_downsample).
+
+    `order_keys` (optional, the [B, R] i64 lanes of `f64_order_keys`): the
+    accelerator's exact min/max. "min"/"max" then come back as i64 keys for
+    `f64_from_order_keys`; the f64 value lane feeds sum and count only."""
     nb, cells = t0.shape[0], num_series * num_buckets
     bucket = ((ts - t0[:, None]) // bucket_ms).astype(jnp.int32)
     ok = (
@@ -320,9 +387,15 @@ def stacked_downsample(
     safe = jnp.clip(series_idx, 0, num_series - 1) * num_buckets \
         + jnp.clip(bucket, 0, num_buckets - 1)
     flat = jnp.where(ok, lane * cells + safe, nb * cells)
+    flat, ok = flat.reshape(-1), ok.reshape(-1)
     s, c, mn, mx = masked_segment_stats(
-        values.reshape(-1), flat.reshape(-1), ok.reshape(-1), nb * cells
+        values.reshape(-1), flat, ok, nb * cells, with_minmax=order_keys is None
     )
+    if order_keys is not None:
+        # masked rows already route to the sentinel cell, which is sliced off
+        kmin, kmax = order_keys
+        mn = jax.ops.segment_min(kmin.reshape(-1), flat, nb * cells + 1)[:-1]
+        mx = jax.ops.segment_max(kmax.reshape(-1), flat, nb * cells + 1)[:-1]
     shape = (nb, num_series, num_buckets)
     s, c = s.reshape(shape), c.reshape(shape)
     return {"sum": s, "count": c, "min": mn.reshape(shape),

@@ -2,12 +2,16 @@
 
 Replaces the reference's per-batch DataFusion SortExec (storage.rs:244-256)
 and the sorted-merge ordering contract (pk asc, then __seq__ asc,
-read.rs:412-427). XLA's sort is a single fused kernel over the whole block —
-the O(n log n) the reference pays per batch on CPU runs at vector width here.
+read.rs:412-427).
 
-`jnp.lexsort` treats the LAST key as primary, so callers pass keys
-most-significant-first and we reverse internally. All sorts are stable, which
-preserves the seq tie-break invariant when seq is included as the least
+Every device sort here is a chain of SINGLE-key stable sorts over u64 lanes
+(least-significant key first, the LSD construction): the TPU compiler takes
+about half a minute for one single-key sort past 16 K rows, compiles the
+identical passes of one program once, and does not finish a variadic sort
+over five 64-bit keys in a quarter of an hour (compile rehearsal,
+tests/test_tpu_compile.py). Keys are mapped to u64 in an order-preserving
+way first, so every pass is the same computation. All passes are stable,
+which preserves the seq tie-break invariant when seq is the least
 significant key.
 """
 
@@ -15,25 +19,80 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from horaedb_tpu.common.xprof import xjit
 
+_SIGN64 = np.uint64(1 << 63)
+# smallest padded length of a host-called sort: row counts share compiled
+# programs by power-of-two class
+_MIN_SORT_ROWS = 1024
 
-@xjit(kernel="sort_perm", static_argnames=("num_keys",))
-def _sort_perm(keys: tuple[jax.Array, ...], num_keys: int) -> jax.Array:
-    # ONE variadic lax.sort with an iota payload: lax.sort is directly
-    # lexicographic over the first num_keys operands, so the permutation
-    # falls out of a single fused sort (lexsort would run one sort pass per
-    # key). is_stable preserves the seq tie-break contract.
+
+def pow2_rows(n: int, floor: int = _MIN_SORT_ROWS) -> int:
+    """The power-of-two length class a device sort of n rows is padded to
+    (one compiled sort per class, not per row count)."""
+    return max(floor, 1 << max(0, int(n) - 1).bit_length())
+
+
+def f64_order_i64(values):
+    """f64 -> i64 whose signed order is the f64 total order (-0.0 before
+    0.0): negative floats order by descending magnitude, so their magnitude
+    bits flip. numpy in, numpy out; traced in, traced out."""
+    xp = np if isinstance(values, np.ndarray) else jnp
+    bits = values.astype(xp.float64).view(xp.int64)
+    return bits ^ ((bits >> 63) & xp.int64((1 << 63) - 1))
+
+
+def order_u64(key):
+    """An order-preserving u64 view of an integer or bool key lane (numpy
+    in, numpy out; traced in, traced out): unsigned widen, signed flip the
+    sign bit, floats through `f64_order_i64`."""
+    xp = np if isinstance(key, np.ndarray) else jnp
+    dt = key.dtype
+    if dt == xp.uint64:
+        return key
+    if dt == xp.bool_ or xp.issubdtype(dt, xp.unsignedinteger):
+        return key.astype(xp.uint64)
+    if xp.issubdtype(dt, xp.floating):
+        key = f64_order_i64(key)
+    return key.astype(xp.int64).view(xp.uint64) ^ _SIGN64
+
+
+def lexsort_perm(keys) -> jax.Array:
+    """Stable permutation ordering rows by `keys` (most-significant first),
+    as single-key passes from the least significant key up. Traceable."""
     n = keys[0].shape[0]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    out = jax.lax.sort((*keys, iota), num_keys=num_keys, is_stable=True)
-    return out[-1]
+    perm = jnp.arange(n, dtype=jnp.int32)
+    for key in reversed(list(keys)):
+        lane = jnp.take(order_u64(key), perm)
+        perm = jax.lax.sort((lane, perm), num_keys=1, is_stable=True)[1]
+    return perm
 
 
-def sort_permutation(keys: list[jax.Array]) -> jax.Array:
-    """Stable permutation ordering rows by `keys` (most-significant first)."""
-    return _sort_perm(tuple(keys), len(keys))
+@xjit(kernel="sort_perm")
+def _sort_perm(keys: tuple[jax.Array, ...]) -> jax.Array:
+    return lexsort_perm(keys)
+
+
+def sort_permutation(keys: list) -> jax.Array:
+    """Stable permutation ordering rows by `keys` (most-significant first).
+
+    Rows pad to their power-of-two class with all-ones keys: pads tie with
+    nothing smaller, start behind every real row and every pass is stable,
+    so they stay at the tail and the first n entries are the answer."""
+    n = int(keys[0].shape[0])
+    padded = pow2_rows(n)
+    lanes = []
+    for key in keys:
+        lane = order_u64(key if isinstance(key, np.ndarray) else jnp.asarray(key))
+        if padded != n:
+            xp = np if isinstance(lane, np.ndarray) else jnp
+            lane = xp.concatenate(
+                [lane, xp.full(padded - n, np.iinfo(np.uint64).max, dtype=xp.uint64)]
+            )
+        lanes.append(lane)
+    return _sort_perm(tuple(lanes))[:n]
 
 
 def apply_permutation(columns: dict[str, jax.Array], perm: jax.Array) -> dict[str, jax.Array]:

@@ -578,6 +578,12 @@ class QueryBatcher:
         val_b = np.zeros((bpad, rpad), dtype=np.float64)
         ok_b = np.zeros((bpad, rpad), dtype=bool)
         t0_b = np.zeros((bpad,), dtype=np.int64)
+        # exact min/max off the CPU: the accelerator's f64 is emulated and
+        # lossy, its i64 is not (ops/aggregate.py f64_order_keys)
+        keys_b = (
+            None if agg_ops.device_f64_is_exact()
+            else np.zeros((2, bpad, rpad), dtype=np.int64)  # min lane, max lane
+        )
         rows = 0
         for j, i in enumerate(stack_idx):
             ts, sid, vals = lanes[i]
@@ -588,13 +594,21 @@ class QueryBatcher:
             val_b[j, :n] = vals
             ok_b[j, :n] = True
             t0_b[j] = group.t0s[i]
+            if keys_b is not None:
+                keys_b[0, j, :n], keys_b[1, j, :n] = agg_ops.f64_order_keys(
+                    np.asarray(vals, dtype=np.float64)
+                )
         waste = 1.0 - rows / float(bpad * rpad)
         with scanstats.stage("device_agg"):
             out = agg_ops.stacked_downsample(
                 ts_b, sid_b, val_b, ok_b, t0_b, group.bucket_ms,
                 num_series=spad, num_buckets=nb,
+                order_keys=None if keys_b is None else tuple(keys_b),
             )
         grids = {k: np.asarray(v) for k, v in out.items()}
+        if keys_b is not None:
+            grids["min"] = agg_ops.f64_from_order_keys(grids["min"])
+            grids["max"] = agg_ops.f64_from_order_keys(grids["max"])
         BATCH_LAUNCHES.inc()
         BATCH_GROUP_SIZE.observe(bsz)
         BATCH_PAD_WASTE.observe(waste)

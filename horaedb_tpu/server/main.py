@@ -50,7 +50,7 @@ import pyarrow as pa
 from aiohttp import web
 
 from horaedb_tpu.common import deadline as deadline_ctx
-from horaedb_tpu.common import memtrace, tracing, xprof
+from horaedb_tpu.common import compile_cache, memtrace, tracing, xprof
 from horaedb_tpu.common.bytebudget import GLOBAL_POOLS, rss_bytes
 from horaedb_tpu.common.error import (
     DeadlineExceeded,
@@ -60,6 +60,7 @@ from horaedb_tpu.common.error import (
 from horaedb_tpu.common.time_ext import now_ms
 from horaedb_tpu.engine import MetricEngine, QueryRequest
 from horaedb_tpu.ingest import ParserPool
+from horaedb_tpu.ingest.pooled_parser import parser_backend
 from horaedb_tpu.ingest.cardinality import CardinalityLimited
 from horaedb_tpu.objstore import LocalStore
 from horaedb_tpu.objstore.resilient import ResilientStore
@@ -1802,14 +1803,15 @@ async def handle_debug_kernels(request: web.Request) -> web.Response:
     where the backend supports cost/memory analysis — the predicted
     FLOPs/bytes envelope with its arithmetic intensity. The static half of
     the roofline story; /metrics' stage histograms are the measured half."""
-    try:
-        import jax
+    import jax
 
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — catalog must render without a backend
-        backend = None
+    devices = jax.devices()
     return web.json_response({
-        "backend": backend,
+        "backend": jax.default_backend(),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
         "totals": xprof.snapshot(),
         "kernels": xprof.catalog(),
     })
@@ -1865,7 +1867,12 @@ async def handle_buildinfo(request: web.Request) -> web.Response:
     """Minimal Prometheus buildinfo (datasource health checks probe it)."""
     return web.json_response({
         "status": "success",
-        "data": {"version": "2.45.0", "application": "horaedb-tpu"},
+        "data": {
+            "version": "2.45.0", "application": "horaedb-tpu",
+            # which rung of the ingest parser chain this process took
+            # (ingest/pooled_parser.py): native | protobuf | wire
+            "parser_backend": parser_backend(),
+        },
     })
 
 
@@ -2625,6 +2632,7 @@ async def build_app(config: Config, store=None) -> web.Application:
     # one shared parser pool: the /metrics pool telemetry must reflect the
     # pool the engine's ingest actually borrows from
     pool = ParserPool()
+    parser_backend()  # resolve (and log) the parser chain's rung at start
     engine_kwargs = dict(
         segment_duration_ms=segment_ms,
         config=config.metric_engine.storage.time_merge_storage,
@@ -2979,9 +2987,8 @@ async def build_app(config: Config, store=None) -> web.Application:
 
 def main() -> None:
     init_logging()
-    # Escape hatch for CPU-only deployments and CI: force the jax platform
-    # BEFORE the backend initializes (some images pre-register an accelerator
-    # platform that wins over JAX_PLATFORMS).
+    # Escape hatch for CPU-only deployments and CI: pick the jax platform
+    # BEFORE the backend initializes (JAX_PLATFORMS does the same).
     import os
 
     platform = os.environ.get("HORAEDB_JAX_PLATFORM")
@@ -2989,11 +2996,13 @@ def main() -> None:
         import jax
 
         jax.config.update("jax_platforms", platform)
+    cache_dir = compile_cache.enable()
     ap = argparse.ArgumentParser(description="horaedb-tpu server")
     ap.add_argument("--config", help="toml config path")
     args = ap.parse_args()
     config = Config.from_file(args.config) if args.config else Config()
     logger.info("starting horaedb-tpu server on 127.0.0.1:%d", config.port)
+    logger.info("compile cache: %s", cache_dir)
 
     async def run():
         app = await build_app(config)

@@ -9,9 +9,9 @@ storage.rs:58-89) and the object layout is identical:
     {root}/data/{id}.sst              sorted parquet SSTs
 
 Execution is TPU-shaped instead of DataFusion-shaped:
-- write: per-batch primary-key sort runs as one XLA lexsort on device
-  (replacing MemoryExec->SortExec, storage.rs:244-256), then parquet encode
-  on host with sorting-columns metadata;
+- write: per-batch primary-key sort runs on device as single-key passes
+  (ops/sort.py; replacing MemoryExec->SortExec, storage.rs:244-256), then
+  parquet encode on host with sorting-columns metadata;
 - scan: per-segment fused device pipeline (storage/read.py), segments
   unioned old->new (storage.rs:343-369);
 - every write is one new sorted SST — no WAL, no memtable; the SST write is
@@ -94,10 +94,7 @@ ORPHAN_SSTS_GC = GLOBAL_METRICS.counter(
 def jax_backend_is_cpu() -> bool:
     import jax
 
-    try:
-        return jax.default_backend() == "cpu"
-    except Exception:  # noqa: BLE001 — no backend at all: treat as host
-        return True
+    return jax.default_backend() == "cpu"
 
 
 def _is_pk_sorted(keys: list[np.ndarray]) -> bool:
@@ -592,8 +589,8 @@ class ObjectBasedStorage(ColumnarStorage):
     def _sort_batch(self, batch: pa.RecordBatch) -> pa.RecordBatch:
         """Primary-key sort on device (replaces SortExec, storage.rs:244-256).
 
-        The permutation is computed over the numeric pk lanes with one XLA
-        lexsort; the gather applies to all columns via pyarrow take so binary
+        The permutation is computed over the numeric pk lanes on device
+        (ops/sort.py); the gather applies to all columns via pyarrow take so binary
         payloads never touch the device. Schemas with binary primary keys
         sort on host via arrow compute (the device path needs numeric lanes).
         """

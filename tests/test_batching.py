@@ -177,6 +177,41 @@ class TestStackedKernelProperty:
                 ), (B, rpad, S, T, q, "mean")
 
 
+    @pytest.mark.parametrize("with_nan", [False, True])
+    def test_order_key_lane_gives_the_same_minmax_bit_for_bit(self, with_nan):
+        """The accelerator's exact min/max (i64 order keys riding next to
+        the f64 value lane) equals the float reduction: empty cells, masked
+        rows and NaN cells included."""
+        from horaedb_tpu.ops import aggregate as agg
+
+        rng = np.random.default_rng(7)
+        B, R, S, T = 3, 64, 4, 5
+        ts_b = rng.integers(0, T * 1000, (B, R)).astype(np.int64)
+        sid_b = rng.integers(0, S - 1, (B, R)).astype(np.int32)  # last series empty
+        val_b = rng.uniform(-50, 50, (B, R))
+        if with_nan:
+            val_b[:, ::9] = np.nan
+        ok_b = rng.random((B, R)) < 0.8
+        t0_b = np.zeros((B,), np.int64)
+        want = agg.stacked_downsample(
+            ts_b, sid_b, val_b, ok_b, t0_b, 1000, num_series=S, num_buckets=T)
+        kmin = np.zeros((B, R), np.int64)
+        kmax = np.zeros((B, R), np.int64)
+        for j in range(B):
+            kmin[j], kmax[j] = agg.f64_order_keys(val_b[j])
+        got = agg.stacked_downsample(
+            ts_b, sid_b, val_b, ok_b, t0_b, 1000, num_series=S, num_buckets=T,
+            order_keys=(kmin, kmax))
+        for k in ("min", "max"):
+            assert np.asarray(got[k]).dtype == np.int64
+            np.testing.assert_array_equal(
+                np.nan_to_num(agg.f64_from_order_keys(np.asarray(got[k])), nan=1e300),
+                np.nan_to_num(np.asarray(want[k]), nan=1e300),
+            )
+        for k in ("sum", "count"):
+            np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
+
+
 class TestEngineParity:
     """Engine-level property test: a concurrent burst of compatible
     panels coalesces (batched_with > 1) and every answer equals the

@@ -71,6 +71,48 @@ class TestSort:
         np.testing.assert_array_equal(np.asarray(out["p"]), [1, 3, 0, 2, 4])
 
 
+    @pytest.mark.parametrize("n", [1, 7, 1000, 5000])
+    @pytest.mark.parametrize("on_device", [False, True])
+    def test_sort_permutation_single_key_passes_match_lexsort(self, n, on_device):
+        """The device sort is a chain of single-key u64 passes (ops/sort.py):
+        u64 ids past 2^63, negative i64, floats and bools must order exactly
+        as numpy's lexsort, with padding rows kept out of the answer."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(n)
+        keys = [
+            rng.integers(0, 5, n).astype(np.uint64) * np.uint64(1 << 62),
+            rng.integers(-3, 3, n).astype(np.int64) * (1 << 61),
+            np.round(rng.normal(size=n), 1) + 0.0,  # no -0.0: numpy ties it with 0.0
+            rng.integers(0, 2, n).astype(bool),
+        ]
+        lanes = [jnp.asarray(k) for k in keys] if on_device else keys
+        perm = np.asarray(sort.sort_permutation(lanes))
+        assert perm.shape == (n,) and sorted(perm) == list(range(n))
+        np.testing.assert_array_equal(perm, np.lexsort(tuple(reversed(keys))))
+
+    def test_pow2_rows_classes(self):
+        assert [sort.pow2_rows(n) for n in (0, 1, 1024, 1025, 10_000)] == \
+            [1024, 1024, 1024, 2048, 16384]
+        assert sort.pow2_rows(10_000, 8192) == 16384
+        assert sort.pow2_rows(100, 8192) == 8192
+
+    @pytest.mark.parametrize("arr", [
+        np.array([0, 1, (1 << 63) - 1, 1 << 63, (1 << 64) - 1], dtype=np.uint64),
+        np.array([-(1 << 63), -1, 0, 1, (1 << 63) - 1], dtype=np.int64),
+        np.array([-5, 0, 7], dtype=np.int32),
+        np.array([False, True]),
+        np.array([-np.inf, -1.5, -0.0, 0.0, 1e-300, 2.0, np.inf]),
+    ], ids=["u64", "i64", "i32", "bool", "f64"])
+    @pytest.mark.parametrize("traced", [False, True])
+    def test_order_u64_is_strictly_monotone(self, arr, traced):
+        import jax.numpy as jnp
+
+        out = np.asarray(sort.order_u64(jnp.asarray(arr) if traced else arr))
+        assert out.dtype == np.uint64
+        assert np.all(out[:-1] < out[1:]), arr.dtype
+
+
 class TestFilter:
     def test_compare_and_bool_algebra(self):
         rng = np.random.default_rng(2)
@@ -330,6 +372,53 @@ class TestAggregate:
             np.testing.assert_allclose(
                 np.asarray(got[k]), np.asarray(expect[k]), rtol=1e-4, atol=1e-4
             )
+
+    def test_f64_order_keys_roundtrip_bit_for_bit(self):
+        vals = np.array([-np.inf, -1e300, -2.5, -0.0, 0.0, 1e-300, 3.0,
+                         99.99967667212489, 1e300, np.inf])
+        kmin, kmax = aggregate.f64_order_keys(vals)
+        assert kmin is kmax and kmin.dtype == np.int64
+        assert np.all(np.diff(kmin) > 0)  # the f64 total order, strictly
+        back = aggregate.f64_from_order_keys(kmin)
+        np.testing.assert_array_equal(back.view(np.int64), vals.view(np.int64))
+
+    def test_f64_order_keys_nan_wins_both_lanes_and_fills_read_inf(self):
+        vals = np.array([1.0, np.nan, -7.0])
+        kmin, kmax = aggregate.f64_order_keys(vals)
+        assert kmin is not kmax
+        assert np.isnan(aggregate.f64_from_order_keys(np.array([kmin.min()])))[0]
+        assert np.isnan(aggregate.f64_from_order_keys(np.array([kmax.max()])))[0]
+        i64 = np.iinfo(np.int64)
+        fills = aggregate.f64_from_order_keys(np.array([i64.max, i64.min]))
+        assert fills[0] == np.inf and fills[1] == -np.inf
+
+    @pytest.mark.parametrize("with_nan", [False, True])
+    def test_downsample_sorted_accelerator_minmax_lane_is_exact(
+        self, monkeypatch, with_nan
+    ):
+        """Off the CPU, min/max reduce i64 order keys (f64 is emulated and
+        lossy there): same grids, bit for bit, as the float reduction,
+        empty cells and NaN cells included."""
+        import types
+
+        rng = np.random.default_rng(5)
+        n, ns, nb = 4000, 8, 6
+        sid = np.sort(rng.integers(0, ns - 1, n)).astype(np.int32)  # last series empty
+        ts = np.concatenate([
+            np.sort(rng.integers(0, nb * 1000, (sid == s).sum())) for s in range(ns)
+        ]).astype(np.int64)
+        vals = rng.uniform(-100, 100, n)
+        if with_nan:
+            vals[::97] = np.nan
+        kw = dict(num_series=ns, num_buckets=nb, with_minmax=True)
+        want = aggregate.downsample_sorted(ts, sid, vals, 0, 1000, **kw)
+        fake = types.SimpleNamespace(platform="tpu")
+        monkeypatch.setattr(aggregate.jax, "devices", lambda *a: [fake])
+        got = aggregate.downsample_sorted(ts, sid, vals, 0, 1000, **kw)
+        for stat in ("min", "max"):  # NaN cells compare equal in place
+            np.testing.assert_array_equal(
+                np.asarray(got[stat]), np.asarray(want[stat]))
+        np.testing.assert_allclose(np.asarray(got["sum"]), np.asarray(want["sum"]))
 
     def test_segment_last_value(self):
         vals = np.array([1.0, 2.0, 3.0, 4.0])

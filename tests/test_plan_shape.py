@@ -35,12 +35,16 @@ def lower_scan_kernel(template=None, do_dedup=True, n=1024):
 
 
 class TestScanKernelPlanShape:
-    def test_contains_one_fused_sort_and_no_scatter(self):
-        """The scan is a sort-based merge: exactly one sort over the block,
-        and NO scatter ops (scatters are the serial op the design avoids on
-        the scan path)."""
+    def test_one_single_key_sort_per_key_lane_and_no_scatter(self):
+        """The scan is a sort-based merge: one single-key stable pass per
+        key lane (mask, pk, seq: the construction the TPU compiler can
+        afford, ops/sort.py), every pass the same u64 computation, and NO
+        scatter ops (scatters are the serial op the design avoids on the
+        scan path)."""
         hlo = lower_scan_kernel()
-        assert hlo.count("stablehlo.sort") == 1, hlo.count("stablehlo.sort")
+        assert hlo.count("stablehlo.sort") == 3, hlo.count("stablehlo.sort")
+        # each pass sorts (u64 key, i32 permutation) and nothing wider
+        assert hlo.count("(tensor<1024xui64>, tensor<1024xi32>)") >= 3
         assert "stablehlo.scatter" not in hlo
         # dedup mask algebra compiles to compares/selects, not loops
         assert "while" not in hlo
@@ -48,7 +52,7 @@ class TestScanKernelPlanShape:
     def test_predicate_fuses_into_the_same_module(self):
         template, _ = filter_ops.split_literals(filter_ops.Compare("value", "gt", 0.0))
         hlo = lower_scan_kernel(template=template)
-        assert hlo.count("stablehlo.sort") == 1
+        assert hlo.count("stablehlo.sort") == 3
         assert "stablehlo.compare" in hlo
         assert "stablehlo.scatter" not in hlo
 

@@ -695,7 +695,9 @@ class TestDebugKernels:
     @async_test
     async def test_kernel_catalog_served(self, tmp_path):
         """/debug/kernels lists the instrumented kernels with compile
-        telemetry; the import graph alone registers the ops/ kernels."""
+        telemetry; importing the ops/ modules alone registers them."""
+        import horaedb_tpu.ops.blockagg  # noqa: F401 — registers at import
+
         client = await make_client(tmp_path)
         try:
             r = await client.get("/debug/kernels")
@@ -711,6 +713,31 @@ class TestDebugKernels:
             for entry in body["kernels"]:
                 assert {"kernel", "compiles", "cache_entries",
                         "compile_seconds"} <= set(entry)
+        finally:
+            await client.close()
+
+
+    @async_test
+    async def test_device_and_parser_are_reported(self, tmp_path):
+        """/debug/kernels names the device as JAX reports it (no `except`:
+        a process without a backend must fail, not answer null) and the
+        compile-cache directory; buildinfo names the rung of the ingest
+        parser chain the process took. chip_smoke.py reads both."""
+        import jax
+
+        from horaedb_tpu.ingest.pooled_parser import parser_backend
+
+        client = await make_client(tmp_path)
+        try:
+            body = await (await client.get("/debug/kernels")).json()
+            dev = jax.devices()[0]
+            assert body["platform"] == dev.platform == "cpu"
+            assert body["device_kind"] == dev.device_kind
+            assert body["device_count"] == len(jax.devices())
+            assert "compile_cache_dir" in body
+            info = await (await client.get("/api/v1/status/buildinfo")).json()
+            assert info["data"]["parser_backend"] == parser_backend()
+            assert parser_backend() in ("native", "protobuf", "wire")
         finally:
             await client.close()
 

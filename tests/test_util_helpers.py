@@ -57,3 +57,15 @@ class TestStreams:
             [record_batch(pk=("i64", [1, 2, 3]), v=("f64", [1.0, 2.0, 3.0]))],
         )
         await eng.close()
+
+
+def test_every_test_runs_under_the_watchdog():
+    """tests/conftest.py arms a SIGALRM watchdog around each test (no
+    pytest-timeout in the image): one hung test fails alone, with a stack."""
+    import signal
+
+    from tests import conftest
+
+    remaining, _ = signal.getitimer(signal.ITIMER_REAL)
+    assert 0 < remaining <= conftest.TEST_WATCHDOG_S
+    assert signal.getsignal(signal.SIGALRM) not in (signal.SIG_DFL, signal.SIG_IGN, None)

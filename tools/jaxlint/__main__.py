@@ -36,7 +36,7 @@ DEFAULT_ROOTS = [
     # tests/ are deliberately out of the default roots: test corpora seed
     # the very defects this gate rejects (tests/test_jaxlint.py)
     "horaedb_tpu", "benchmarks", "tools",
-    "bench.py", "__graft_entry__.py",
+    "bench.py", "chip_smoke.py", "__graft_entry__.py",
 ]
 HYGIENE_CODES = {"J000", "J021", "J999"}  # never suppressible
 

@@ -228,7 +228,7 @@ def lint_file(path: Path) -> list[str]:
 def main() -> None:
     roots = sys.argv[1:] or [
         "horaedb_tpu", "tests", "benchmarks", "tools",
-        "bench.py", "__graft_entry__.py",
+        "bench.py", "chip_smoke.py", "__graft_entry__.py",
     ]
     files = iter_py_files(roots)
     all_findings: list[str] = []
