@@ -11,7 +11,8 @@ and data directory. The parent
    cpu_* fields, one sample per series every 10 s for --hours) and sends it
    as remote-write requests in time order to /api/v1/write;
 3. asks three PromQL queries with ?explain=1 and compares each answer with
-   a plain numpy float64 computation on the generated arrays;
+   a plain numpy float64 computation on the generated arrays; then the same
+   of one more series whose samples (1e300, 1e-300) no f32 exponent holds;
 4. calls /compact, waits for it, and repeats the downsample;
 5. reads /debug/kernels and /metrics and requires the chip to have done
    the work;
@@ -67,6 +68,11 @@ BASE_MS = 1_767_225_600_000  # 2026-01-01T00:00:00Z: aligned to any segment
 # tests/test_promql.py holds the pushdown grid to the raw path at this
 # tolerance; max and last value are selections and must be exact
 MEAN_RTOL, MEAN_ATOL = 1e-9, 1e-12
+# the first query of each shape pays its cold compiles inside the longest
+# deadline the server allows (docs/example.toml max_timeout)
+FIRST_TIMEOUT_S = 300
+# the whole run's own limit: it fails itself before the driver's 1,200 s
+BUDGET_S = 1100
 AGG_KERNELS = {
     "downsample", "stacked_downsample", "block_sum_count", "block_min_max",
     "scatter_fused", "lane_sum_count", "grouped_stats", "segment_last_value",
@@ -145,14 +151,14 @@ def _label(name: str, value: str) -> bytes:
 
 
 class RequestTemplate:
-    """The wire bytes of one WriteRequest of `k` scrape rounds, built once;
-    a request then only overwrites the sample values and timestamps in
+    """The wire bytes of one WriteRequest of one scrape round, built once;
+    a request then only overwrites the sample values and the timestamp in
     place (prometheus remote.proto: WriteRequest.timeseries=1,
     TimeSeries.labels=1/.samples=2, Sample.value=1 (double)/.timestamp=2)."""
 
     TS_VARINT = len(_varint(BASE_MS))
 
-    def __init__(self, host_tags: list[dict], k: int):
+    def __init__(self, host_tags: list[dict]):
         sample_len = 1 + 8 + 1 + self.TS_VARINT
         sample_field = 1 + 1 + sample_len  # tag, len, message
         buf = bytearray()
@@ -161,31 +167,25 @@ class RequestTemplate:
             tag_bytes = b"".join(_label(n, v) for n, v in sorted(tags.items()))
             for field in CPU_FIELDS:
                 labels = _label("__name__", f"cpu_{field}") + tag_bytes
-                buf += b"\x0a" + _varint(len(labels) + k * sample_field)
+                buf += b"\x0a" + _varint(len(labels) + sample_field)
                 buf += labels
-                for _ in range(k):
-                    buf += b"\x12" + bytes([sample_len]) + b"\x09"
-                    val_off.append(len(buf))
-                    buf += bytes(8) + b"\x10"
-                    ts_off.append(len(buf))
-                    buf += bytes(self.TS_VARINT)
-        self.k = k
+                buf += b"\x12" + bytes([sample_len]) + b"\x09"
+                val_off.append(len(buf))
+                buf += bytes(8) + b"\x10"
+                ts_off.append(len(buf))
+                buf += bytes(self.TS_VARINT)
         self._buf = np.frombuffer(buf, dtype=np.uint8)
         self._val_idx = np.asarray(val_off)[:, None] + np.arange(8)
         self._ts_idx = np.asarray(ts_off)[:, None] + np.arange(self.TS_VARINT)
 
-    def fill(self, values: np.ndarray, ts_ms: np.ndarray) -> bytes:
-        """values[field, host, k] and the k timestamps -> request bytes."""
-        # series order is host-major, samples of a series in time order
-        v = np.ascontiguousarray(values.transpose(1, 0, 2)).astype("<f8")
+    def fill(self, values: np.ndarray, ts_ms: int) -> bytes:
+        """values[field, host] of one round and its timestamp -> request
+        bytes (series order is host-major)."""
+        v = np.ascontiguousarray(values.T).astype("<f8")
         self._buf[self._val_idx] = v.reshape(-1).view(np.uint8).reshape(-1, 8)
-        enc = np.empty((self.k, self.TS_VARINT), dtype=np.uint8)
-        for j, t in enumerate(ts_ms):
-            b = _varint(int(t))
-            require(len(b) == self.TS_VARINT, "timestamp varint width changed")
-            enc[j] = np.frombuffer(b, dtype=np.uint8)
-        n_series = len(self._ts_idx) // self.k
-        self._buf[self._ts_idx] = np.tile(enc, (n_series, 1))
+        enc = _varint(int(ts_ms))
+        require(len(enc) == self.TS_VARINT, "timestamp varint width changed")
+        self._buf[self._ts_idx] = np.frombuffer(enc, dtype=np.uint8)
         return self._buf.tobytes()
 
 
@@ -194,10 +194,8 @@ def check_encoder(host_tags, values) -> None:
     request (importing the pb module does not pull JAX in)."""
     from horaedb_tpu.pb import remote_write_pb2
 
-    tmpl = RequestTemplate(host_tags[:2], 2)
-    ts = np.asarray([BASE_MS, BASE_MS + SCRAPE_MS])
     req = remote_write_pb2.WriteRequest()
-    req.ParseFromString(tmpl.fill(values[:, :2, :2], ts))
+    req.ParseFromString(RequestTemplate(host_tags[:2]).fill(values[:, :2, 1], BASE_MS))
     require(len(req.timeseries) == 2 * len(CPU_FIELDS), "encoder: series count")
     for i, series in enumerate(req.timeseries):
         h, f = divmod(i, len(CPU_FIELDS))
@@ -205,8 +203,34 @@ def check_encoder(host_tags, values) -> None:
         require(labels == {"__name__": f"cpu_{CPU_FIELDS[f]}", **host_tags[h]},
                 f"encoder: labels of series {i}")
         got = [(s.timestamp, s.value) for s in series.samples]
-        want = [(int(ts[j]), float(values[f, h, j])) for j in range(2)]
-        require(got == want, f"encoder: samples of series {i}")
+        require(got == [(BASE_MS, float(values[f, h, 1]))],
+                f"encoder: samples of series {i}")
+
+
+WIDE_METRIC = "smoke_wide_values"
+WIDE_VALUES = (1e300, 2.5e300, 1e-300, 5e-324, 3.5e38, 99.99967667212489)
+
+
+def send_wide_series(server: "Server", ts: np.ndarray) -> np.ndarray:
+    """One more series, one request: WIDE_VALUES in turn at every scrape
+    time. Returns its values as [1, rounds]."""
+    from horaedb_tpu.pb import remote_write_pb2
+
+    wide = np.resize(np.asarray(WIDE_VALUES), len(ts))
+    req = remote_write_pb2.WriteRequest()
+    series = req.timeseries.add()
+    for name, value in (("__name__", WIDE_METRIC), ("hostname", "host_0")):
+        series.labels.add(name=name.encode(), value=value.encode())
+    for t, v in zip(ts, wide):
+        series.samples.add(timestamp=int(t), value=float(v))
+    status, resp = server.request(
+        "POST", "/api/v1/write",
+        body=pa.Codec("snappy").compress(req.SerializeToString(), asbytes=True),
+        headers={"Content-Encoding": "snappy",
+                 "Content-Type": "application/x-protobuf"})
+    require(status == 200 and json.loads(resp)["samples"] == len(ts),
+            f"write of {WIDE_METRIC}: {status} {resp[:300]!r}")
+    return wide[None, :]
 
 
 # ---------------------------------------------------------------------------
@@ -215,18 +239,17 @@ def check_encoder(host_tags, values) -> None:
 
 
 class Server:
-    def __init__(self, out_dir: str, budget_s: float):
+    def __init__(self, out_dir: str):
         self.out_dir = out_dir
         self.proc: subprocess.Popen | None = None
         self.port = 0
         self._conn: http.client.HTTPConnection | None = None
         # the whole run's clock: past it every wait fails instead of hanging
-        self._deadline = time.monotonic() + budget_s
-        self._budget_s = budget_s
+        self._deadline = time.monotonic() + BUDGET_S
 
     def remaining(self) -> float:
         left = self._deadline - time.monotonic()
-        require(left > 0, f"the run's own limit of {self._budget_s:.0f} s is spent")
+        require(left > 0, f"the run's own limit of {BUDGET_S} s is spent")
         return left
 
     def command(self, cfg: str) -> list[str]:
@@ -406,10 +429,22 @@ def explain_summary(body: dict) -> dict:
         "bound": ex.get("bound"),
         "compile_s": ex.get("compile_s"),
         "steady_s": ex.get("steady_s"),
+        "stages_s": ex.get("stages_s"),
         "ssts_read": (ex.get("ssts") or {}).get("read"),
         "cache": serving.get("cache"),
         "rollup": serving.get("rollup"),
     }
+
+
+def stage_seconds(metrics: dict) -> dict:
+    out = {}
+    for key, value in metrics.items():
+        if key.startswith("horaedb_scan_stage_seconds_sum{"):
+            stage = key.split('stage="')[1].split('"')[0]
+            count = metrics[key.replace("_sum{", "_count{")]
+            if count:
+                out[stage] = [round(value, 4), int(count)]
+    return out
 
 
 def settle_compaction(server: Server, before: dict) -> dict:
@@ -473,29 +508,23 @@ def run(args, server: Server) -> dict:
     ts = BASE_MS + SCRAPE_MS * np.arange(rounds, dtype=np.int64)
     check_encoder(host_tags, values)
     gen_s = time.perf_counter() - t0
-    templates: dict[int, RequestTemplate] = {}
-    sent = requests = wire_bytes = 0
+    tmpl = RequestTemplate(host_tags)
+    sent = wire_bytes = 0
     t_ingest = time.perf_counter()
-    for lo in range(0, rounds, args.rounds_per_request):
-        hi = min(lo + args.rounds_per_request, rounds)
-        tmpl = templates.get(hi - lo)
-        if tmpl is None:
-            tmpl = templates[hi - lo] = RequestTemplate(host_tags, hi - lo)
-        raw = tmpl.fill(values[:, :, lo:hi], ts[lo:hi])
+    for r in range(rounds):  # one request per scrape round
+        raw = tmpl.fill(values[:, :, r], ts[r])
         body = pa.Codec("snappy").compress(raw, asbytes=True)
         status, resp = server.request(
             "POST", "/api/v1/write", body=body,
             headers={"Content-Encoding": "snappy",
                      "Content-Type": "application/x-protobuf"})
-        require(status == 200, f"write {requests}: {status} {resp[:300]!r}")
+        require(status == 200, f"write {r}: {status} {resp[:300]!r}")
         acked = json.loads(resp)["samples"]
-        require(acked == n_series * (hi - lo),
-                f"write {requests}: {acked} samples acknowledged")
+        require(acked == n_series, f"write {r}: {acked} samples acknowledged")
         sent += acked
-        requests += 1
         wire_bytes += len(body)
     ingest_s = time.perf_counter() - t_ingest
-    emit("ingest", t0, series=n_series, samples=sent, requests=requests,
+    emit("ingest", t0, series=n_series, samples=sent, requests=rounds,
          wire_bytes=wire_bytes, generate_seconds=round(gen_s, 3),
          ingest_seconds=round(ingest_s, 3),
          samples_per_second=round(sent / ingest_s, 1))
@@ -507,37 +536,37 @@ def run(args, server: Server) -> dict:
     all_hosts = list(range(args.hosts))
     rng = np.random.default_rng(args.seed + 1)
     one, other = (int(h) for h in rng.choice(args.hosts, size=2, replace=False))
-    long_timeout = f"{args.first_timeout}s"
+    long_timeout = f"{FIRST_TIMEOUT_S}s"
 
-    def range_query(expr, fn, field, hosts, start_s, exact, what, **extra):
+    def range_query(expr, fn, source, hosts, start_s, exact, what, **extra):
         steps = 1000 * np.arange(start_s, end_s + 1, STEP_S, dtype=np.int64)
         body, secs = ask(server, "/api/v1/query_range", {
             "query": expr, "start": start_s, "end": end_s,
             "step": f"{STEP_S}s", **extra})
-        want = window_reduce(values[field][hosts], ts, steps, fn)
+        want = window_reduce(source[hosts], ts, steps, fn)
         compare(by_host(body), want, hosts, steps, exact, what)
         return body, secs
 
-    def instant_query(field, what, **extra):
+    def instant_query(metric, source, hosts, what, **extra):
         at_s = end_s
         body, secs = ask(server, "/api/v1/query", {
-            "query": f"cpu_{CPU_FIELDS[field]}", "time": at_s, **extra})
+            "query": metric, "time": at_s, **extra})
         # the last sample at or before `time`, within the 5 m lookback
-        want = values[field][:, -1:]
-        compare(by_host(body), want, all_hosts,
+        compare(by_host(body), source[hosts, -1:], hosts,
                 np.asarray([at_s * 1000], dtype=np.int64), True, what)
         return body, secs
 
-    def phase(name, first, again):
+    def phase(name, first, *again):
         t0 = time.perf_counter()
         totals = [kernel_totals(server)]
         asked = []
-        for query in (first, again):
+        for query in (first, *again):
             asked.append(query())
             totals.append(kernel_totals(server))
         report = {}
         for key, (body, secs), k0, k1, timeout in zip(
-            ("first", "again"), asked, totals, totals[1:], (long_timeout, "default")
+            ("first", "again", "third"), asked, totals, totals[1:],
+            (long_timeout, "default", "default")
         ):
             report[key] = {
                 "seconds": round(secs, 3), "timeout": timeout,
@@ -556,27 +585,44 @@ def run(args, server: Server) -> dict:
         "query_groupby_1_1_1",
         lambda: range_query(
             f'max_over_time(cpu_usage_user{{hostname="host_{one}"}}[5m])',
-            np.max, 0, [one], hour_start, True, "max_over_time, one host",
+            np.max, values[0], [one], hour_start, True, "max_over_time, one host",
             timeout=long_timeout),
         lambda: range_query(
             f'max_over_time(cpu_usage_user{{hostname="host_{other}"}}[5m])',
-            np.max, 0, [other], hour_start, True, "max_over_time, another host"),
+            np.max, values[0], [other], hour_start, True,
+            "max_over_time, another host"),
     )
     window_start = BASE_MS // 1000 + STEP_S
     downsample = phase(
         "query_downsample_all_hosts",
         lambda: range_query(
-            "avg_over_time(cpu_usage_user[5m])", np.mean, 0, all_hosts,
+            "avg_over_time(cpu_usage_user[5m])", np.mean, values[0], all_hosts,
             window_start, False, "avg_over_time, every host",
             timeout=long_timeout),
         lambda: range_query(
-            "avg_over_time(cpu_usage_system[5m])", np.mean, 1, all_hosts,
+            "avg_over_time(cpu_usage_system[5m])", np.mean, values[1], all_hosts,
             window_start, False, "avg_over_time, every host, another metric"),
     )
     phase(
         "query_lastpoint",
-        lambda: instant_query(0, "last value, every host", timeout=long_timeout),
-        lambda: instant_query(1, "last value, every host, another metric"),
+        lambda: instant_query("cpu_usage_user", values[0], all_hosts,
+                              "last value, every host", timeout=long_timeout),
+        lambda: instant_query("cpu_usage_system", values[1], all_hosts,
+                              "last value, every host, another metric"),
+    )
+    # one series of samples no f32 exponent holds: an accelerator carries
+    # f64 as a pair of f32, so these come back right only if the server
+    # keeps its selections on integer lanes and such sums on the host
+    wide = send_wide_series(server, ts)
+    phase(
+        "query_wide_values",
+        lambda: range_query(
+            f"avg_over_time({WIDE_METRIC}[5m])", np.mean, wide, [0],
+            hour_start, False, "avg_over_time of 1e300s", timeout=long_timeout),
+        lambda: range_query(
+            f"max_over_time({WIDE_METRIC}[5m])", np.max, wide, [0],
+            hour_start, True, "max_over_time of 1e300s"),
+        lambda: instant_query(WIDE_METRIC, wide, [0], "last value of 1e300s"),
     )
 
     # 4. compaction, and the downsample again: the same answer
@@ -585,7 +631,7 @@ def run(args, server: Server) -> dict:
     server.get_json("/compact")
     settled = settle_compaction(server, before)
     body, secs = range_query(
-        "avg_over_time(cpu_usage_user[5m])", np.mean, 0, all_hosts,
+        "avg_over_time(cpu_usage_user[5m])", np.mean, values[0], all_hosts,
         window_start, False, "avg_over_time after compaction")
     got, was = by_host(body), by_host(downsample)
     for host, pairs in was.items():
@@ -610,6 +656,9 @@ def run(args, server: Server) -> dict:
                           for e in k["kernels"] if e["compiles"]},
          compile_seconds_total=kernel_totals(server)["compile_seconds"],
          scan_path_device=device_merges, scan_path_host=host_merges,
+         # every scan and compaction of the run, compiles deducted:
+         # stage -> [seconds, times entered]
+         scan_stage_seconds=stage_seconds(metrics),
          compile_cache_dir=cache_dir, compile_cache_files=count_files(cache_dir))
     require(device["platform"] == "tpu",
             f"the server ran on platform {device['platform']!r}, not on a TPU")
@@ -625,13 +674,6 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--hosts", type=int, default=1000)
     ap.add_argument("--hours", type=float, default=2.0)
-    ap.add_argument("--rounds-per-request", type=int, default=1,
-                    help="scrape rounds per remote-write request")
-    ap.add_argument("--first-timeout", type=int, default=300,
-                    help="seconds allowed to the first query of each shape "
-                         "(clamped by the server's max_timeout)")
-    ap.add_argument("--budget", type=float, default=1100.0,
-                    help="seconds the whole run may take before it fails itself")
     ap.add_argument("--out", default=os.path.join(ROOT, ".chip_smoke"),
                     help="scratch directory (emptied first): the server's "
                          "config, data and log")
@@ -643,7 +685,7 @@ def main() -> int:
     shutil.rmtree(args.out, ignore_errors=True)
     os.makedirs(args.out)
     os.makedirs(args.report_dir, exist_ok=True)
-    server = Server(args.out, args.budget)
+    server = Server(args.out)
     device = None
     t_run = time.perf_counter()
     try:

@@ -594,6 +594,13 @@ def _record(name: str) -> str:
     return name
 
 
+def record_choice(name: str) -> str:
+    """A lane the caller chose for a reason of its own (aggregate.py: sums
+    an accelerator's f64 cannot carry take the host lane), counted and
+    attributed like a dispatcher decision."""
+    return _record(name)
+
+
 def choose_sorted(n: int, num_cells: int, *, concrete: bool = True,
                   platform: str | None = None) -> str:
     """Resolve the sorted-lane impl: HORAEDB_AGG_IMPL pin > legacy

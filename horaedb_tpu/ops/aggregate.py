@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from horaedb_tpu.common.error import ensure
 from horaedb_tpu.common.xprof import xjit
 from horaedb_tpu.ops.sort import f64_order_i64
 
@@ -156,19 +157,41 @@ _I64_MIN = np.iinfo(np.int64).min
 _NAN_LOW, _NAN_HIGH = _I64_MIN + 1, _I64_MAX - 1
 
 
+_F32_MAX = float(np.finfo(np.float32).max)
+# below this magnitude the low half of an f32 pair is no longer a normal f32
+_F32_PAIR_MIN = float(np.finfo(np.float32).tiny) * 2.0 ** 24
+
+
 def device_f64_is_exact() -> bool:
-    """Only the CPU backend holds f64 as f64; see `f64_order_keys`."""
+    """Only the CPU backend holds f64 as f64. An accelerator holds 64-bit
+    integers exactly but emulates f64 as a pair of f32: about 48 mantissa
+    bits and f32's exponent range, so 1e300 reads back inf and a stored
+    sample loses its last bits on the way there and back (measured on a
+    TPU v5e, PR 25). Callers keep what must stay exact on integer lanes
+    (`f64_order_keys`, i64 bit views) or on the host."""
     return jax.devices()[0].platform == "cpu"
+
+
+def device_sums_hold(values: np.ndarray) -> bool:
+    """Whether the device can accumulate `values` in its f64: always on the
+    CPU; on an accelerator only when every finite magnitude, and their sum,
+    stays inside the range an f32 pair carries."""
+    if device_f64_is_exact():
+        return True
+    mag = np.abs(values[np.isfinite(values)])
+    small = mag[mag > 0]
+    return float(mag.sum()) <= _F32_MAX and (
+        small.size == 0 or float(small.min()) >= _F32_PAIR_MIN
+    )
 
 
 def f64_order_keys(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """Host side of the exact device min/max: (min-lane, max-lane) i64 keys
-    whose signed order is the f64 total order of `values`. An accelerator
-    holds 64-bit integers exactly but emulates f64 as a pair of f32 (about
-    48 mantissa bits, f32's exponent range: measured on a TPU v5e, PR 25),
-    so a selection that must return a stored sample bit for bit is reduced
-    over these keys and mapped back by `f64_from_order_keys`. The two lanes
-    are one array unless the block holds a NaN."""
+    whose signed order is the f64 total order of `values`. A selection must
+    return a stored sample bit for bit and the device's f64 may not be one
+    (`device_f64_is_exact`), so min/max reduce these keys on every backend
+    and `f64_from_order_keys` maps the winners back. The two lanes are one
+    array unless the block holds a NaN."""
     keys = f64_order_i64(np.asarray(values))
     nan = np.isnan(values)
     if not nan.any():
@@ -211,13 +234,26 @@ def downsample_sorted(
     series_idx (e.g. the searchsorted position, not -1) and are zeroed via
     the compaction's weight column.
 
-    Concrete (non-traced) inputs on the CPU backend consult the calibrated
-    registry dispatcher first: when the measured winner is a host lane
+    Concrete (non-traced) inputs consult the calibrated registry
+    dispatcher first: when the measured winner is a host lane
     (np.add.reduceat over run boundaries), the WHOLE grid computes on host
     — no device dispatch at all, and no f32-exact grid-size ceiling (host
     keys are i64).
+
+    Concrete f64 values take their min/max over i64 order keys built on the
+    host (`f64_order_keys`), whatever the backend. On an accelerator, values
+    whose sums its f64 cannot carry (`device_sums_hold`) take the host
+    reduceat lane, recorded as the dispatcher's choice like any other; an
+    f64 jax array is refused there, since it has already lost bits.
     """
-    from horaedb_tpu.ops.blockagg import _F32_EXACT, sorted_segment_sum_count
+    from horaedb_tpu.ops import agg_registry
+    from horaedb_tpu.ops.blockagg import (
+        _F32_EXACT,
+        _scatter_min_max,
+        _scatter_sum_count,
+        sorted_segment_min_max,
+        sorted_segment_sum_count,
+    )
 
     num_cells = num_series * num_buckets
     traced = any(
@@ -228,35 +264,28 @@ def downsample_sorted(
     # reductions below — re-resolving per reduction would triple-count
     # horaedb_agg_impl_total and re-read env/cache on the scan hot path
     choice: str | None = None
-    if not traced and device_f64_is_exact():
-        from horaedb_tpu.ops import agg_registry
-
-        choice = agg_registry.choose_sorted(
-            jnp.shape(values)[0], num_cells, concrete=True
+    order_keys = None
+    if not traced:
+        f64 = jnp.result_type(values) == jnp.float64
+        ensure(
+            device_f64_is_exact() or not (f64 and isinstance(values, jax.Array)),
+            "an f64 device array has already lost bits on this backend: "
+            "hand downsample_sorted the host array",
         )
+        if f64 and not device_sums_hold(np.asarray(values)):
+            choice = agg_registry.record_choice("reduceat")
+        else:
+            choice = agg_registry.choose_sorted(
+                jnp.shape(values)[0], num_cells, concrete=True
+            )
         if agg_registry.is_host_impl(choice):
             return agg_registry.host_downsample_sorted(
                 ts, series_idx, values, t0, bucket_ms,
                 num_series=num_series, num_buckets=num_buckets,
                 with_minmax=with_minmax, valid=valid, impl=choice,
             )
-    if num_cells >= _F32_EXACT:
-        # grid too large for exact f32 cell-id recovery; use the scatter path
-        v_mask = (
-            jnp.ones(jnp.asarray(values).shape[0], dtype=bool)
-            if valid is None else jnp.asarray(valid)
-        )
-        out = downsample(ts, series_idx, values, v_mask, t0, bucket_ms,
-                         num_series=num_series, num_buckets=num_buckets)
-        if not with_minmax:
-            out = {k: out[k] for k in ("sum", "count", "mean")}
-        return out
-    order_keys = None
-    if (
-        with_minmax and isinstance(values, np.ndarray)
-        and values.dtype == np.float64 and not device_f64_is_exact()
-    ):
-        order_keys = f64_order_keys(values)
+        if with_minmax and f64:
+            order_keys = f64_order_keys(np.asarray(values))
     ts = jnp.asarray(ts)
     series_idx = jnp.asarray(series_idx)
     values = jnp.asarray(values)
@@ -267,39 +296,42 @@ def downsample_sorted(
     )
     if valid is not None:
         ok = ok & jnp.asarray(valid)
-    safe, flat = masked_cell_keys(series_idx, bucket, ok, num_series, num_buckets)
+    safe, _flat = masked_cell_keys(series_idx, bucket, ok, num_series, num_buckets)
+    # a grid too large for exact f32 cell-id recovery takes plain scatters
+    big = num_cells >= _F32_EXACT
+
+    def min_max(lane):
+        if big:
+            return _scatter_min_max(safe, lane, num_cells, valid=ok)
+        return sorted_segment_min_max(safe, lane, num_cells, impl=choice, valid=ok)
+
     # typed zero fill: a weak 0.0 would promote integer values to float and
     # bypass the dtype-preserving integer scatter route
-    s, c = sorted_segment_sum_count(
-        safe, jnp.where(ok, values, jnp.zeros((), values.dtype)), num_cells,
-        impl=choice, weights=ok.astype(values.dtype),
-    )
+    masked = jnp.where(ok, values, jnp.zeros((), values.dtype))
+    weights = ok.astype(values.dtype)
+    if big:
+        s, c = _scatter_sum_count(safe, masked, num_cells, w=weights)
+    else:
+        s, c = sorted_segment_sum_count(
+            safe, masked, num_cells, impl=choice, weights=weights,
+        )
     shape = (num_series, num_buckets)
     out = {
         "sum": s.reshape(shape),
         "count": c.reshape(shape),
         "mean": (s / c).reshape(shape),
     }
+    if with_minmax and order_keys is not None:
+        # a selection returns a stored sample bit for bit: reduce the i64
+        # order keys and map the winners back to f64 on the host
+        kmin, kmax = order_keys
+        mn, mx = min_max(kmin)
+        if kmax is not kmin:  # the block holds a NaN: its own max lane
+            mx = min_max(kmax)[1]
+        mn, mx = f64_from_order_keys(mn), f64_from_order_keys(mx)
+    elif with_minmax:
+        mn, mx = min_max(values)
     if with_minmax:
-        from horaedb_tpu.ops.blockagg import sorted_segment_min_max
-
-        if order_keys is not None:
-            # exact selection on an accelerator: reduce the i64 order keys
-            # (64-bit integers are exact there, f64 is not) and map the
-            # winners back to their f64 bit patterns on the host
-            kmin, kmax = order_keys
-            mn, mx = sorted_segment_min_max(
-                safe, kmin, num_cells, impl=choice, valid=ok
-            )
-            if kmax is not kmin:  # the block holds a NaN: its own max lane
-                mx = sorted_segment_min_max(
-                    safe, kmax, num_cells, impl=choice, valid=ok
-                )[1]
-            mn, mx = f64_from_order_keys(mn), f64_from_order_keys(mx)
-        else:
-            mn, mx = sorted_segment_min_max(
-                safe, values, num_cells, impl=choice, valid=ok
-            )
         out["min"] = mn.reshape(shape)
         out["max"] = mx.reshape(shape)
     return out
@@ -351,7 +383,7 @@ def stacked_downsample(
     bucket_ms,
     num_series: int,
     num_buckets: int,
-    order_keys=None,
+    order_keys,
 ) -> dict[str, jax.Array]:
     """Downsample grids for a STACK of coalesced queries in one launch —
     the query batcher's device lane (server/batching.py): inputs carry a
@@ -374,9 +406,10 @@ def stacked_downsample(
     Accumulation dtype follows the inputs (f64 on the x64 CPU path, the
     engine's precision contract — see SampleManager.query_downsample).
 
-    `order_keys` (optional, the [B, R] i64 lanes of `f64_order_keys`): the
-    accelerator's exact min/max. "min"/"max" then come back as i64 keys for
-    `f64_from_order_keys`; the f64 value lane feeds sum and count only."""
+    `order_keys` (the [B, R] i64 min and max lanes of `f64_order_keys`)
+    carry the selection: "min"/"max" come back as i64 keys for
+    `f64_from_order_keys`, exact on every backend; the f64 value lane feeds
+    sum and count only."""
     nb, cells = t0.shape[0], num_series * num_buckets
     bucket = ((ts - t0[:, None]) // bucket_ms).astype(jnp.int32)
     ok = (
@@ -388,14 +421,13 @@ def stacked_downsample(
         + jnp.clip(bucket, 0, num_buckets - 1)
     flat = jnp.where(ok, lane * cells + safe, nb * cells)
     flat, ok = flat.reshape(-1), ok.reshape(-1)
-    s, c, mn, mx = masked_segment_stats(
-        values.reshape(-1), flat, ok, nb * cells, with_minmax=order_keys is None
+    s, c, _mn, _mx = masked_segment_stats(
+        values.reshape(-1), flat, ok, nb * cells, with_minmax=False
     )
-    if order_keys is not None:
-        # masked rows already route to the sentinel cell, which is sliced off
-        kmin, kmax = order_keys
-        mn = jax.ops.segment_min(kmin.reshape(-1), flat, nb * cells + 1)[:-1]
-        mx = jax.ops.segment_max(kmax.reshape(-1), flat, nb * cells + 1)[:-1]
+    # masked rows already route to the sentinel cell, which is sliced off
+    kmin, kmax = order_keys
+    mn = jax.ops.segment_min(kmin.reshape(-1), flat, nb * cells + 1)[:-1]
+    mx = jax.ops.segment_max(kmax.reshape(-1), flat, nb * cells + 1)[:-1]
     shape = (nb, num_series, num_buckets)
     s, c = s.reshape(shape), c.reshape(shape)
     return {"sum": s, "count": c, "min": mn.reshape(shape),

@@ -540,12 +540,20 @@ class QueryBatcher:
         awaits): runs on the event loop like the solo fold path. Returns
         one entry per member: grids dict | None | SOLO (overflow) |
         BaseException (that member's scan failed)."""
+        from horaedb_tpu.ops import aggregate as agg_ops
+
         members = group.members
         results: list = [None] * len(members)
         stack_idx: list[int] = []
         for i, lane in enumerate(lanes):
             if isinstance(lane, BaseException):
                 results[i] = lane
+            elif lane is not None and not agg_ops.device_sums_hold(
+                np.asarray(lane[2], dtype=np.float64)
+            ):
+                # values an accelerator's f64 cannot sum: the solo fold
+                # takes them to the host lane (ops/aggregate.py)
+                results[i] = SOLO
             elif lane is not None:
                 stack_idx.append(i)
             # lane None: nothing in range — results[i] stays None
@@ -568,8 +576,6 @@ class QueryBatcher:
             results[big] = SOLO
         if not stack_idx:
             return results
-        from horaedb_tpu.ops import aggregate as agg_ops
-
         bsz = len(stack_idx)
         spad = group.spad
         nb = group.num_buckets
@@ -578,12 +584,9 @@ class QueryBatcher:
         val_b = np.zeros((bpad, rpad), dtype=np.float64)
         ok_b = np.zeros((bpad, rpad), dtype=bool)
         t0_b = np.zeros((bpad,), dtype=np.int64)
-        # exact min/max off the CPU: the accelerator's f64 is emulated and
-        # lossy, its i64 is not (ops/aggregate.py f64_order_keys)
-        keys_b = (
-            None if agg_ops.device_f64_is_exact()
-            else np.zeros((2, bpad, rpad), dtype=np.int64)  # min lane, max lane
-        )
+        # min/max reduce exact i64 order keys (ops/aggregate.py
+        # f64_order_keys): the min lane and the max lane
+        keys_b = np.zeros((2, bpad, rpad), dtype=np.int64)
         rows = 0
         for j, i in enumerate(stack_idx):
             ts, sid, vals = lanes[i]
@@ -594,21 +597,19 @@ class QueryBatcher:
             val_b[j, :n] = vals
             ok_b[j, :n] = True
             t0_b[j] = group.t0s[i]
-            if keys_b is not None:
-                keys_b[0, j, :n], keys_b[1, j, :n] = agg_ops.f64_order_keys(
-                    np.asarray(vals, dtype=np.float64)
-                )
+            keys_b[0, j, :n], keys_b[1, j, :n] = agg_ops.f64_order_keys(
+                np.asarray(vals, dtype=np.float64)
+            )
         waste = 1.0 - rows / float(bpad * rpad)
         with scanstats.stage("device_agg"):
             out = agg_ops.stacked_downsample(
                 ts_b, sid_b, val_b, ok_b, t0_b, group.bucket_ms,
                 num_series=spad, num_buckets=nb,
-                order_keys=None if keys_b is None else tuple(keys_b),
+                order_keys=tuple(keys_b),
             )
         grids = {k: np.asarray(v) for k, v in out.items()}
-        if keys_b is not None:
-            grids["min"] = agg_ops.f64_from_order_keys(grids["min"])
-            grids["max"] = agg_ops.f64_from_order_keys(grids["max"])
+        grids["min"] = agg_ops.f64_from_order_keys(grids["min"])
+        grids["max"] = agg_ops.f64_from_order_keys(grids["max"])
         BATCH_LAUNCHES.inc()
         BATCH_GROUP_SIZE.observe(bsz)
         BATCH_PAD_WASTE.observe(waste)

@@ -128,9 +128,16 @@ class Executor:
         return t
 
     async def drain(self) -> None:
-        """Wait for in-flight compactions (tests & shutdown)."""
+        """Wait for in-flight compactions (tests & shutdown).
+
+        A finished task leaves `_inflight` only when the loop runs its done
+        callback; awaiting finished tasks does not yield to the loop, so the
+        set is emptied here as well, or a drain that starts between a
+        task's last step and its callback would spin without end."""
         while self._inflight:
-            await asyncio.gather(*list(self._inflight), return_exceptions=True)
+            tasks = list(self._inflight)
+            await asyncio.gather(*tasks, return_exceptions=True)
+            self._inflight.difference_update(tasks)
 
     # -- the compaction itself (executor.rs:155-222) --------------------------
     async def do_compaction(self, task: Task) -> None:

@@ -51,6 +51,7 @@ from horaedb_tpu.common.error import HoraeError, ensure
 from horaedb_tpu.common.xprof import xjit
 from horaedb_tpu.objstore import ObjectStore
 from horaedb_tpu.server.metrics import GLOBAL_METRICS
+from horaedb_tpu.ops import aggregate as agg_ops
 from horaedb_tpu.ops import dedup as dedup_ops
 from horaedb_tpu.ops import filter as filter_ops
 from horaedb_tpu.ops import sort as sort_ops
@@ -523,6 +524,11 @@ def _build_index_kernel(
     return kernel
 
 
+def _is_f64(schema: StorageSchema, name: str) -> bool:
+    i = schema.arrow_schema.get_field_index(name)
+    return i >= 0 and pa.types.is_float64(schema.arrow_schema.field(i).type)
+
+
 def _plan_and_merge(
     schema: StorageSchema,
     n: int,
@@ -552,7 +558,8 @@ def _plan_and_merge(
 
     `HORAEDB_SCAN_PATH` in {auto, host, device, sharded} overrides (A/B
     harnesses, tests). Binary-column predicates always evaluate on host (the
-    device has no byte lanes) but may still merge on device via the mask lane.
+    device has no byte lanes) but may still merge on device via the mask lane;
+    so do f64 predicates on a backend whose f64 is not exact.
 
     When an ambient mesh is installed (parallel/mesh.py) the packed route
     upgrades to the cross-chip sample-sort merge (parallel/merge.py) for
@@ -576,6 +583,16 @@ def _plan_and_merge(
             f"HORAEDB_SCAN_PATH={mode!r} is not one of "
             "auto/host/device/sharded"
         )
+    # an accelerator's f64 is lossy (ops/aggregate.py device_f64_is_exact):
+    # f64 predicate lanes evaluate on the host and ride the mask lane, as
+    # byte lanes do, and f64 sort keys keep the whole merge on the host
+    host_pred, host_keys = binary_pred, False
+    if not agg_ops.device_f64_is_exact():
+        host_pred = host_pred or any(_is_f64(schema, c) for c in pred_cols)
+        host_keys = any(_is_f64(schema, k) for k in sort_keys)
+        ensure(not host_keys or mode in ("auto", "host"),
+               f"HORAEDB_SCAN_PATH={mode} cannot sort f64 keys exactly on "
+               "this backend")
     link = _LinkProfile.get()
     dispatch = link["dispatch_s"]
 
@@ -786,14 +803,16 @@ def _plan_and_merge(
             return timed_eval()
 
     if mode == "device":
-        if binary_pred:
+        if host_pred:
             return device_merge(eval_mask())
         return device_merge(None)
     if mode == "sharded":
         # force the cross-chip route: host-eval any predicate into a mask so
         # the packed path (the only sharded one) is always eligible
         return device_merge(eval_mask())
-    if mode == "host":
+    if mode == "host" or host_keys:
+        if host_keys:
+            scanstats.note("path_host_f64_keys")
         return host_merge(eval_mask())
     # ambient-mesh auto upgrade (docs/operations.md): past the sharded
     # threshold the cross-chip merge supersedes the single-device cost
@@ -813,7 +832,7 @@ def _plan_and_merge(
     # auto with a predicate: if the device wins even at worst-case
     # selectivity, skip the host eval entirely
     eval_cost = n * _HostCalib.eval_s_per_row() * n_terms
-    if not binary_pred and dev_cost(tmpl_bytes, n) < eval_cost \
+    if not host_pred and dev_cost(tmpl_bytes, n) < eval_cost \
             and not keys_presorted():
         return device_merge(None)
     with scanstats.stage("host_filter"):
@@ -829,6 +848,16 @@ def _plan_and_merge(
 # ---------------------------------------------------------------------------
 # fused per-segment scan kernel
 # ---------------------------------------------------------------------------
+
+
+_HOST_MASK = "__hostmask__"
+
+
+def _host_lane(sorted_cols: dict, name: str, bit_lanes: frozenset) -> np.ndarray:
+    """One lane of a fused pass back on the host; an f64 lane that crossed
+    as its i64 bits (`_fused_pass`) reads as f64 again."""
+    a = np.asarray(sorted_cols[name])
+    return a.view(np.float64) if name in bit_lanes else a
 
 
 @lru_cache(maxsize=256)
@@ -859,6 +888,8 @@ def _build_scan_kernel(
         n = cols[sort_keys[0]].shape[0]
         valid = jnp.arange(n) < num_valid
         mask = filter_ops.eval_predicate(template, cols, literals) & valid
+        if _HOST_MASK in cols:  # the predicate was evaluated on the host
+            mask = mask & (cols[_HOST_MASK] != 0)
         kept = jnp.sum(mask)
         if presorted:
             # stable partition: valid rows keep their (sorted) order as a
@@ -1673,14 +1704,15 @@ class ParquetReader:
         )
         if schema.update_mode == UpdateMode.APPEND and has_binary_value:
             (
-                sorted_cols, perm, _keep, starts, kept, numeric_names, binary_names,
+                sorted_cols, perm, _keep, starts, kept, numeric_names,
+                binary_names, bit_lanes,
             ) = self._fused_pass(table, predicate)
             # group-byte concatenation + arrow rebuild is CPU-bound
             # host work: off the event loop (J018)
             result = await asyncio.to_thread(
                 self._materialize_append_mode,
                 table, sorted_cols, np.asarray(perm), np.asarray(starts),
-                int(kept), numeric_names, binary_names, out_names,
+                int(kept), numeric_names, binary_names, out_names, bit_lanes,
             )
             return self._slice_batches(result, batch_size)
 
@@ -1875,7 +1907,13 @@ class ParquetReader:
         literal casting, and the jitted filter->sort->dedup kernel. Used by
         the single-block scan, the hierarchical merge levels, and aggregate
         pushdown (`extra_arrays` rides host-computed lanes, e.g. the dense
-        series index, through the same permutation)."""
+        series index, through the same permutation).
+
+        An f64 lane the kernel only carries crosses as its i64 bits
+        (`bit_lanes`, read back with `_host_lane`): a device's f64 need not
+        be exact (ops/aggregate.py device_f64_is_exact), its i64 is. Where
+        it is not exact, a predicate over f64 lanes evaluates on the host
+        and rides in as a mask lane, and f64 sort keys are refused."""
         schema = self._schema
         pk_names = tuple(schema.primary_key_names)
         sort_keys = pk_names + (SEQ_COLUMN_NAME,)
@@ -1900,6 +1938,20 @@ class ParquetReader:
         }
         if extra_arrays:
             arrays.update(extra_arrays)
+        pred_cols = filter_ops.pred_columns(predicate)
+        f64_lanes = {k for k, a in arrays.items() if a.dtype == np.float64}
+        if not agg_ops.device_f64_is_exact():
+            ensure(f64_lanes.isdisjoint(sort_keys),
+                   "f64 primary key lanes do not sort exactly on this backend")
+            if not f64_lanes.isdisjoint(pred_cols):
+                with scanstats.stage("host_filter"):
+                    arrays[_HOST_MASK] = filter_ops.eval_predicate_np(
+                        predicate, {c: arrays[c] for c in pred_cols}
+                    ).astype(np.uint8)
+                predicate, pred_cols = None, set()
+        bit_lanes = frozenset(f64_lanes - set(pred_cols) - set(sort_keys))
+        for name in bit_lanes:
+            arrays[name] = arrays[name].view(np.int64)
         with scanstats.stage("h2d"):
             block = Block.from_numpy(
                 arrays, pad_multiple=_merge_rows(table.num_rows),
@@ -1922,7 +1974,8 @@ class ParquetReader:
             sorted_cols, perm, keep, starts, kept = kernel(
                 block.columns, literals, block.num_valid
             )
-        return sorted_cols, perm, keep, starts, kept, numeric_names, binary_names
+        return (sorted_cols, perm, keep, starts, kept, numeric_names,
+                binary_names, bit_lanes)
 
     async def _scan_segment_chunked(
         self,
@@ -2128,8 +2181,6 @@ class ParquetReader:
         """
         import jax.numpy as jnp
 
-        from horaedb_tpu.ops import aggregate as agg_ops
-
         num_series = len(series_ids)
         grids = {
             "sum": np.zeros((num_series, num_buckets)),
@@ -2253,26 +2304,29 @@ class ParquetReader:
         all_hit = bool(sid_hit.all())
         if not all_hit:
             extra["__sidok__"] = sid_hit.astype(np.int32)
-        sorted_cols, _perm, keep, _starts, _kept, _num, _bin = self._fused_pass(
-            table, predicate, extra_arrays=extra
-        )
+        (sorted_cols, _perm, keep, _starts, _kept, _num, _bin,
+         bit_lanes) = self._fused_pass(table, predicate, extra_arrays=extra)
         row_ok = keep if all_hit else keep & (sorted_cols["__sidok__"] != 0)
-        if mesh is not None:
-            # mesh path: the merged/deduped rows leave the fused pass and
-            # shard over the mesh for the reduction; misses keep their
-            # monotone position and are zeroed via the weight column
+        if mesh is not None or not agg_ops.device_f64_is_exact():
+            # the merged/deduped rows leave the fused pass for the sorted
+            # reduction: sharded over the mesh, or (a backend whose f64 is
+            # not exact) with its selections on integer lanes; misses keep
+            # their monotone position and are zeroed via the weight column
             accumulate_sorted(
                 np.asarray(sorted_cols[ts_column]).astype(np.int64),
                 np.asarray(sorted_cols["__sid__"]).astype(np.int32),
-                np.asarray(sorted_cols[value_column]),
+                _host_lane(sorted_cols, value_column, bit_lanes),
                 valid_np=np.asarray(row_ok),
             )
             return grids
         # device-side reduction of the surviving rows (row_ok is a mask)
+        values = sorted_cols[value_column]
+        if value_column in bit_lanes:
+            values = jax.lax.bitcast_convert_type(values, jnp.float64)
         out = agg_ops.downsample(
             sorted_cols[ts_column].astype(jnp.int64),
             sorted_cols["__sid__"],
-            sorted_cols[value_column],
+            values,
             row_ok,
             t0,
             bucket_ms,
@@ -2423,6 +2477,7 @@ class ParquetReader:
         numeric_names: list[str],
         binary_names: list[str],
         out_names: list[str],
+        bit_lanes: frozenset,
     ) -> pa.RecordBatch:
         """Append mode with binary values: groups collapse by concatenating
         value bytes (BytesMergeOperator) on host; group extents come from the
@@ -2452,7 +2507,7 @@ class ParquetReader:
                 else:
                     cols.append(src.take(pa.array(start_idx)))
             else:
-                np_col = np.asarray(sorted_cols[name])[:kept]
+                np_col = _host_lane(sorted_cols, name, bit_lanes)[:kept]
                 # non-value numeric columns take the group's first row; numeric
                 # value columns in append mode also take first (reference only
                 # concatenates binary value columns, operator.rs:59-111)

@@ -23,6 +23,7 @@ os.environ.setdefault("HORAEDB_AGG_CALIB_N", "20000")
 import asyncio
 import faulthandler
 import functools
+import io
 import signal
 
 import pytest
@@ -39,11 +40,25 @@ jax.config.update("jax_platforms", "cpu")
 TEST_WATCHDOG_S = 180
 
 
+def _pending_task_stacks() -> str:
+    """Where every task of the running event loop is parked: a hung async
+    test is an await that never resolves, which no thread stack shows."""
+    try:
+        tasks = asyncio.all_tasks(asyncio.get_running_loop())
+    except RuntimeError:  # the test runs no loop
+        return ""
+    out = io.StringIO()
+    for task in tasks:
+        task.print_stack(file=out)
+    return out.getvalue()
+
+
 @pytest.fixture(autouse=True)
 def _watchdog(request):
     def on_alarm(signum, frame):
         pytest.fail(
-            f"{request.node.nodeid} ran over {TEST_WATCHDOG_S} s (watchdog)",
+            f"{request.node.nodeid} ran over {TEST_WATCHDOG_S} s (watchdog)\n"
+            + _pending_task_stacks(),
             pytrace=True,
         )
 

@@ -14,7 +14,8 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = ["build", "start", "ingest", "query_groupby_1_1_1",
-          "query_downsample_all_hosts", "query_lastpoint", "compact", "kernels"]
+          "query_downsample_all_hosts", "query_lastpoint", "query_wide_values",
+          "compact", "kernels"]
 
 
 @pytest.fixture()
@@ -106,18 +107,14 @@ def smoke():
     return mod
 
 
-@pytest.mark.parametrize("k", [1, 2, 5])
-def test_request_template_is_what_the_protobuf_runtime_reads(smoke, k):
-    """The hand-written wire encoder, for k scrape rounds per request,
-    against the generated protobuf classes: labels, order, samples."""
-    import numpy as np
-
+def test_request_template_is_what_the_protobuf_runtime_reads(smoke):
+    """The hand-written wire encoder against the generated protobuf
+    classes: labels, order, the one sample of each series."""
     from horaedb_tpu.pb import remote_write_pb2
 
-    tags, values = smoke.make_fleet(3, 4, k)
-    ts = smoke.BASE_MS + smoke.SCRAPE_MS * np.arange(k)
+    tags, values = smoke.make_fleet(3, 4, 2)
     req = remote_write_pb2.WriteRequest()
-    req.ParseFromString(smoke.RequestTemplate(tags, k).fill(values, ts))
+    req.ParseFromString(smoke.RequestTemplate(tags).fill(values[:, :, 1], smoke.BASE_MS))
     assert len(req.timeseries) == 4 * len(smoke.CPU_FIELDS)
     for i, series in enumerate(req.timeseries):
         h, f = divmod(i, len(smoke.CPU_FIELDS))
@@ -125,19 +122,16 @@ def test_request_template_is_what_the_protobuf_runtime_reads(smoke, k):
         assert labels == {"__name__": f"cpu_{smoke.CPU_FIELDS[f]}", **tags[h]}
         assert [lb.name for lb in series.labels] == sorted(lb.name for lb in series.labels)
         assert [(s.timestamp, s.value) for s in series.samples] == \
-            [(int(ts[j]), float(values[f, h, j])) for j in range(k)]
+            [(smoke.BASE_MS, float(values[f, h, 1]))]
 
 
 def test_a_template_is_refilled_in_place(smoke):
-    import numpy as np
-
-    tags, values = smoke.make_fleet(0, 2, 4)
-    tmpl = smoke.RequestTemplate(tags, 2)
-    ts = smoke.BASE_MS + smoke.SCRAPE_MS * np.arange(4)
-    first = tmpl.fill(values[:, :, :2], ts[:2])
-    second = tmpl.fill(values[:, :, 2:], ts[2:])
+    tags, values = smoke.make_fleet(0, 2, 2)
+    tmpl = smoke.RequestTemplate(tags)
+    first = tmpl.fill(values[:, :, 0], smoke.BASE_MS)
+    second = tmpl.fill(values[:, :, 1], smoke.BASE_MS + smoke.SCRAPE_MS)
     assert len(first) == len(second) and first != second
-    assert tmpl.fill(values[:, :, :2], ts[:2]) == first
+    assert tmpl.fill(values[:, :, 0], smoke.BASE_MS) == first
 
 
 def test_fleet_is_a_function_of_the_seed(smoke):

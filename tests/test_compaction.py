@@ -337,3 +337,29 @@ class TestScopedCompaction:
         assert len(seg0) == 1      # scoped segment compacted
         assert len(seg1) == 3      # out-of-scope segment untouched
         await eng.close()
+
+
+class TestDrain:
+    @async_test
+    async def test_drain_entered_before_a_finished_tasks_callback_returns(self):
+        """A task leaves `_inflight` in its done callback, one loop turn
+        after its last step. A drain that starts in between (the test
+        helpers' poll does, when a timer fires in that same turn) used to
+        await the finished task without ever yielding, so the callback
+        never ran: the watchdog's hang in tests/test_serving.py."""
+        from horaedb_tpu.storage.compaction.executor import Executor
+
+        ex = Executor(None, None, 1 << 30, asyncio.Queue())
+
+        async def nothing():
+            return None
+
+        task = asyncio.create_task(nothing())
+        ex._inflight.add(task)
+        task.add_done_callback(ex._inflight.discard)
+        # both wake-ups sit in one turn of the loop: the task's only step,
+        # then this coroutine, before any done callback
+        await asyncio.sleep(0)
+        assert task.done() and task in ex._inflight
+        await ex.drain()
+        assert not ex._inflight

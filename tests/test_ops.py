@@ -392,15 +392,8 @@ class TestAggregate:
         fills = aggregate.f64_from_order_keys(np.array([i64.max, i64.min]))
         assert fills[0] == np.inf and fills[1] == -np.inf
 
-    @pytest.mark.parametrize("with_nan", [False, True])
-    def test_downsample_sorted_accelerator_minmax_lane_is_exact(
-        self, monkeypatch, with_nan
-    ):
-        """Off the CPU, min/max reduce i64 order keys (f64 is emulated and
-        lossy there): same grids, bit for bit, as the float reduction,
-        empty cells and NaN cells included."""
-        import types
-
+    @staticmethod
+    def _sorted_rows(with_nan=False, wide=False):
         rng = np.random.default_rng(5)
         n, ns, nb = 4000, 8, 6
         sid = np.sort(rng.integers(0, ns - 1, n)).astype(np.int32)  # last series empty
@@ -408,17 +401,98 @@ class TestAggregate:
             np.sort(rng.integers(0, nb * 1000, (sid == s).sum())) for s in range(ns)
         ]).astype(np.int64)
         vals = rng.uniform(-100, 100, n)
+        if wide:  # magnitudes no f32 exponent holds
+            vals[1::5] *= 1e300
+            vals[2::5] *= 1e-300
         if with_nan:
             vals[::97] = np.nan
-        kw = dict(num_series=ns, num_buckets=nb, with_minmax=True)
+        return ts, sid, vals, dict(num_series=ns, num_buckets=nb, with_minmax=True)
+
+    @pytest.mark.parametrize("impl", ["scatter", "block"])
+    @pytest.mark.parametrize("with_nan", [False, True])
+    def test_downsample_sorted_minmax_is_a_stored_sample_bit_for_bit(
+        self, monkeypatch, with_nan, impl
+    ):
+        """The device lane's min/max reduce i64 order keys: the same grids,
+        bit for bit, as numpy's float reduction on the host lane, with empty
+        cells, NaN cells and 1e300 / 1e-300 samples."""
+        ts, sid, vals, kw = self._sorted_rows(with_nan, wide=True)
+        monkeypatch.setenv("HORAEDB_AGG_IMPL", "reduceat")
         want = aggregate.downsample_sorted(ts, sid, vals, 0, 1000, **kw)
-        fake = types.SimpleNamespace(platform="tpu")
-        monkeypatch.setattr(aggregate.jax, "devices", lambda *a: [fake])
+        monkeypatch.setenv("HORAEDB_AGG_IMPL", impl)
         got = aggregate.downsample_sorted(ts, sid, vals, 0, 1000, **kw)
         for stat in ("min", "max"):  # NaN cells compare equal in place
             np.testing.assert_array_equal(
                 np.asarray(got[stat]), np.asarray(want[stat]))
-        np.testing.assert_allclose(np.asarray(got["sum"]), np.asarray(want["sum"]))
+        assert np.abs(np.asarray(got["max"])[np.isfinite(got["max"])]).max() > 1e300
+
+    def test_downsample_sorted_large_grid_takes_the_same_lanes(self, monkeypatch):
+        """Past the f32-exact cell-id range the reductions are plain
+        scatters over the same value and order-key lanes."""
+        from horaedb_tpu.ops import blockagg
+
+        ts, sid, vals, kw = self._sorted_rows(with_nan=True, wide=True)
+        valid = np.arange(len(ts)) % 3 != 0
+        monkeypatch.setenv("HORAEDB_AGG_IMPL", "reduceat")
+        want = aggregate.downsample_sorted(ts, sid, vals, 0, 1000, valid=valid, **kw)
+        monkeypatch.setenv("HORAEDB_AGG_IMPL", "scatter")
+        monkeypatch.setattr(blockagg, "_F32_EXACT", 16)
+        got = aggregate.downsample_sorted(ts, sid, vals, 0, 1000, valid=valid, **kw)
+        for stat in ("min", "max", "count"):
+            np.testing.assert_array_equal(
+                np.asarray(got[stat]), np.asarray(want[stat]))
+        np.testing.assert_allclose(
+            np.asarray(got["sum"]), np.asarray(want["sum"]), rtol=1e-12)
+
+    @pytest.fixture
+    def on_an_accelerator(self, monkeypatch):
+        monkeypatch.setattr(aggregate, "device_f64_is_exact", lambda: False)
+
+    @pytest.mark.parametrize("values,holds", [
+        ([0.0, 1.5, -99.0, np.nan, np.inf], True),
+        ([1e300], False),            # reads back inf from an f32 pair
+        ([1e-300], False),           # reads back 0
+        ([3e38, 3e38], False),       # each fits, the sum does not
+        ([1e-30, 1.0], True),
+    ])
+    def test_device_sums_hold_is_the_f32_pair_range(
+        self, on_an_accelerator, values, holds
+    ):
+        assert aggregate.device_sums_hold(np.asarray(values)) is holds
+
+    def test_device_sums_hold_anything_on_the_cpu(self):
+        assert aggregate.device_sums_hold(np.asarray([1e300, 1e-300]))
+
+    @pytest.mark.parametrize("wide", [False, True])
+    def test_downsample_sorted_off_the_cpu_keeps_wide_values_on_the_host(
+        self, monkeypatch, on_an_accelerator, wide
+    ):
+        """An accelerator's f64 cannot sum 1e300: such a block takes the
+        host lane and says so; a block in range stays on the device lane
+        the dispatcher chose."""
+        from horaedb_tpu.ops import agg_registry
+
+        ts, sid, vals, kw = self._sorted_rows(wide=wide)
+        monkeypatch.setenv("HORAEDB_AGG_IMPL", "scatter")
+        got = aggregate.downsample_sorted(ts, sid, vals, 0, 1000, **kw)
+        assert agg_registry.last_choice() == ("reduceat" if wide else "scatter")
+        want = agg_registry.host_downsample_sorted(ts, sid, vals, 0, 1000, **kw)
+        for stat in ("min", "max", "count"):
+            np.testing.assert_array_equal(
+                np.asarray(got[stat]), np.asarray(want[stat]))
+        np.testing.assert_allclose(
+            np.asarray(got["sum"]), np.asarray(want["sum"]), rtol=1e-12)
+
+    def test_downsample_sorted_off_the_cpu_refuses_an_f64_device_array(
+        self, on_an_accelerator
+    ):
+        import jax.numpy as jnp
+
+        from horaedb_tpu.common.error import HoraeError
+
+        ts, sid, vals, kw = self._sorted_rows()
+        with pytest.raises(HoraeError, match="already lost bits"):
+            aggregate.downsample_sorted(ts, sid, jnp.asarray(vals), 0, 1000, **kw)
 
     def test_segment_last_value(self):
         vals = np.array([1.0, 2.0, 3.0, 4.0])

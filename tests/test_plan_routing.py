@@ -303,3 +303,71 @@ class TestPlannerSelfCalibration:
         assert "path_host_merge" in _routes(st)  # presorted always host
         # the O(n) shortcut must not be folded into the per-row SORT cost
         assert _HostCalib.sort_s_per_row() == before
+
+
+class TestBackendWhoseF64IsNotExact:
+    """An accelerator carries f64 as a pair of f32 (ops/aggregate.py
+    device_f64_is_exact): f64 predicate lanes evaluate on the host and ride
+    the mask lane, f64 sort keys keep the merge on the host."""
+
+    @staticmethod
+    def _inexact(monkeypatch):
+        from horaedb_tpu.ops import aggregate
+
+        monkeypatch.setattr(aggregate, "device_f64_is_exact", lambda: False)
+        monkeypatch.setattr(_LinkProfile, "_cached", dict(FAST_LINK))
+
+    def test_f64_predicate_is_evaluated_on_the_host_then_merged_on_device(
+        self, monkeypatch
+    ):
+        from horaedb_tpu.ops import filter as F
+
+        schema, n, cols = _make_inputs(n=20_000)
+        cols["v"][::3] *= 1e300  # no f32 exponent holds these
+        pred = F.Compare("v", "gt", 1e299)
+        asked = []
+
+        def host_mask():
+            asked.append(1)
+            return F.eval_predicate_np(pred, {"v": cols["v"]})
+
+        def run():
+            return _plan_and_merge(
+                schema, n, lambda name: cols[name], pred, host_mask, False,
+                lambda name: cols[name].dtype.itemsize,
+            )
+
+        monkeypatch.setenv("HORAEDB_SCAN_PATH", "host")
+        want = run()
+        self._inexact(monkeypatch)
+        monkeypatch.setenv("HORAEDB_SCAN_PATH", "device")
+        del asked[:]
+        with scanstats.scan_stats() as st:
+            got = run()
+        assert asked, "the f64 predicate went to the device"
+        assert _routes(st) & {"path_device_merge", "path_device_merge_packed"}
+        np.testing.assert_array_equal(got, want)
+        assert len(got) and np.all(cols["v"][got] > 1e299)
+
+    def test_f64_sort_keys_stay_on_the_host(self, monkeypatch):
+        import pytest
+
+        from horaedb_tpu.common.error import HoraeError
+
+        schema = StorageSchema.try_new(
+            pa.schema([("pk", pa.float64()), ("v", pa.float64())]), 1,
+            UpdateMode.OVERWRITE,
+        )
+        rng = np.random.default_rng(3)
+        n = 50_000
+        cols = {"pk": rng.normal(size=n) * 1e300,
+                "__seq__": np.full(n, 3, dtype=np.uint64),
+                "v": rng.normal(size=n)}
+        self._inexact(monkeypatch)
+        with scanstats.scan_stats() as st:
+            idx = _run(schema, n, cols)
+        assert _routes(st) == {"path_host_f64_keys", "path_host_merge"}, st.counts
+        assert np.all(np.diff(cols["pk"][idx]) > 0) and len(idx) == n
+        monkeypatch.setenv("HORAEDB_SCAN_PATH", "device")
+        with pytest.raises(HoraeError, match="f64 keys"):
+            _run(schema, n, cols)

@@ -405,6 +405,92 @@ class TestAppendMode:
         await eng.close()
 
 
+class TestFusedPassKeepsF64Exact:
+    """The fused filter->sort->dedup pass carries f64 lanes as their i64
+    bits, so a stored sample comes back bit for bit whatever the device's
+    f64 is; where it is not exact (an accelerator: faked here), an f64
+    predicate evaluates on the host and the reduction keeps its selections
+    on integer lanes."""
+
+    WIDE = [1e300, -2.5e-300, 99.99967667212489, -0.0, 3.5e38, 1e-45]
+
+    @pytest.fixture(params=[True, False], ids=["exact_f64", "inexact_f64"])
+    def device_f64(self, request, monkeypatch):
+        from horaedb_tpu.ops import aggregate
+
+        from horaedb_tpu.storage.read import ParquetReader
+
+        monkeypatch.setattr(
+            aggregate, "device_f64_is_exact", lambda: request.param)
+        fused, self.bit_lanes = ParquetReader._fused_pass, []
+
+        def spy(reader, *a, **kw):
+            out = fused(reader, *a, **kw)
+            self.bit_lanes.append(set(out[-1]))
+            return out
+
+        monkeypatch.setattr(ParquetReader, "_fused_pass", spy)
+        return request.param
+
+    @async_test
+    async def test_append_mode_numeric_lane_beside_binary_values(self, device_f64):
+        store = MemStore()
+        schema = pa.schema(
+            [("pk", pa.int64()), ("w", pa.float64()), ("payload", pa.binary())])
+        cfg = StorageConfig(update_mode=UpdateMode.APPEND)
+        eng = await new_engine(store, schema=schema, num_pks=1, config=cfg)
+        n = len(self.WIDE)
+        batch = pa.RecordBatch.from_pydict(
+            {"pk": np.arange(n, dtype=np.int64)[::-1].copy(),
+             "w": np.asarray(self.WIDE), "payload": [b"x"] * n}, schema=schema)
+        await eng.write(WriteRequest(batch, TimeRange(10, 11)))
+        for pred, keep in ((None, list(range(n))),
+                           (F.Compare("w", "gt", 1e299), [0])):
+            t = await collect(
+                eng, ScanRequest(range=TimeRange(0, SEGMENT_MS), predicate=pred))
+            want = np.asarray(self.WIDE)[keep][::-1]  # pk ascending
+            got = t.column("w").to_numpy()
+            np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+        # the predicate's lane stays f64 only where the device evaluates it
+        assert self.bit_lanes == [{"w"}, {"w"} if not device_f64 else set()]
+        await eng.close()
+
+    @async_test
+    async def test_downsample_pushdown_through_the_fused_pass(self, device_f64):
+        store = MemStore()
+        eng = await new_engine(store, num_pks=3)  # ts is part of the key
+        schema = make_schema()
+        rng = np.random.default_rng(2)
+        n, series = 600, 4
+        sid = rng.integers(0, series, n)
+        ts = rng.permutation(n) * 10
+        vals = rng.uniform(-100, 100, n)
+        vals[::7] *= 1e300
+        vals[1::7] *= 1e-300
+        for lo in range(0, n, 200):  # three overlapping SSTs
+            sl = slice(lo, lo + 200)
+            await eng.write(WriteRequest(
+                make_batch(schema, sid[sl], sid[sl], ts[sl], vals[sl]),
+                TimeRange(0, n * 10)))
+        ssts = eng.manifest.all_ssts()
+        grids = await eng.parquet_reader.scan_segment_downsample(
+            ssts, None, "ts", "value", "pk1", np.arange(series), 0, 1000, 6,
+            packed_ok=False)
+        want_mx = np.full((series, 6), -np.inf)
+        want_mn = np.full((series, 6), np.inf)
+        want_sum = np.zeros((series, 6))
+        for s, t, v in zip(sid, ts, vals):
+            want_mx[s, t // 1000] = max(want_mx[s, t // 1000], v)
+            want_mn[s, t // 1000] = min(want_mn[s, t // 1000], v)
+            want_sum[s, t // 1000] += v
+        np.testing.assert_array_equal(grids["max"], want_mx)
+        np.testing.assert_array_equal(grids["min"], want_mn)
+        np.testing.assert_allclose(grids["sum"], want_sum, rtol=1e-9)
+        assert grids["count"].sum() == n
+        assert self.bit_lanes == [{"value"}]
+        await eng.close()
+
+
 class TestBinaryPrimaryKeys:
     """The reference compares binary pks too (macros.rs dispatch); here the
     host path handles them (sort/dedup via arrow compute)."""

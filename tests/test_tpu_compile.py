@@ -125,6 +125,7 @@ def test_stacked_downsample(spec):
         spec((1, rows), jnp.float64), spec((1, rows), jnp.bool_),
         spec((1,), jnp.int64), spec((), jnp.int64),
         num_series=1024, num_buckets=BUCKETS,
+        order_keys=(spec((1, rows), jnp.int64), spec((1, rows), jnp.int64)),
     )
 
 
@@ -185,6 +186,19 @@ def test_downsample_sorted_f64(spec):
     )
 
 
+def test_min_max_over_order_keys(spec):
+    """The pushdown's selections: min/max of the i64 order keys the host
+    builds from the f64 values (ops/aggregate.py f64_order_keys)."""
+    def select(cells, keys, ok):
+        return blockagg.sorted_segment_min_max(
+            cells, keys, HOSTS * BUCKETS, impl="scatter", valid=ok)
+
+    compile_for_chip(
+        jax.jit(select),
+        spec(ROWS, jnp.int32), spec(ROWS, jnp.int64), spec(ROWS, jnp.bool_),
+    )
+
+
 # -- sort / merge -------------------------------------------------------------
 
 
@@ -221,8 +235,9 @@ def test_index_merge_filter(spec):
 @pytest.mark.parametrize("presorted", [False, True])
 def test_scan_kernel(spec, presorted):
     """storage/read.py `scan_kernel`: filter -> sort -> dedup over every
-    numeric lane of the data table plus the dense series id."""
-    lanes = dict(DATA_LANES, __sid__=jnp.int32)
+    numeric lane of the data table plus the dense series id; the f64 value
+    lane crosses as its i64 bits (`_fused_pass`)."""
+    lanes = dict(DATA_LANES, value=jnp.int64, __sid__=jnp.int32)
     names = tuple(lanes)
     dtypes = {k: np.dtype(v) for k, v in lanes.items()}
     template, literals = query_template(dtypes)

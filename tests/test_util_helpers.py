@@ -69,3 +69,24 @@ def test_every_test_runs_under_the_watchdog():
     remaining, _ = signal.getitimer(signal.ITIMER_REAL)
     assert 0 < remaining <= conftest.TEST_WATCHDOG_S
     assert signal.getsignal(signal.SIGALRM) not in (signal.SIG_DFL, signal.SIG_IGN, None)
+
+
+def test_the_watchdog_names_the_await_a_hung_test_is_parked_on():
+    """A hung async test is an await that never resolves; the alarm's
+    message carries every pending task's stack (no thread stack has it)."""
+    import asyncio
+
+    from tests import conftest
+
+    async def parked_here():
+        await asyncio.Event().wait()
+
+    async def main():
+        task = asyncio.create_task(parked_here())
+        await asyncio.sleep(0)
+        stacks = conftest._pending_task_stacks()
+        task.cancel()
+        return stacks
+
+    assert "parked_here" in asyncio.run(main())
+    assert conftest._pending_task_stacks() == ""  # no loop, nothing to say
