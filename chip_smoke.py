@@ -647,8 +647,9 @@ def run(args, server: Server) -> dict:
     k = dump_kernels(server, args.report_dir)
     compiled = {e["kernel"]: e["compiles"] for e in k["kernels"] if e["compiles"]}
     metrics = server.metrics()
-    device_merges = metrics.get('horaedb_scan_path_total{path="device"}', 0.0)
-    host_merges = metrics.get('horaedb_scan_path_total{path="host"}', 0.0)
+    device_merges = sum(v for k2, v in metrics.items() if k2.startswith(
+        'horaedb_scan_path_total{path="device_merge'))
+    host_merges = metrics.get('horaedb_scan_path_total{path="host_merge"}', 0.0)
     device = {"platform": k["platform"], "kind": k["device_kind"],
               "count": k["device_count"]}
     emit("kernels", t0, **device, kernels_compiled=compiled,
@@ -665,7 +666,7 @@ def run(args, server: Server) -> dict:
     require(AGG_KERNELS & set(compiled), "no aggregation kernel was compiled")
     require(MERGE_KERNELS & set(compiled), "no merge/sort kernel was compiled")
     require(device_merges > 0, "no merge ran on the device "
-            '(horaedb_scan_path_total{path="device"} is 0)')
+            '(horaedb_scan_path_total{path="device_merge*"} is 0)')
     return device
 
 

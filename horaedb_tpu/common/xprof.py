@@ -2,12 +2,9 @@
 
 PR 2 made the host side observable (request traces, per-stage lane
 histograms) and PR 3 added dispatcher provenance — but the device side
-stayed a black box: nothing recorded when JAX recompiled a hot kernel,
-what a compiled kernel's FLOPs/bytes envelope was, or how much of a slow
-request was compile time rather than steady-state execution. "When Is a
-Columnar Scan Bandwidth-Bound?" (PAPERS.md) argues the attribution that
-matters is predicted arithmetic intensity vs achieved throughput; this
-module supplies the predicted side.
+stayed a black box: nothing recorded when JAX recompiled a hot kernel or
+how much of a slow request was compile time rather than steady-state
+execution.
 
 `xjit` wraps `jax.jit` and every hot-path jitted entry point (ops/,
 parallel/, storage/read.py — enforced by jaxlint J007) routes through it:
@@ -24,10 +21,21 @@ Per kernel it records:
   arg-signature (shapes/dtypes/static values) that triggered the
   retrace — the #1 question when a steady workload suddenly stalls is
   "what shape churned the cache";
-- distinct-signature count: `horaedb_jit_cache_entries{kernel}`;
-- where the backend supports it, `lowered.compile().cost_analysis()` /
-  `memory_analysis()` — the predicted FLOPs/bytes envelope served at
-  GET /debug/kernels and folded into query EXPLAIN.
+- distinct-signature count: `horaedb_jit_cache_entries{kernel}`.
+
+The jitted function carries the `kernel=` label as its name, so the
+device's trace reads `jit_<kernel>` (never `jit_kernel`), and every call
+runs under a `TraceAnnotation("xjit.<kernel>")`, so the host side of a
+dispatch — and a compile under it — has a name on the profiler's
+timeline. What a kernel should cost comes from the benchmark's work
+functions and the device trace (bench_chip/work/), not from here.
+
+Beside the catalog, `register_metrics()` hangs two `jax.monitoring`
+listeners: EVERY XLA compile of the process, the eager `jnp` ones no xjit
+sees included, lands in `horaedb_xla_compile_seconds` (count = compiles
+and loads from the persistent cache, sum = their seconds) and every
+persistent-cache hit in `horaedb_xla_cache_hits_total`. A compile outside
+an xjit call also goes to the request's `compile` lane.
 
 Detection mechanism: the traced wrapper body only executes when JAX
 (re)traces — a cache hit never enters Python beyond the jit dispatch — so
@@ -42,31 +50,25 @@ Honest accounting notes:
   traced inside an outer xjit compile) count their trace time under both
   kernels, so per-kernel compile sums can exceed wall clock, exactly
   like overlapping scanstats stages.
-- cost/memory analysis requires an extra `lower().compile()` per
-  captured signature. `HORAEDB_XPROF_COST` bounds it: `first` (default)
-  pays it once per kernel, `all` per new signature, `off` never.
 
 Knobs:
     HORAEDB_XPROF       off -> xjit degrades to plain jax.jit (no
                         telemetry, no catalog)
-    HORAEDB_XPROF_COST  first | all | off (cost-analysis capture)
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 import os
 import threading
 import time
 from contextvars import ContextVar
 
 import jax
-
-logger = logging.getLogger(__name__)
+from jax.profiler import TraceAnnotation
 
 __all__ = ["xjit", "XJit", "catalog", "snapshot", "kernel_entries", "reset",
-           "register_metrics"]
+           "register_metrics", "xla_totals"]
 
 # Compile-latency buckets: traces are >=ms, XLA compiles span 10ms-minutes.
 COMPILE_BUCKETS = (
@@ -81,13 +83,20 @@ COMPILE_BUCKETS = (
 # is idempotent; server/main.py calls register_metrics() at boot so the
 # zero-state families render on /metrics before the first compile.
 _metric_families = None
+_xla_families = None
 _metrics_lock = threading.Lock()
 
 
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
 def register_metrics():
-    """(compile_total, compile_seconds, cache_entries) families, creating
-    them in the process registry on first call."""
-    global _metric_families
+    """(compile_total, compile_seconds, cache_entries) families of the
+    xjit kernels, creating them in the process registry on first call,
+    and with them the two `horaedb_xla_*` families and the jax.monitoring
+    listeners that feed them."""
+    global _metric_families, _xla_families
     if _metric_families is None:
         with _metrics_lock:
             if _metric_families is None:
@@ -116,12 +125,56 @@ def register_metrics():
                         labelnames=("kernel",),
                     ),
                 )
+                _xla_families = (
+                    GLOBAL_METRICS.histogram(
+                        "horaedb_xla_compile_seconds",
+                        help="Every XLA backend compile of the process, "
+                             "eager jnp operations included: count = "
+                             "compiles and persistent-cache loads, sum = "
+                             "their seconds.",
+                        buckets=COMPILE_BUCKETS,
+                    ),
+                    GLOBAL_METRICS.counter(
+                        "horaedb_xla_cache_hits_total",
+                        help="Compiles answered by JAX's persistent "
+                             "compilation cache.",
+                    ),
+                )
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_duration)
+                jax.monitoring.register_event_listener(_on_event)
     return _metric_families
+
+
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    if event != _BACKEND_COMPILE:
+        return
+    _xla_families[0].observe(duration)
+    if _TRACE_BOX.get() is None:
+        # an eager compile (or any outside an XJit call): nobody else hands
+        # it to the request's compile lane; inside one, _record_compile does
+        _scanstats().record("compile", duration)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _xla_families[1].inc()
+
+
+def xla_totals() -> dict:
+    """Every XLA compile since boot (the `xla` object of /debug/kernels)."""
+    register_metrics()
+    compiles, hits = (f.labels() for f in _xla_families)
+    return {
+        "compiles": compiles.count,
+        "compile_seconds": round(compiles.sum, 6),
+        "cache_hits": int(hits.value),
+    }
 
 # Sentinel box: a list the traced wrapper appends the triggering signature
 # to. Context-local so concurrent asyncio requests cannot claim each
-# other's compiles; None outside an XJit.__call__ (which also makes the
-# wrapper a no-op during internal cost-capture lowering — no recursion).
+# other's compiles; None outside an XJit.__call__ (where the compile
+# listener, not the wrapper, owns the request's compile lane).
 _TRACE_BOX: ContextVar["list | None"] = ContextVar("horaedb_xprof_box",
                                                    default=None)
 
@@ -136,11 +189,6 @@ _REGISTRY: dict[str, "_KernelStats"] = {}
 
 _MAX_SIGNATURES = 64      # per-instance signature memory bound
 _SIG_LEAVES = 16          # leaves rendered per signature
-
-
-def _cost_mode() -> str:
-    mode = os.environ.get("HORAEDB_XPROF_COST", "first")
-    return mode if mode in ("first", "all", "off") else "first"
 
 
 def _signature(args: tuple, kwargs: dict) -> str:
@@ -179,52 +227,13 @@ def _scanstats():
     return _scanstats_mod
 
 
-def _has_tracer(args: tuple, kwargs: dict) -> bool:
-    return any(
-        isinstance(leaf, jax.core.Tracer)
-        for leaf in jax.tree_util.tree_leaves((args, kwargs))
-    )
-
-
-def _memory_dict(mem) -> dict | None:
-    """Flatten a backend memory_analysis object to plain ints (the exposed
-    attribute set varies by backend/version; probe, don't assume)."""
-    if mem is None:
-        return None
-    out = {}
-    for attr in (
-        "argument_size_in_bytes", "output_size_in_bytes",
-        "temp_size_in_bytes", "generated_code_size_in_bytes",
-        "alias_size_in_bytes", "peak_memory_in_bytes",
-    ):
-        v = getattr(mem, attr, None)
-        if isinstance(v, (int, float)):
-            out[attr] = int(v)
-    return out or None
-
-
-def _cost_dict(cost) -> dict | None:
-    """Scalar entries of cost_analysis (list-wrapped on some versions);
-    per-operand breakdowns are dropped — the envelope is flops + bytes."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    if not isinstance(cost, dict):
-        return None
-    out = {
-        k: float(v) for k, v in cost.items()
-        if isinstance(v, (int, float)) and "{" not in str(k)
-    }
-    return dict(sorted(out.items())[:24]) or None
-
-
 class _KernelStats:
     """Per-kernel-NAME telemetry, shared by every XJit instance carrying
     the name (one per memoized shape variant). Own lock — instances come
     and go, the stats object is process-lifetime."""
 
     __slots__ = ("kernel", "lock", "instances", "compiles",
-                 "compile_seconds", "signatures", "cost", "memory",
-                 "last_compile_ms")
+                 "compile_seconds", "signatures", "last_compile_ms")
 
     def __init__(self, kernel: str):
         self.kernel = kernel
@@ -233,16 +242,12 @@ class _KernelStats:
         self.compiles = 0
         self.compile_seconds = 0.0
         self.signatures: dict[str, int] = {}
-        self.cost: dict | None = None
-        self.memory: dict | None = None
         self.last_compile_ms: float | None = None
 
     def snapshot(self) -> dict:
         with self.lock:
-            cost = dict(self.cost) if self.cost else None
-            mem = dict(self.memory) if self.memory else None
             sigs = dict(self.signatures)
-            out = {
+            return {
                 "kernel": self.kernel,
                 "instances": self.instances,
                 "compiles": self.compiles,
@@ -251,19 +256,6 @@ class _KernelStats:
                 "signatures": sigs,
                 "last_compile_ms": self.last_compile_ms,
             }
-        flops = (cost or {}).get("flops")
-        bytes_accessed = (cost or {}).get("bytes accessed")
-        out.update({
-            "flops": flops,
-            "bytes_accessed": bytes_accessed,
-            "arithmetic_intensity": (
-                round(flops / bytes_accessed, 4)
-                if flops and bytes_accessed else None
-            ),
-            "cost": cost,
-            "memory": mem,
-        })
-        return out
 
 
 def _stats_for(kernel: str) -> _KernelStats:
@@ -296,29 +288,33 @@ class XJit:
         # static_argnames to positions) see the REAL parameter list
         # through the (*args, **kwargs) wrapper
         functools.update_wrapper(_traced, fn)
+        # the program's name on the device: `jit_<kernel>` in the trace's
+        # XLA Modules line, not the Python function's (seven are `kernel`)
+        _traced.__name__ = _traced.__qualname__ = kernel
         self._jitted = jax.jit(_traced, **jit_kwargs)
+        self._annotation = f"xjit.{kernel}"
 
     def __call__(self, *args, **kwargs):
         box: list = []
         token = _TRACE_BOX.set(box)
         t0 = time.perf_counter()
         try:
-            out = self._jitted(*args, **kwargs)
+            with TraceAnnotation(self._annotation):
+                out = self._jitted(*args, **kwargs)
         finally:
             _TRACE_BOX.reset(token)
         if box:
-            self._record_compile(box[-1], time.perf_counter() - t0,
-                                 args, kwargs)
+            self._record_compile(box[-1], time.perf_counter() - t0)
         _scanstats().kernel_use(self.kernel)
         return out
 
     def lower(self, *args, **kwargs):
-        """AOT lowering passthrough (plan-shape tests, cost capture)."""
+        """AOT lowering passthrough (plan-shape tests)."""
         return self._jitted.lower(*args, **kwargs)
 
     # -- telemetry ----------------------------------------------------------
 
-    def _record_compile(self, sig: str, dt: float, args, kwargs) -> None:
+    def _record_compile(self, sig: str, dt: float) -> None:
         stats = self._stats
         with stats.lock:
             stats.compiles += 1
@@ -328,10 +324,6 @@ class XJit:
                 stats.signatures.pop(next(iter(stats.signatures)))
             stats.last_compile_ms = time.time() * 1000.0
             n_sigs = len(stats.signatures)
-            want_cost = (
-                (_cost_mode() == "first" and stats.cost is None)
-                or _cost_mode() == "all"
-            )
         compile_total, compile_seconds, cache_entries = register_metrics()
         compile_total.labels(self.kernel).inc()
         compile_seconds.labels(self.kernel).observe(dt)
@@ -340,35 +332,6 @@ class XJit:
         # active trace span: compile becomes a first-class lane next to
         # io/transfer/kernel in the roofline attribution
         _scanstats().record("compile", dt)
-        if want_cost and not _has_tracer(args, kwargs):
-            self._capture_cost(args, kwargs)
-
-    def _capture_cost(self, args, kwargs) -> None:
-        """Predicted FLOPs/bytes envelope via AOT compile. Pays one extra
-        XLA compile (the _TRACE_BOX default of None makes the wrapper
-        inert here, so this never re-enters _record_compile); bounded by
-        HORAEDB_XPROF_COST. Backends without analysis support just leave
-        the catalog entry envelope-less."""
-        try:
-            compiled = self._jitted.lower(*args, **kwargs).compile()
-        except Exception:  # noqa: BLE001 — AOT quirks must never fail a query
-            logger.debug("xprof: cost-capture lowering failed for %s",
-                         self.kernel, exc_info=True)
-            return
-        cost = mem = None
-        try:
-            cost = _cost_dict(compiled.cost_analysis())
-        except Exception:  # noqa: BLE001 — backend-dependent surface
-            pass
-        try:
-            mem = _memory_dict(compiled.memory_analysis())
-        except Exception:  # noqa: BLE001 — backend-dependent surface
-            pass
-        with self._stats.lock:
-            if cost is not None:
-                self._stats.cost = cost
-            if mem is not None:
-                self._stats.memory = mem
 
     def stats(self) -> dict:
         """This kernel NAME's merged telemetry (shared across shape
@@ -411,7 +374,7 @@ def catalog() -> list[dict]:
 
 def kernel_entries(names) -> list[dict]:
     """Catalog entries for the named kernels only (query EXPLAIN embeds
-    the envelope of just the kernels the request invoked)."""
+    just the kernels the request invoked)."""
     wanted = set(names)
     with _REG_LOCK:
         stats = [v for k, v in sorted(_REGISTRY.items()) if k in wanted]
@@ -442,6 +405,4 @@ def reset() -> None:
             s.compiles = 0
             s.compile_seconds = 0.0
             s.signatures.clear()
-            s.cost = None
-            s.memory = None
             s.last_compile_ms = None

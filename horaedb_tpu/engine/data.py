@@ -13,7 +13,6 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
-import time
 
 import numpy as np
 import pyarrow as pa
@@ -23,7 +22,6 @@ from horaedb_tpu.common.aio import TaskGroup
 from horaedb_tpu.engine.flush_executor import (
     FLUSH_FAILURES_TOTAL,
     FLUSH_OVERLAP_RATIO,
-    FLUSH_STAGE_SECONDS,
     FlushExecutor,
     SealedMemtable,
 )
@@ -116,6 +114,9 @@ class SampleManager:
         # Observability identity: the storage root is region-qualified
         # ("metrics/region-0/data") so flush logs/metrics name the region.
         self._table_id = getattr(storage, "_root", None) or "data"
+        # the table's flush stages in the one stage funnel: this manager
+        # observes `drain`, the storage's writes the rest
+        self._flush = scanstats.flush_family(self._table_id)
         # pre-register the flush families' children so /metrics exposes
         # them (zero state) before the first write-out
         for fam in (FLUSH_SECONDS, FLUSH_ROWS, FLUSH_FAILURES, LATE_SAMPLES):
@@ -439,43 +440,40 @@ class SampleManager:
         has_accum = self._accum is not None and self._accum.rows
         if not (self._buffered or has_accum):
             return None
-        t0 = time.perf_counter()
-        buf, self._buf = self._buf, {}
-        keys, self._dense_keys = self._dense_keys, []
-        self._dense = {}
-        cols_view = None
-        backing = None
-        block = None
-        if self._fill:
-            backing = self._cols
-            # the sealed rows travel as ONE frozen column block: read-only
-            # zero-copy views of the arena's filled prefix (the drain reads
-            # them in place — the old recycled-array copy is gone), while
-            # the writable backing recycles into the spare pool after the
-            # write-out lands
-            block = colblock.ColBlock.wrap({
-                "__series__": backing[0][: self._fill],
-                "ts": backing[1][: self._fill],
-                "value": backing[2][: self._fill],
-            }).freeze()
-            memtrace.track_bytes(block.nbytes, "seal", "view")
-            cols_view = tuple(
-                block.lane(k) for k in ("__series__", "ts", "value")
-            )
-            self._cols = None
-            self._fill = 0
-        rows = self._buffered
-        self._buffered = 0
-        lanes = None
-        if has_accum:
-            # synchronous C++ drain: pk-sorted lanes copied out, arena
-            # cleared — part of the same atomic swap
-            lanes = self._accum.take_sorted()
-            rows += len(lanes[2])
-        seq = allocate_id()
-        FLUSH_STAGE_SECONDS.labels(self._table_id, "drain").observe(
-            time.perf_counter() - t0
-        )
+        with self._flush.stage("drain"):
+            buf, self._buf = self._buf, {}
+            keys, self._dense_keys = self._dense_keys, []
+            self._dense = {}
+            cols_view = None
+            backing = None
+            block = None
+            if self._fill:
+                backing = self._cols
+                # the sealed rows travel as ONE frozen column block: read-only
+                # zero-copy views of the arena's filled prefix (the drain reads
+                # them in place — the old recycled-array copy is gone), while
+                # the writable backing recycles into the spare pool after the
+                # write-out lands
+                block = colblock.ColBlock.wrap({
+                    "__series__": backing[0][: self._fill],
+                    "ts": backing[1][: self._fill],
+                    "value": backing[2][: self._fill],
+                }).freeze()
+                memtrace.track_bytes(block.nbytes, "seal", "view")
+                cols_view = tuple(
+                    block.lane(k) for k in ("__series__", "ts", "value")
+                )
+                self._cols = None
+                self._fill = 0
+            rows = self._buffered
+            self._buffered = 0
+            lanes = None
+            if has_accum:
+                # synchronous C++ drain: pk-sorted lanes copied out, arena
+                # cleared — part of the same atomic swap
+                lanes = self._accum.take_sorted()
+                rows += len(lanes[2])
+            seq = allocate_id()
         return SealedMemtable(
             seq=seq, rows=rows, buf=buf, cols=cols_view, keys=keys,
             cols_backing=backing, lanes=lanes, block=block,
@@ -786,39 +784,36 @@ class SampleManager:
         in time order, so within a series the append order already sorts
         ts — verified in O(n); only genuinely out-of-order data pays a
         full lexsort."""
-        t0 = time.perf_counter()
-        dense_ps, ts, vals = cols
-        k = len(keys)
-        key_arr = np.empty((k, 2), dtype=np.uint64)
-        for i, (m, t) in enumerate(keys):
-            key_arr[i, 0] = m
-            key_arr[i, 1] = t
-        order = np.lexsort((key_arr[:, 1], key_arr[:, 0]))  # rank over k keys
-        rank_of_dense = np.empty(k, dtype=np.int64)
-        rank_of_dense[order] = np.arange(k)
-        rank_ps = rank_of_dense[dense_ps].astype(np.int32)
-        # stable radix argsort over small int ranks (numpy uses radix for
-        # integer stable sorts — effectively linear, far cheaper than a
-        # 3-key u64 lexsort)
-        perm = np.argsort(rank_ps, kind="stable")
-        counts = np.bincount(rank_ps, minlength=k)  # indexed by rank
-        mid = key_arr[order, 0].repeat(counts)
-        tsid = key_arr[order, 1].repeat(counts)
-        ts = ts[perm]
-        vals = vals[perm]
-        # ts must be nondecreasing within each series group; a decrease is
-        # only legal exactly at a group boundary
-        dips = np.flatnonzero(np.diff(ts) < 0)
-        boundaries = np.cumsum(counts)[:-1] - 1
-        if np.setdiff1d(dips, boundaries).size:
-            perm2 = np.lexsort((ts, tsid, mid))
-            mid, tsid, ts, vals = mid[perm2], tsid[perm2], ts[perm2], vals[perm2]
-        seg = ts - (ts % self._segment_duration)
-        uniq = np.unique(seg)
         # pk-rank sort is the drain's CPU cost (encode/upload time below)
-        FLUSH_STAGE_SECONDS.labels(self._table_id, "drain").observe(
-            time.perf_counter() - t0
-        )
+        with self._flush.stage("drain"):
+            dense_ps, ts, vals = cols
+            k = len(keys)
+            key_arr = np.empty((k, 2), dtype=np.uint64)
+            for i, (m, t) in enumerate(keys):
+                key_arr[i, 0] = m
+                key_arr[i, 1] = t
+            order = np.lexsort((key_arr[:, 1], key_arr[:, 0]))  # rank over k keys
+            rank_of_dense = np.empty(k, dtype=np.int64)
+            rank_of_dense[order] = np.arange(k)
+            rank_ps = rank_of_dense[dense_ps].astype(np.int32)
+            # stable radix argsort over small int ranks (numpy uses radix for
+            # integer stable sorts — effectively linear, far cheaper than a
+            # 3-key u64 lexsort)
+            perm = np.argsort(rank_ps, kind="stable")
+            counts = np.bincount(rank_ps, minlength=k)  # indexed by rank
+            mid = key_arr[order, 0].repeat(counts)
+            tsid = key_arr[order, 1].repeat(counts)
+            ts = ts[perm]
+            vals = vals[perm]
+            # ts must be nondecreasing within each series group; a decrease is
+            # only legal exactly at a group boundary
+            dips = np.flatnonzero(np.diff(ts) < 0)
+            boundaries = np.cumsum(counts)[:-1] - 1
+            if np.setdiff1d(dips, boundaries).size:
+                perm2 = np.lexsort((ts, tsid, mid))
+                mid, tsid, ts, vals = mid[perm2], tsid[perm2], ts[perm2], vals[perm2]
+            seg = ts - (ts % self._segment_duration)
+            uniq = np.unique(seg)
         for seg_start in uniq:
             m = seg == seg_start if len(uniq) > 1 else slice(None)
             await self._write_segment(mid[m], tsid[m], ts[m], vals[m], seq=seq, fast=True)

@@ -637,7 +637,7 @@ class MetricEngine:
 
         from horaedb_tpu.ingest import ParserPool
 
-        from horaedb_tpu.ingest.pooled_parser import PARSE_SECONDS
+        from horaedb_tpu.ingest.pooled_parser import STAGES
 
         if self._pool is None:
             self._pool = ParserPool()
@@ -651,19 +651,21 @@ class MetricEngine:
         async with self._pool.borrow() as parser:
             if not isinstance(parser, NativeParser):
                 with tracing.span("parse", bytes=len(payload)), \
-                        PARSE_SECONDS.time():
-                    parsed = await asyncio.to_thread(parser.parse, payload)
+                        STAGES.stage("parse"):
+                    parsed = await asyncio.to_thread(
+                        STAGES.on_worker, "parse", parser.parse, payload)
                 with tracing.span("append", samples=parsed.n_samples):
                     return await self.write_parsed(parsed)
             # small payloads parse inline: the native parse runs ~1 GB/s, so
             # a sub-256KB payload blocks the loop far less than a thread
             # handoff costs (~100us)
             with tracing.span("parse", bytes=len(payload)), \
-                    PARSE_SECONDS.time():
+                    STAGES.stage("parse"):
                 if len(payload) <= 256 * 1024:
                     req = parser.parse_light(payload)
                 else:
-                    req = await asyncio.to_thread(parser.parse_light, payload)
+                    req = await asyncio.to_thread(
+                        STAGES.on_worker, "parse", parser.parse_light, payload)
             if len(req.meta_type):
                 self._record_metadata(req)
             if req.n_series == 0:

@@ -42,6 +42,7 @@ import numpy as np
 from horaedb_tpu.common import tracing
 from horaedb_tpu.common.error import UnavailableError
 from horaedb_tpu.server.metrics import GLOBAL_METRICS
+from horaedb_tpu.storage import scanstats
 
 logger = logging.getLogger(__name__)
 
@@ -57,18 +58,6 @@ INGEST_STALL_SECONDS = GLOBAL_METRICS.histogram(
          "stalls on the condition variable), by table. A fat tail means "
          "flush bandwidth — not parse — is the ingest ceiling.",
     labelnames=("table",),
-)
-# storage.py observes the encode/upload stages of flush-profile SST writes
-# into this same family (the registry is idempotent by name); the drain
-# stage is observed by the SampleManager's seal/sort.
-FLUSH_STAGE_SECONDS = GLOBAL_METRICS.histogram(
-    "horaedb_flush_stage_seconds",
-    help="Per-stage flush cost: drain (memtable -> pk-sorted column "
-         "lanes), encode (parquet), upload (object-store PUT).",
-    labelnames=("table", "stage"),
-    # OpenMetrics exemplars: a slow flush stage names the trace that
-    # paid it (telemetry package wires the source)
-    exemplars=True,
 )
 FLUSH_FAILURES_TOTAL = GLOBAL_METRICS.counter(
     "horaedb_flush_failures_total",
@@ -157,7 +146,7 @@ class FlushExecutor:
         FLUSH_FAILURES_TOTAL.labels(table_id)
         FLUSH_OVERLAP_RATIO.labels(table_id)
         for stage in ("drain", "encode", "upload"):
-            FLUSH_STAGE_SECONDS.labels(table_id, stage)
+            scanstats.FLUSH_STAGE_SECONDS.labels(table_id, stage)
         self._depth.set(0)
 
     # -- state ---------------------------------------------------------------

@@ -15,6 +15,7 @@ import numpy as np
 from horaedb_tpu.common import colblock, memtrace, tracing
 from horaedb_tpu.ingest.types import ParsedWriteRequest
 from horaedb_tpu.server.metrics import GLOBAL_METRICS
+from horaedb_tpu.storage import scanstats
 
 logger = logging.getLogger(__name__)
 
@@ -76,6 +77,13 @@ POOL_WAIT_SECONDS = GLOBAL_METRICS.histogram(
          "means POOL_SIZE is the ingest bottleneck.",
 )
 
+
+# the ingest front's stages in the one stage funnel (storage/scanstats.py):
+# `ingest.parse` is work; `ingest.pool_wait` matches the trace reduction's
+# WAITS pattern, so waiting for an arena never reads as work
+STAGES = scanstats.Family(
+    "ingest", {"parse": PARSE_SECONDS, "pool_wait": POOL_WAIT_SECONDS}.__getitem__
+)
 
 _BACKEND: str | None = None
 
@@ -139,8 +147,9 @@ class ParserPool:
             # native parse releases no GIL-bound state we await on; run in a
             # thread so large payloads don't stall the event loop
             with tracing.span("parse", bytes=len(payload)), \
-                    PARSE_SECONDS.time():
-                return await asyncio.to_thread(parser.parse, payload)
+                    STAGES.stage("parse"):
+                return await asyncio.to_thread(
+                    STAGES.on_worker, "parse", parser.parse, payload)
 
     def borrow(self):
         """Async context manager lending a parser backend for multi-call use
@@ -168,7 +177,7 @@ class _Borrow:
         pool = self._pool
         pool._waiting += 1
         try:
-            with POOL_WAIT_SECONDS.time():
+            with STAGES.stage("pool_wait"):
                 await pool._sem.acquire()
         finally:
             pool._waiting -= 1

@@ -38,14 +38,6 @@ H2D_BYTES = GLOBAL_METRICS.counter(
     "horaedb_h2d_transfer_bytes_total",
     help="Bytes placed onto the mesh by sharded scans.",
 )
-# same family storage/read.py registers (registration is idempotent): the
-# mesh downsample is a distinct "sharded" route entry point
-SCAN_PATH = GLOBAL_METRICS.counter(
-    "horaedb_scan_path_total",
-    help="Merge route the scan planner took (host SIMD, single-device "
-         "kernel, or the cross-chip sharded merge).",
-    labelnames=("path",),
-)
 
 
 def _local_grids(ts, sid, vals, valid, t0, bucket_ms, series_lo, local_series,
@@ -216,7 +208,6 @@ def sharded_downsample(
     # cooperative deadline before the device dispatch (host side, outside
     # the traced body): an expired query launches no kernel
     deadline_ctx.check("device_lane")
-    SCAN_PATH.labels("sharded").inc()
     template, literals = filter_ops.split_literals(predicate)
     fn = build_sharded_downsample(
         mesh, num_series, num_buckets, template, with_minmax, sorted_input

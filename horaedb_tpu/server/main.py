@@ -1799,10 +1799,9 @@ async def handle_debug_trace(request: web.Request) -> web.Response:
 
 async def handle_debug_kernels(request: web.Request) -> web.Response:
     """Process-wide instrumented-kernel catalog (common/xprof.py): per
-    kernel, the compile/retrace history, distinct arg-signatures, and —
-    where the backend supports cost/memory analysis — the predicted
-    FLOPs/bytes envelope with its arithmetic intensity. The static half of
-    the roofline story; /metrics' stage histograms are the measured half."""
+    xjit kernel, the compile/retrace history and distinct arg-signatures;
+    under `xla`, EVERY backend compile of the process (eager `jnp`
+    operations included) and the persistent cache's hits."""
     import jax
 
     devices = jax.devices()
@@ -1813,6 +1812,7 @@ async def handle_debug_kernels(request: web.Request) -> web.Response:
         "device_count": len(devices),
         "compile_cache_dir": jax.config.jax_compilation_cache_dir,
         "totals": xprof.snapshot(),
+        "xla": xprof.xla_totals(),
         "kernels": xprof.catalog(),
     })
 
@@ -1851,15 +1851,74 @@ async def handle_debug_memory(request: web.Request) -> web.Response:
     the common/bytebudget registry), process RSS, the per-stage copy-tax
     table accumulated since boot, and the memtrace mode. Every number is
     a read-back of state the process already keeps; the handler computes
-    nothing new."""
+    nothing new. `device`: the accelerator's own memory statistics, of
+    the fullest local device (only the process that holds the chip can
+    read them)."""
     pools = GLOBAL_POOLS.refresh()
     return web.json_response({
         "memtrace_mode": memtrace.mode() or "default",
         "rss_bytes": rss_bytes(),
+        "device": _device_memory(),
         "pools": pools,
         # since-boot lineage aggregate, sorted by bytes moved: the
         # fleet-independent face of the per-query EXPLAIN verdict
         "copy_tax": memtrace.copy_tax_table(),
+    })
+
+
+def _device_memory() -> dict:
+    """`memory_stats()` of the local device with the highest peak (a CPU
+    backend reports none: `{}`)."""
+    import jax
+
+    worst: dict = {}
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        if stats.get("peak_bytes_in_use", -1) >= worst.get("peak_bytes_in_use", -1):
+            worst = stats
+    return {k: v for k, v in worst.items() if isinstance(v, (int, float))}
+
+
+async def handle_profile_start(request: web.Request) -> web.Response:
+    """`POST /debug/profile/start?dir=<directory>`: open a jax.profiler
+    session of this process (the one that holds the chip) writing under
+    `dir`. The Python tracer is off: on, a 9 s trace of the serving
+    process held 1.5 M host events and its stop blocked for 17 s (PERF.md,
+    PR 26); the host's lanes come from the stage funnel's annotations
+    (`flush.*`, `compaction.*`, `scan.*`, `ingest.*`, `xjit.*`). One
+    session at a time: a second start answers 409."""
+    import jax
+
+    out_dir = request.query.get("dir")
+    if not out_dir:
+        return web.json_response({"error": "dir is required"}, status=400)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    t0 = time.perf_counter()
+    try:
+        jax.profiler.start_trace(out_dir, profiler_options=options)
+    except RuntimeError as e:  # a session is already open
+        return web.json_response({"error": str(e)}, status=409)
+    return web.json_response({
+        "dir": out_dir, "started_unix": time.time(),
+        "start_call_s": time.perf_counter() - t0,
+    })
+
+
+async def handle_profile_stop(request: web.Request) -> web.Response:
+    """`POST /debug/profile/stop`: close the session and write the trace
+    (`<dir>/plugins/profile/<time>/*.xplane.pb`). Collecting blocks for
+    seconds (`stop_call_s`), so it runs off the event loop."""
+    import jax
+
+    stopped = time.time()
+    t0 = time.perf_counter()
+    try:
+        await asyncio.to_thread(jax.profiler.stop_trace)
+    except RuntimeError as e:  # no session open
+        return web.json_response({"error": str(e)}, status=409)
+    return web.json_response({
+        "stopped_unix": stopped, "stop_call_s": time.perf_counter() - t0,
     })
 
 
@@ -2545,6 +2604,28 @@ async def bench_write_worker(state: ServerState, worker_id: int) -> None:
         await asyncio.sleep(interval)
 
 
+LOOP_LAG_SECONDS = METRICS.histogram(
+    "horaedb_loop_lag_seconds",
+    help="How late the event loop woke a 20 ms timer. The sum over a "
+         "window is the time the loop ran late, which is what every "
+         "request queued on it waited.",
+    buckets=(0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+             1.0, 2.5, 5.0, 10.0),
+)
+LOOP_LAG_PERIOD_S = 0.02
+
+
+async def loop_lag_heartbeat() -> None:
+    """Started with the app, cancelled at its clean-up: whatever holds the
+    loop (a synchronous merge inside a coroutine, a long callback) shows
+    as the lateness of this task's wake-ups."""
+    loop = asyncio.get_running_loop()
+    while True:
+        due = loop.time() + LOOP_LAG_PERIOD_S
+        await asyncio.sleep(LOOP_LAG_PERIOD_S)
+        LOOP_LAG_SECONDS.observe(max(0.0, loop.time() - due))
+
+
 # ---------------------------------------------------------------------------
 # bootstrap
 # ---------------------------------------------------------------------------
@@ -2600,13 +2681,16 @@ async def build_app(config: Config, store=None) -> web.Application:
     # the analog of the reference's named multi-thread runtimes
     # (main.rs:102-119): heavy compaction encodes no longer compete with
     # ingest for the event loop's default pool.
+    # every pool's threads take their Python names at the OS level as they
+    # start (scanstats.name_thread): a new thread is born with its
+    # creator's name, and the profiler cannot tell lines of one name apart
     sst_executor = ThreadPoolExecutor(
         max_workers=config.metric_engine.threads.sst_thread_num,
-        thread_name_prefix="sst",
+        thread_name_prefix="sst", initializer=scanstats.name_thread,
     )
     manifest_executor = ThreadPoolExecutor(
         max_workers=config.metric_engine.threads.manifest_thread_num,
-        thread_name_prefix="manifest",
+        thread_name_prefix="manifest", initializer=scanstats.name_thread,
     )
     cluster_cfg = config.metric_engine.cluster
     replica_role = cluster_cfg.enabled and cluster_cfg.role == "replica"
@@ -2853,6 +2937,9 @@ async def build_app(config: Config, store=None) -> web.Application:
                         cluster=cluster_state)
     if config.test.enable_write:
         state.write_enabled.set()
+    state.write_workers.append(
+        asyncio.create_task(loop_lag_heartbeat(), name="loop-lag")
+    )
     for i in range(config.test.write_worker_num):
         state.write_workers.append(
             asyncio.create_task(bench_write_worker(state, i), name=f"bench-write-{i}")
@@ -2962,6 +3049,8 @@ async def build_app(config: Config, store=None) -> web.Application:
             web.get("/debug/kernels", handle_debug_kernels),
             web.get("/debug/slowlog", handle_debug_slowlog),
             web.get("/debug/memory", handle_debug_memory),
+            web.post("/debug/profile/start", handle_profile_start),
+            web.post("/debug/profile/stop", handle_profile_stop),
             web.get("/debug/cluster", handle_debug_cluster),
         ]
     )
@@ -3005,6 +3094,15 @@ def main() -> None:
     logger.info("compile cache: %s", cache_dir)
 
     async def run():
+        # the loop's thread under a name of its own on the profiler's
+        # timeline (every other Python thread that never names itself is
+        # `python3` there, and lines of one name are not told apart)
+        scanstats.name_thread("horaedb-loop")
+        from concurrent.futures import ThreadPoolExecutor
+
+        # asyncio's own default pool, its threads named as they start
+        asyncio.get_running_loop().set_default_executor(ThreadPoolExecutor(
+            thread_name_prefix="asyncio", initializer=scanstats.name_thread))
         app = await build_app(config)
         # handler_cancellation: a client disconnect raises CancelledError
         # into the handler, so an abandoned query frees its admission

@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
+from contextlib import contextmanager
 
 import pyarrow as pa
 
@@ -46,6 +48,24 @@ COMPACTIONS = GLOBAL_METRICS.counter(
     help="Completed compaction tasks by result.",
     labelnames=("result",),
 )
+COMPACTION_ROWS = GLOBAL_METRICS.counter(
+    "horaedb_compaction_rows_total",
+    help="Rows compaction tasks took in (their inputs' num_rows) and "
+         "wrote out (after dedup, tombstones and retention).",
+    labelnames=("dir",),
+)
+for _dir in ("in", "out"):
+    COMPACTION_ROWS.labels(_dir)
+del _dir
+
+
+@contextmanager
+def _stage(report: dict, name: str):
+    """One compaction stage through the funnel, its seconds kept for the
+    task's log line."""
+    with scanstats.COMPACTION.stage(name) as t:
+        yield
+    report[name] = t.seconds
 
 
 class Executor:
@@ -141,26 +161,65 @@ class Executor:
 
     # -- the compaction itself (executor.rs:155-222) --------------------------
     async def do_compaction(self, task: Task) -> None:
+        """One task through the funnel's compaction stages: `scan` (read
+        and merge the inputs), `encode` (write the output shards),
+        `commit` (the manifest update), `rollup` (emission, full-segment
+        tasks only) and `cleanup` (physical deletes and GC). The finished
+        task leaves one INFO line and its numbers on the root span."""
+        t0 = time.perf_counter()
+        done = {"rows_in": sum(f.meta.num_rows for f in task.inputs),
+                "rows_out": 0, "bytes_out": 0}
+        stages: dict[str, float] = {}
+        await self._compact(task, done, stages)
+        tracing.add_attr(**done)
+        logger.info(
+            "compaction done: inputs=%d expireds=%d rows_in=%d rows_out=%d "
+            "bytes_in=%d bytes_out=%d seconds=%.3f stages=%s",
+            len(task.inputs), len(task.expireds), done["rows_in"],
+            done["rows_out"], task.input_size(), done["bytes_out"],
+            time.perf_counter() - t0,
+            {k: round(v, 4) for k, v in stages.items()},
+        )
+
+    async def _commit(self, stages: dict, new_files: list[SstFile],
+                      to_deletes: list[int], time_range: TimeRange) -> None:
+        """The commit point: add new THEN delete inputs+expireds, atomically
+        in one manifest delta (executor.rs:206-216)."""
         from horaedb_tpu.serving.cache import RESULT_CACHE
+
+        with _stage(stages, "commit"):
+            await self._manifest.update(new_files, to_deletes)
+        # serving-tier invalidation funnel (jaxlint J013): the sealed-SST
+        # set just changed; cached results over the old set are dead
+        RESULT_CACHE.serving_invalidate(
+            self._storage._root, "compact", time_range
+        )
+
+    async def _cleanup(self, stages: dict, to_deletes: list[int]) -> None:
+        """After the commit: best-effort, never fails the task."""
+        with _stage(stages, "cleanup"):
+            await self._delete_ssts(to_deletes)
+            await self._gc_tombstones()
+            await self._gc_rollups()
+
+    async def _compact(self, task: Task, done: dict, stages: dict) -> None:
         from horaedb_tpu.storage import visibility as vis_mod
 
         self.pre_check(task)
         self._trigger_more_task(task.scope)
         COMPACTION_BYTES.observe(task.input_size())
+        COMPACTION_ROWS.labels("in").inc(done["rows_in"])
         logger.debug("Start do compaction, input_len=%d", len(task.inputs))
 
         if not task.inputs:
             # expired-only task (retention enforcement): delete-only commit,
             # no merge — the horizon already proved every row out of range
             to_deletes = [f.id for f in task.expireds]
-            await self._manifest.update([], to_deletes)
-            RESULT_CACHE.serving_invalidate(
-                self._storage._root, "compact",
+            await self._commit(
+                stages, [], to_deletes,
                 TimeRange.union_of([f.meta.time_range for f in task.expireds]),
             )
-            await self._delete_ssts(to_deletes)
-            await self._gc_tombstones()
-            await self._gc_rollups()
+            await self._cleanup(stages, to_deletes)
             return
 
         time_range = TimeRange.union_of([f.meta.time_range for f in task.inputs])
@@ -182,7 +241,7 @@ class Executor:
         # "compact" context (storage/visibility.py): tombstoned/expired
         # rows are PHYSICALLY absent from the rewritten output — this is
         # where a delete reclaims bytes.
-        with vis_mod.mask_context("compact"):
+        with _stage(stages, "scan"), vis_mod.mask_context("compact"):
             batches = await self._storage.parquet_reader.scan_segment(
                 task.inputs,
                 predicate=None,
@@ -198,16 +257,13 @@ class Executor:
             # error would unmark + re-pick the same files in an infinite
             # retry loop).
             to_deletes = [f.id for f in task.expireds] + [f.id for f in task.inputs]
-            await self._manifest.update([], to_deletes)
-            RESULT_CACHE.serving_invalidate(
-                self._storage._root, "compact",
+            await self._commit(
+                stages, [], to_deletes,
                 TimeRange.union_of(
                     [f.meta.time_range for f in task.inputs + task.expireds]
                 ),
             )
-            await self._delete_ssts(to_deletes)
-            await self._gc_tombstones()
-            await self._gc_rollups()
+            await self._cleanup(stages, to_deletes)
             return
         table = pa.Table.from_batches(batches)
 
@@ -228,12 +284,13 @@ class Executor:
         slices = [table.slice(i * per, per) for i in range(n_shards)]
         slices = [s for s in slices if s.num_rows > 0]
         ids = [allocate_id() for _ in slices]
-        with scanstats.stage("encode"):
+        with _stage(stages, "encode"):
             # all-settle semantics: a failed shard encode must not leave its
             # siblings running detached (they would race close/teardown);
             # gather with return_exceptions, then re-raise the first failure
             results = await asyncio.gather(
-                *(self._storage.write_sst(fid, s) for fid, s in zip(ids, slices)),
+                *(self._storage.write_sst(fid, s, stages=scanstats.COMPACTION_SST)
+                  for fid, s in zip(ids, slices)),
                 return_exceptions=True,
             )
             # compaction outputs carry the encoding descriptor of their
@@ -247,6 +304,9 @@ class Executor:
                 if isinstance(r, BaseException):
                     raise r
             sizes = results
+        done["rows_out"] = table.num_rows
+        done["bytes_out"] = sum(sizes)
+        COMPACTION_ROWS.labels("out").inc(table.num_rows)
         new_files = [
             SstFile(
                 id=fid,
@@ -266,15 +326,8 @@ class Executor:
             len(new_files), ids, table.num_rows,
         )
 
-        # Commit point: add new THEN delete inputs+expireds, atomically in one
-        # manifest delta (executor.rs:206-216).
         to_deletes = [f.id for f in task.expireds] + [f.id for f in task.inputs]
-        await self._manifest.update(new_files, to_deletes)
-        # serving-tier invalidation funnel (jaxlint J013): the sealed-SST
-        # set just changed; cached results over the old set are dead
-        RESULT_CACHE.serving_invalidate(
-            self._storage._root, "compact", time_range
-        )
+        await self._commit(stages, new_files, to_deletes, time_range)
         # From now on, no error should be returned (executor.rs:218-219).
         try:
             # rollup emission rides the bytes compaction already rewrote:
@@ -282,14 +335,13 @@ class Executor:
             # tombstone-applied content. Post-commit and best-effort — a
             # failed artifact costs speed on the next dashboard refresh,
             # never correctness (the planner scans raw without it).
-            await self._emit_rollups(task, table, new_files, time_range,
-                                     applied_tombs)
+            with _stage(stages, "rollup"):
+                await self._emit_rollups(task, table, new_files, time_range,
+                                         applied_tombs)
         except Exception:  # noqa: BLE001 — perf artifact only
             logger.warning("rollup emission failed (raw scans still exact)",
                            exc_info=True)
-        await self._delete_ssts(to_deletes)
-        await self._gc_tombstones()
-        await self._gc_rollups()
+        await self._cleanup(stages, to_deletes)
 
     async def _emit_rollups(
         self, task: Task, table: pa.Table, new_files: list[SstFile],

@@ -34,6 +34,7 @@ import os
 import threading
 import time
 from collections import OrderedDict
+from contextvars import ContextVar
 from dataclasses import dataclass
 from functools import lru_cache
 import jax
@@ -79,14 +80,32 @@ DEFAULT_SCAN_BATCH_SIZE = 8192
 
 SCAN_PATH = GLOBAL_METRICS.counter(
     "horaedb_scan_path_total",
-    help="Merge route the scan planner took (host SIMD, single-device "
-         "kernel, or the cross-chip sharded merge).",
+    help="Segment scans by the merge route(s) the planner took, named as "
+         "EXPLAIN's scan_paths names them: host_merge (host SIMD), "
+         "device_merge_packed / device_merge (single-device kernels), "
+         "device_merge_sharded (cross-chip). One count a segment scan and "
+         "route, however many chunks merged; a pushdown aggregate takes no "
+         "merge route.",
     labelnames=("path",),
 )
 # pre-register so the route split is visible on /metrics from boot
-for _p in ("host", "device", "sharded"):
+for _p in ("host_merge", "device_merge_packed", "device_merge",
+           "device_merge_sharded"):
     SCAN_PATH.labels(_p)
 del _p
+
+# the routes of the segment scan in progress (scan_segment counts each once)
+_ROUTES: ContextVar["set[str] | None"] = ContextVar("horaedb_scan_routes",
+                                                    default=None)
+
+
+def _route(name: str) -> None:
+    """The planner took merge route `name`: EXPLAIN's `scan_paths` and
+    `horaedb_scan_path_total` name it alike."""
+    scanstats.note("path_" + name)
+    routes = _ROUTES.get()
+    if routes is not None:
+        routes.add(name)
 
 
 def _is_binary_like(t: pa.DataType) -> bool:
@@ -597,8 +616,7 @@ def _plan_and_merge(
     dispatch = link["dispatch_s"]
 
     def host_merge(mask: np.ndarray | None) -> np.ndarray:
-        scanstats.note("path_host_merge")
-        SCAN_PATH.labels("host").inc()
+        _route("host_merge")
         sel_rows = int(np.count_nonzero(mask)) if mask is not None else n
         t0 = time.perf_counter()
         with scanstats.stage("host_merge"):
@@ -672,15 +690,13 @@ def _plan_and_merge(
         if want_sharded:
             from horaedb_tpu.parallel.merge import sharded_packed_merge
 
-            scanstats.note("path_device_merge_sharded")
-            SCAN_PATH.labels("sharded").inc()
+            _route("device_merge_sharded")
             with scanstats.stage("device_merge"):
                 res = sharded_packed_merge(
                     packed, seq_width, do_dedup, mesh, defer=defer_device
                 )
             return res
-        scanstats.note("path_device_merge_packed")
-        SCAN_PATH.labels("device").inc()
+        _route("device_merge_packed")
         with scanstats.stage("h2d"):
             block = Block.from_numpy({"__packed__": packed},
                                      pad_multiple=_merge_rows(n),
@@ -711,8 +727,7 @@ def _plan_and_merge(
             packed_res = device_merge_packed(mask)
             if packed_res is not None:
                 return packed_res
-        scanstats.note("path_device_merge")
-        SCAN_PATH.labels("device").inc()
+        _route("device_merge")
         need = list(sort_keys)
         if mask is None:
             need += [c for c in sorted(pred_cols) if c not in need]
@@ -1278,7 +1293,8 @@ class ParquetReader:
                     # cancellation machinery
                     try:
                         table = await asyncio.to_thread(
-                            self._read_encoded, enc, columns, predicate
+                            scanstats.SCAN.on_worker, "io_decode",
+                            self._read_encoded, enc, columns, predicate,
                         )
                     except Exception:  # noqa: BLE001 — the parquet
                         # object is authoritative: ANY malformed-sidecar
@@ -1368,11 +1384,15 @@ class ParquetReader:
 
         from horaedb_tpu.objstore import NotFound
 
+        # the callers' `io_decode` stage marks a stage in progress on the
+        # loop's thread; on_worker marks the thread that decodes
+        on_worker = scanstats.SCAN.on_worker
         try:
-            table = await asyncio.to_thread(_read)
+            table = await asyncio.to_thread(on_worker, "io_decode", _read)
         except _NeedBytes:
             data = await self._store.get(path)
-            table = await asyncio.to_thread(_read_bytes, data)
+            table = await asyncio.to_thread(
+                on_worker, "io_decode", _read_bytes, data)
         except FileNotFoundError as e:
             # compaction deleted the file after the caller's manifest
             # snapshot; normalized so scan layers can refresh + retry
@@ -1619,14 +1639,21 @@ class ParquetReader:
         """Traced entry point of the per-segment pipeline: the span anchors
         the per-stage lane timings (scanstats bridges every stage() into the
         active span's `stages` attr) for /debug/traces."""
-        with tracing.span(
-            "scan_segment", ssts=len(ssts),
-            rows=sum(s.meta.num_rows for s in ssts),
-        ):
-            return await self._scan_segment(
-                ssts, predicate, projections, keep_builtin, batch_size,
-                use_block_cache,
-            )
+        routes: set[str] = set()
+        token = _ROUTES.set(routes)
+        try:
+            with tracing.span(
+                "scan_segment", ssts=len(ssts),
+                rows=sum(s.meta.num_rows for s in ssts),
+            ):
+                return await self._scan_segment(
+                    ssts, predicate, projections, keep_builtin, batch_size,
+                    use_block_cache,
+                )
+        finally:
+            _ROUTES.reset(token)
+            for name in routes:
+                SCAN_PATH.labels(name).inc()
 
     async def _scan_segment(
         self,
@@ -2217,14 +2244,12 @@ class ParquetReader:
             # (host-side check; never traced into the kernel body)
             deadline_ctx.check("device_lane")
             if mesh is not None:
-                # path counter rides sharded_downsample (one inc per fold)
                 with scanstats.stage("device_agg"):
                     out = self._sharded_accumulate(
                         mesh, ts_np, sid_np, val_np, t0, bucket_ms,
                         num_series, num_buckets, with_minmax, valid_np=valid_np,
                     )
             else:
-                SCAN_PATH.labels("device").inc()
                 with scanstats.stage("device_agg"):
                     out = agg_ops.downsample_sorted(
                         ts_np, sid_np, val_np, t0, bucket_ms,

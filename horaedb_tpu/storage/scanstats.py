@@ -22,15 +22,32 @@ active trace span (common/tracing.py), so lane attribution is continuous on
 two perf_counter calls + one histogram observe, against stage bodies that
 decode whole segments or dispatch device kernels.
 Stage sums can exceed wall clock (stages from concurrent SST reads overlap).
+
+This is the ONE stage funnel of the process. A `Family` says which layer
+a stage belongs to: the scan's lanes (`SCAN`, the module-level `stage()`),
+a table's flush (`flush_family(table)`: sort, encode, upload, sidecar,
+manifest, drain), a compaction task (`COMPACTION`: scan, encode, commit,
+cleanup; `COMPACTION_SST` for the stages of the SSTs it writes) and the
+ingest front (`ingest/pooled_parser.py`: parse, pool_wait). Every stage of
+every family goes to four sinks: the family's histogram, the active
+span's `stages` attribute, the per-query collector, and a
+`jax.profiler.TraceAnnotation("<family>.<stage>")` on the profiler's
+timeline, where the device's idle gaps are named after it
+(bench_chip/trace/reduce.py). The annotation's name is constant: a stage
+that is work must not match that file's WAITS pattern, a stage that is a
+wait (`pool_wait`) must.
 """
 
 from __future__ import annotations
 
+import ctypes
 import threading
 import time
 from contextlib import contextmanager
 from contextvars import ContextVar
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 from horaedb_tpu.common import tracing
 from horaedb_tpu.server.metrics import GLOBAL_METRICS
@@ -68,6 +85,30 @@ for _lane in ("io_decode", "host_prep", "transfer", "kernel", "compile",
               "decode"):
     STAGE_SECONDS.labels(_lane)
 del _lane
+
+FLUSH_STAGE_SECONDS = GLOBAL_METRICS.histogram(
+    "horaedb_flush_stage_seconds",
+    help="Per-stage cost of a flush or direct write, every write: drain "
+         "(memtable -> pk-sorted lanes), sort, encode (parquet), upload "
+         "(object-store PUT), sidecar (bloom + encoded lanes), manifest "
+         "(commit). A compaction's SST writes are not here.",
+    labelnames=("table", "stage"),
+    # OpenMetrics exemplars: a slow flush stage names the trace that
+    # paid it (telemetry package wires the source)
+    exemplars=True,
+)
+COMPACTION_STAGE_SECONDS = GLOBAL_METRICS.histogram(
+    "horaedb_compaction_stage_seconds",
+    help="Per-stage cost of a compaction task: scan (read + merge the "
+         "inputs), encode (all output shards written), commit (manifest "
+         "update), cleanup (physical deletes, tombstone and rollup GC); "
+         "sst_encode / sst_upload / sst_sidecar are one output shard's, "
+         "inside encode and concurrent.",
+    labelnames=("stage",),
+)
+for _stage in ("scan", "encode", "commit", "cleanup"):
+    COMPACTION_STAGE_SECONDS.labels(_stage)
+del _stage
 
 # Roofline-attribution lane of each stage (attribution(), query EXPLAIN):
 # anything not listed is host-side work.
@@ -151,12 +192,15 @@ class _DeductCell:
     that could physically have overlapped and the stage's own lane never
     silently absorbs a negative."""
 
-    __slots__ = ("_t0", "_total", "_lock")
+    __slots__ = ("_t0", "_total", "_lock", "seconds")
 
     def __init__(self) -> None:
         self._t0 = time.perf_counter()
         self._total = 0.0
         self._lock = threading.Lock()
+        # what the stage observed, once it has closed (stage() yields the
+        # cell: a caller that logs its own stages reads it here)
+        self.seconds = 0.0
 
     def add(self, secs: float) -> None:
         with self._lock:
@@ -186,31 +230,150 @@ def scan_stats():
         _ACTIVE.reset(token)
 
 
-@contextmanager
-def stage(name: str):
-    """Time one stage into (a) the active per-query collector when one is
-    attached, (b) the process-wide `horaedb_scan_stage_seconds{stage=...}`
-    histogram — ALWAYS, so lane attribution shows on /metrics without any
-    collector — and (c) the active trace span's `stages` attr. Stages wrap
-    chunky work (a segment's decode, one device merge), so the two
-    perf_counter calls + one histogram observe are noise next to the work
-    itself."""
-    st = _ACTIVE.get()
-    cell = _DeductCell()
-    token = _COMPILE_DEDUCT.set(cell)
-    t0 = time.perf_counter()
+_thread_named = threading.local()
+_PR_SET_NAME = 15
+
+
+def name_thread(name: "str | None" = None) -> None:
+    """Give the calling thread a name at the OS level, once: its Python
+    name (`asyncio_3`, `sst_0`) unless one is given. The profiler names a
+    host line after its thread, and every Python thread is born with the
+    process's name: twenty lines called `python3` cannot be told apart —
+    the benchmark's trace reduction keeps one line a name, so it would
+    see one of them. A worker is named before its first annotation; the
+    main thread only where the server names it (`horaedb-loop`: renaming
+    it renames the process as `top` shows it). Linux only; elsewhere the
+    lines keep their names."""
+    if name is None and getattr(_thread_named, "done", False):
+        return
+    _thread_named.done = True
+    thread = threading.current_thread()
+    if name is None and thread is threading.main_thread():
+        return
     try:
-        yield
-    finally:
-        dt = max(0.0, time.perf_counter() - t0 - cell.total())
-        _COMPILE_DEDUCT.reset(token)
+        prctl = ctypes.CDLL(None, use_errno=True).prctl
+    except (OSError, AttributeError):
+        return
+    prctl.argtypes = (ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong)
+    prctl.restype = ctypes.c_int
+    # the kernel keeps 15 bytes: the tail of the name is what differs
+    prctl(_PR_SET_NAME, (name or thread.name).encode()[-15:], 0, 0, 0)
+
+
+class Family:
+    """One layer's stages: where `stage()` observes them (`child(stage)`
+    is the histogram child) and the `<name>.` prefix of their profiler
+    annotations. `prefix` renames every stage (a compaction's own SST
+    writes are `sst_encode`, not the task's `encode`)."""
+
+    __slots__ = ("name", "_child", "_prefix", "_known")
+
+    def __init__(self, name: str, child, prefix: str = ""):
+        self.name = name
+        self._child = child
+        self._prefix = prefix
+        # stage as the caller names it -> (stage, annotation, histogram
+        # child): the write path runs on the event loop, where every
+        # microsecond is paid by each request queued behind it
+        self._known: dict[str, tuple] = {}
+
+    def _lookup(self, stage: str) -> tuple:
+        known = self._known.get(stage)
+        if known is None:
+            full = self._prefix + stage
+            known = self._known[stage] = (
+                full, f"{self.name}.{full}", self._child(full))
+        return known
+
+    def mark(self, stage: str) -> TraceAnnotation:
+        """The profiler annotation alone, for a synchronous body on the
+        worker thread that runs it: the stage itself is timed by the
+        `stage()` that awaits the worker (queueing included) and marks a
+        stage in progress on the loop's thread; this marks the thread
+        that does the work."""
+        name_thread()
+        return TraceAnnotation(self._lookup(stage)[1])
+
+    def on_worker(self, stage: str, fn, *args):
+        """`fn(*args)` under `mark(stage)`: what a stage hands to its
+        worker thread (`asyncio.to_thread(fam.on_worker, "parse", ...)`)."""
+        with self.mark(stage):
+            return fn(*args)
+
+    def stage(self, stage: str) -> "_Stage":
+        """Time one stage into (a) the family's histogram — ALWAYS, so
+        attribution shows on /metrics without any collector — (b) the
+        active trace span's `stages` attr, (c) the active per-query
+        collector when one is attached and (d) the profiler's timeline.
+        Stages wrap chunky work (a segment's decode, one device merge, a
+        parquet encode), so two perf_counter calls, one histogram observe
+        and one TraceMe (an atomic load with no session open) are noise
+        next to the work itself. `with ... as cell`: `cell.seconds` is
+        what the stage observed, once it has closed."""
+        return _Stage(*self._lookup(stage))
+
+    def fold(self, st: "ScanStats | None", stage: str, dt: float) -> None:
+        """An externally timed duration into the three sinks that take
+        one (record())."""
+        full, _, child = self._lookup(stage)
+        _fold(st, full, child, dt)
+
+
+def _fold(st: "ScanStats | None", stage: str, child, dt: float) -> None:
+    if st is not None:
+        st.add(stage, dt)
+    child.observe(dt)
+    tracing.add_stage(stage, dt)
+
+
+class _Stage:
+    """One open stage (a plain context manager: a generator's frame costs
+    a third again of the whole, on the loop's thread)."""
+
+    __slots__ = ("_stage", "_child", "_annotation", "_st", "_cell", "_token", "_t0")
+
+    def __init__(self, stage: str, annotation: str, child):
+        self._stage = stage
+        self._child = child
+        self._annotation = TraceAnnotation(annotation)
+
+    def __enter__(self) -> _DeductCell:
+        name_thread()
+        self._st = _ACTIVE.get()
+        self._cell = cell = _DeductCell()
+        self._token = _COMPILE_DEDUCT.set(cell)
+        self._t0 = time.perf_counter()
+        self._annotation.__enter__()
+        return cell
+
+    def __exit__(self, *exc) -> bool:
+        self._annotation.__exit__(*exc)
+        cell = self._cell
+        deducted = cell.total()
+        dt = cell.seconds = max(0.0, time.perf_counter() - self._t0 - deducted)
+        _COMPILE_DEDUCT.reset(self._token)
         outer = _COMPILE_DEDUCT.get()
         if outer is not None:
-            outer.add(cell.total())
-        if st is not None:
-            st.add(name, dt)
-        STAGE_SECONDS.labels(_STAGE_LANE.get(name, name)).observe(dt)
-        tracing.add_stage(name, dt)
+            outer.add(deducted)
+        _fold(self._st, self._stage, self._child, dt)
+        return False
+
+
+SCAN = Family("scan", lambda s: STAGE_SECONDS.labels(_STAGE_LANE.get(s, s)))
+COMPACTION = Family("compaction", COMPACTION_STAGE_SECONDS.labels)
+COMPACTION_SST = Family("compaction", COMPACTION_STAGE_SECONDS.labels,
+                        prefix="sst_")
+
+
+def flush_family(table: str) -> Family:
+    """The flush stages of one table root."""
+    return Family("flush", lambda s: FLUSH_STAGE_SECONDS.labels(table, s))
+
+
+def stage(name: str):
+    """One stage of the scan's lanes (`SCAN.stage`)."""
+    return SCAN.stage(name)
 
 
 @contextmanager
@@ -257,11 +420,7 @@ def record(name: str, secs: float, *, deduct: "bool | None" = None) -> None:
         cell = _COMPILE_DEDUCT.get()
         if cell is not None:
             cell.add(secs)
-    st = _ACTIVE.get()
-    if st is not None:
-        st.add(name, secs)
-    STAGE_SECONDS.labels(_STAGE_LANE.get(name, name)).observe(secs)
-    tracing.add_stage(name, secs)
+    SCAN.fold(_ACTIVE.get(), name, secs)
 
 
 def kernel_use(name: str) -> None:

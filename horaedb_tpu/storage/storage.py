@@ -74,15 +74,6 @@ SCAN_SECONDS = GLOBAL_METRICS.histogram(
          "consumer breaks count as completed scans), by table root.",
     labelnames=("table",),
 )
-# Shared with engine/flush_executor.py (registry is idempotent by name):
-# flush-profile SST writes attribute their encode vs upload cost here; the
-# drain stage is observed at the memtable seal/sort.
-FLUSH_STAGE_SECONDS = GLOBAL_METRICS.histogram(
-    "horaedb_flush_stage_seconds",
-    help="Per-stage flush cost: drain (memtable -> pk-sorted column "
-         "lanes), encode (parquet), upload (object-store PUT).",
-    labelnames=("table", "stage"),
-)
 ORPHAN_SSTS_GC = GLOBAL_METRICS.counter(
     "horaedb_orphan_ssts_gc_total",
     help="Orphan SST objects (uploaded but never manifest-committed — a "
@@ -195,6 +186,8 @@ class ObjectBasedStorage(ColumnarStorage):
             fence = None
         config = config or StorageConfig()
         self._root = root.strip("/")
+        # this table's flush stages in the one stage funnel (scanstats.py)
+        self._flush = scanstats.flush_family(self._root)
         self._store = store
         self._config = config
         self._read_only = read_only
@@ -546,7 +539,8 @@ class ObjectBasedStorage(ColumnarStorage):
                 format_version=fmt,
                 encodings=encodings,
             )
-            await self._manifest.add_file(result.id, meta)
+            with self._flush.stage("manifest"):
+                await self._manifest.add_file(result.id, meta)
         # serving-tier invalidation funnel (jaxlint J013): a committed SST
         # changes the table's sealed set — cached results for it are dead
         from horaedb_tpu.serving.cache import RESULT_CACHE
@@ -575,7 +569,9 @@ class ObjectBasedStorage(ColumnarStorage):
         if presorted:
             sorted_batch = batch
         else:
-            sorted_batch = await self._run_sst(self._sort_batch, batch)
+            with self._flush.stage("sort"):
+                sorted_batch = await self._run_sst(
+                    self._flush.on_worker, "sort", self._sort_batch, batch)
         # file ids are increasing, so the id doubles as the sequence unless
         # the caller pinned one at snapshot time (same allocator, so the
         # combined seq stream stays monotonic with unbuffered writes)
@@ -723,12 +719,17 @@ class ObjectBasedStorage(ColumnarStorage):
         return out
 
     async def write_sst(
-        self, file_id: int, table: pa.Table, fast_encode: bool = False
+        self, file_id: int, table: pa.Table, fast_encode: bool = False,
+        stages: "scanstats.Family | None" = None,
     ) -> int:
         """Encode a (sorted, builtin-filled) table as one parquet SST,
         STREAMED to the object store at chunk granularity — host memory
         stays O(row group + chunk), not O(table), matching the reference's
         AsyncArrowWriter streaming (storage.rs:192-224). Returns object size.
+
+        `stages`: the family the write's stages (encode, upload, sidecar)
+        are observed in: this table's flush family unless the caller is a
+        compaction, which passes its own.
 
         When bloom filters are enabled, a sidecar `{id}.bloom` lands after
         the SST but before the file is registrable in the manifest, so
@@ -736,6 +737,7 @@ class ObjectBasedStorage(ColumnarStorage):
         import queue as _queue
         import threading as _threading
 
+        stages = stages or self._flush
         path = self._path_gen.generate(file_id)
         cfg = self._config.write
         # The manifest wire format carries num_rows as u32 (sst.proto,
@@ -760,31 +762,19 @@ class ObjectBasedStorage(ColumnarStorage):
                 writer.close()
                 return sink.getvalue()
 
-            t_enc = time.perf_counter()
-            blob = await self._run_sst(_encode_small)
+            # encode (thread pool; pyarrow cannot thread one file's
+            # columns, so flush parallelism is shard-level across the
+            # pool) vs the upload PUT below
+            with stages.stage("encode"):
+                blob = await self._run_sst(
+                    stages.on_worker, "encode", _encode_small)
             # lineage: the encoded object is a fresh buffer distinct from
             # the table's lanes (the copy-tax of the flush encode)
             memtrace.track_bytes(len(blob), "flush_encode", "alloc")
-            if fast_encode:
-                # flush-path stage attribution: encode (thread pool; pyarrow
-                # cannot thread one file's columns, so flush parallelism is
-                # shard-level across the pool) vs the upload PUT below
-                FLUSH_STAGE_SECONDS.labels(self._root, "encode").observe(
-                    time.perf_counter() - t_enc
-                )
             ensure(len(blob) < 2**32, f"sst too large for manifest format: {len(blob)}")
-            t_up = time.perf_counter()
-            with context(f"write sst {path}"):
+            with stages.stage("upload"), context(f"write sst {path}"):
                 await self._store.put(path, blob)
-            if fast_encode:
-                FLUSH_STAGE_SECONDS.labels(self._root, "upload").observe(
-                    time.perf_counter() - t_up
-                )
-            # bloom first, enc LAST: _write_enc_sidecar registers the
-            # pending (format, encodings) entry only once nothing after
-            # it can fail, so a failed write never strands it
-            await self._write_bloom_sidecar(file_id, path, table)
-            await self._write_enc_sidecar(file_id, path, table)
+            await self._write_sidecars(file_id, path, table, stages)
             SST_BYTES.observe(len(blob))
             return len(blob)
 
@@ -823,14 +813,15 @@ class ObjectBasedStorage(ColumnarStorage):
 
         def _produce() -> None:
             try:
-                sink = _Sink()
-                writer = pq.ParquetWriter(sink, table.schema, **kwargs)
-                # one call: pyarrow splits into max_row_group_size row
-                # groups in C++ (same file layout as a Python slice loop,
-                # without per-group Python/GIL overhead)
-                writer.write_table(table, row_group_size=cfg.max_row_group_size)
-                writer.close()
-                sink.flush_tail()
+                with stages.mark("encode"):
+                    sink = _Sink()
+                    writer = pq.ParquetWriter(sink, table.schema, **kwargs)
+                    # one call: pyarrow splits into max_row_group_size row
+                    # groups in C++ (same file layout as a Python slice
+                    # loop, without per-group Python/GIL overhead)
+                    writer.write_table(table, row_group_size=cfg.max_row_group_size)
+                    writer.close()
+                    sink.flush_tail()
                 q.put(None)  # EOF
             except BaseException as e:  # noqa: BLE001 — relayed to consumer
                 q.put(e)
@@ -861,15 +852,11 @@ class ObjectBasedStorage(ColumnarStorage):
                 yield item
 
         try:
-            t_up = time.perf_counter()
-            with context(f"write sst {path}"):
+            # streaming path overlaps encode with the PUT; the combined
+            # wall time attributes to upload (encode rides the stream, and
+            # is on the timeline as the producer thread's own event)
+            with stages.stage("upload"), context(f"write sst {path}"):
                 size = await self._store.put_stream(path, chunks())
-            if fast_encode:
-                # streaming path overlaps encode with the PUT; the combined
-                # wall time attributes to upload (encode rides the stream)
-                FLUSH_STAGE_SECONDS.labels(self._root, "upload").observe(
-                    time.perf_counter() - t_up
-                )
         finally:
             cancel.set()
             while not done.is_set():
@@ -879,23 +866,29 @@ class ObjectBasedStorage(ColumnarStorage):
                     pass
                 done.wait(timeout=0.05)
 
-        # bloom first, enc last (see write_sst fast path): the pending
-        # enc entry must be the final fallible step
-        await self._write_bloom_sidecar(file_id, path, table)
-        await self._write_enc_sidecar(file_id, path, table)
+        await self._write_sidecars(file_id, path, table, stages)
         SST_BYTES.observe(size)
         return size
+
+    async def _write_sidecars(self, file_id: int, path: str, table,
+                              stages: "scanstats.Family") -> None:
+        """Bloom first, enc LAST: _write_enc_sidecar registers the pending
+        (format, encodings) entry only once nothing after it can fail, so
+        a failed write never strands it."""
+        with stages.stage("sidecar"):
+            await self._write_bloom_sidecar(file_id, path, table, stages)
+            await self._write_enc_sidecar(file_id, path, table, stages)
 
     def pop_enc_meta(self, file_id: int) -> tuple[int, tuple]:
         """(format_version, encodings) of a just-written SST — consumed
         exactly once by the FileMeta construction site."""
         return self._pending_enc.pop(file_id, (1, ()))
 
-    async def _write_enc_sidecar(self, file_id: int, path: str, table) -> None:
+    async def _write_enc_sidecar(self, file_id: int, path: str, table,
+                                 stages: "scanstats.Family") -> None:
         """Encoded-lane sidecar AFTER the SST object lands and BEFORE the
         manifest can reference it — a registered v2 SST always has its
-        sidecar. Encode cost is attributed per table
-        (horaedb_flush_stage_seconds{stage=enc_encode}); a failed PUT
+        sidecar. Its cost is part of the `sidecar` stage; a failed PUT
         reclaims the SST object best-effort and raises, exactly like the
         bloom sidecar path."""
         cfg = self._config.encoding
@@ -914,13 +907,10 @@ class ObjectBasedStorage(ColumnarStorage):
             return (e, enc_mod.encode_blob(e)) if e is not None else (None, None)
 
         try:
-            t0 = time.perf_counter()
-            enc, blob = await self._run_sst(_encode_and_pack)
+            enc, blob = await self._run_sst(
+                stages.on_worker, "sidecar", _encode_and_pack)
             if enc is None:
                 return
-            FLUSH_STAGE_SECONDS.labels(self._root, "enc_encode").observe(
-                time.perf_counter() - t0
-            )
             await self._store.put(self._path_gen.generate_enc(file_id), blob)
         except BaseException:
             try:
@@ -934,7 +924,8 @@ class ObjectBasedStorage(ColumnarStorage):
             enc_mod.SST_FORMAT_V2, enc.descriptor(),
         )
 
-    async def _write_bloom_sidecar(self, file_id: int, path: str, table) -> None:
+    async def _write_bloom_sidecar(self, file_id: int, path: str, table,
+                                   stages: "scanstats.Family") -> None:
         """Bloom sidecar AFTER the SST lands: readers only learn ids via the
         manifest (updated after this returns), so ordering is safe, and a
         failed stream can't orphan a sidecar. If the sidecar put itself
@@ -946,7 +937,8 @@ class ObjectBasedStorage(ColumnarStorage):
 
         try:
             blooms = await self._run_sst(
-                bloom_mod.build_blooms, table, bloom_cols
+                stages.on_worker, "sidecar", bloom_mod.build_blooms, table,
+                bloom_cols,
             )
             await self._store.put(
                 self._path_gen.generate_bloom(file_id),

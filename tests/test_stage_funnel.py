@@ -1,0 +1,407 @@
+"""The one stage funnel (storage/scanstats.py) and what PR 27 hung on it:
+every stage of every family goes to the family's histogram, the active
+span's `stages`, the per-query collector and a profiler annotation named
+`<family>.<stage>`; the write path and the compaction run through it;
+kernels carry their label; every XLA compile is counted; the loop's lag
+is measured; the scan-path counter counts routes."""
+
+import asyncio
+import logging
+import time
+
+import numpy as np
+import pytest
+
+from horaedb_tpu.common import tracing, xprof
+from horaedb_tpu.common.xprof import xjit
+from horaedb_tpu.objstore import MemStore
+from horaedb_tpu.server.metrics import GLOBAL_METRICS
+from horaedb_tpu.storage import (
+    ObjectBasedStorage,
+    ScanRequest,
+    SchedulerConfig,
+    StorageConfig,
+    TimeRange,
+    WriteRequest,
+    scanstats,
+)
+from tests.conftest import async_test
+from tests.test_storage import SEGMENT_MS, collect, make_batch, make_schema
+
+# the trace reduction's pattern for host events that are waits: a stage
+# that is work must not match it, a stage that is a wait must
+from bench_chip.trace.reduce import WAITS  # noqa: E402
+
+
+def hist(family: str, **labels) -> tuple[int, float]:
+    """(count, sum) of one histogram child, 0 where it was never made."""
+    count = total = 0.0
+    want = tuple(sorted(labels.items()))
+    for name, _kind, sample, key, value in GLOBAL_METRICS.snapshot_samples():
+        if name != family or tuple(sorted(k for k in key if k[0] != "le")) != want:
+            continue
+        if sample == family + "_count":
+            count = value
+        elif sample == family + "_sum":
+            total = value
+    return int(count), total
+
+
+def counter(family: str, **labels) -> float:
+    want = tuple(sorted(labels.items()))
+    for name, _kind, _sample, key, value in GLOBAL_METRICS.snapshot_samples():
+        if name == family and tuple(sorted(key)) == want:
+            return value
+    return 0.0
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Names of the profiler annotations opened, the class patched where
+    the funnel and xjit look it up."""
+    names: list[str] = []
+
+    class Recording:
+        def __init__(self, name, **_kw):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(scanstats, "TraceAnnotation", Recording)
+    monkeypatch.setattr(xprof, "TraceAnnotation", Recording)
+    return names
+
+
+class TestFunnel:
+    @pytest.mark.parametrize("family,stage,histogram,labels", [
+        (scanstats.SCAN, "host_prep", "horaedb_scan_stage_seconds", {"stage": "host_prep"}),
+        # scan-internal names fold into the operator's lanes on /metrics
+        (scanstats.SCAN, "device_merge", "horaedb_scan_stage_seconds", {"stage": "kernel"}),
+        (scanstats.flush_family("t/funnel"), "encode", "horaedb_flush_stage_seconds",
+         {"table": "t/funnel", "stage": "encode"}),
+        (scanstats.COMPACTION, "commit", "horaedb_compaction_stage_seconds", {"stage": "commit"}),
+        (scanstats.COMPACTION_SST, "encode", "horaedb_compaction_stage_seconds",
+         {"stage": "sst_encode"}),
+    ])
+    def test_four_sinks(self, annotations, family, stage, histogram, labels):
+        n0, s0 = hist(histogram, **labels)
+        tracing.configure(sample=1.0)
+        with tracing.trace("funnel-test") as t, scanstats.scan_stats() as st:
+            with family.stage(stage) as cell:
+                time.sleep(0.01)
+        n1, s1 = hist(histogram, **labels)
+        name = labels["stage"] if family is not scanstats.SCAN else stage
+        assert (n1 - n0, round(s1 - s0, 9)) == (1, round(cell.seconds, 9))
+        assert cell.seconds >= 0.01
+        assert st.seconds[name] == cell.seconds
+        assert t.spans[0].attrs["stages"][name] == pytest.approx(cell.seconds, abs=1e-6)
+        assert annotations == [f"{family.name}.{name}"]
+        assert not WAITS.search(annotations[0])
+
+    def test_mark_is_the_annotation_alone(self, annotations):
+        before = hist("horaedb_compaction_stage_seconds", stage="sst_encode")
+        with scanstats.COMPACTION_SST.mark("encode"):
+            pass
+        assert annotations == ["compaction.sst_encode"]
+        assert hist("horaedb_compaction_stage_seconds", stage="sst_encode") == before
+
+    def test_a_worker_thread_is_named_for_the_profiler(self):
+        """Every Python thread is born `python3` and the profiler names a
+        line after its thread: a worker takes its Python name before its
+        first annotation, the main thread (the process) keeps its own."""
+        import os
+        import threading
+
+        if not os.path.isdir("/proc/self/task"):
+            pytest.skip("thread names are set through prctl, on Linux")
+
+        def comm() -> str:
+            with open(f"/proc/self/task/{threading.get_native_id()}/comm") as f:
+                return f.read().strip()
+
+        names = {}
+        main_before = comm()
+
+        def body(key):
+            names[key] = comm()
+
+        def worker():
+            scanstats.SCAN.on_worker("io_decode", body, "marked")
+            with scanstats.COMPACTION.stage("commit"):
+                body("staged")
+            scanstats.name_thread("horaedb-loop")  # as the server names its loop's
+            body("given")
+
+        t = threading.Thread(target=worker, name="funnel-worker-thread-7")
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        assert names == {"marked": "worker-thread-7", "staged": "worker-thread-7",
+                         "given": "horaedb-loop"}
+        with scanstats.SCAN.stage("host_prep"):
+            assert comm() == main_before
+
+    @async_test
+    async def test_a_wait_is_named_a_wait(self, annotations):
+        """The parser-pool wait matches the reduction's WAITS pattern, the
+        parse does not: an idle gap is never named after a wait."""
+        from horaedb_tpu.ingest.pooled_parser import STAGES, ParserPool
+        from tests.test_engine import make_remote_write
+
+        n0, _ = hist("horaedb_ingest_parse_seconds")
+        w0, _ = hist("horaedb_ingest_pool_wait_seconds")
+        payload = make_remote_write([({"__name__": "funnel_m"}, [(1000, 1.0)])])
+        parsed = await ParserPool().decode(payload)
+        assert parsed.n_samples == 1
+        assert STAGES.name == "ingest"
+        assert hist("horaedb_ingest_parse_seconds")[0] == n0 + 1
+        assert hist("horaedb_ingest_pool_wait_seconds")[0] == w0 + 1
+        # the worker's own mark carries the stage's name (it may run on
+        # a thread of its own, so the list's order is not pinned)
+        assert sorted(annotations) == ["ingest.parse", "ingest.parse", "ingest.pool_wait"]
+        assert WAITS.search("ingest.pool_wait") and not WAITS.search("ingest.parse")
+
+
+def big_batch(schema, rows: int, seed: int):
+    rng = np.random.default_rng(seed)
+    return make_batch(schema, rng.integers(0, 1 << 40, rows), rng.integers(0, 8, rows),
+                      rng.integers(10, 1000, rows), rng.random(rows))
+
+
+FLUSH = "horaedb_flush_stage_seconds"
+
+
+class TestWritePath:
+    @pytest.mark.parametrize("rows,stages,least", [
+        # one encode on a worker, one put
+        (150_000, ("sort", "encode", "upload", "sidecar", "manifest"), 0.9),
+        # past 16 MiB the encode streams into the put and rides in `upload`;
+        # starting the producer and joining it lie between the stages
+        (400_000, ("sort", "upload", "sidecar", "manifest"), 0.75),
+    ])
+    @async_test
+    async def test_unbuffered_write_leaves_its_stages(self, rows, stages, least):
+        """Every write, the shipped unbuffered path included, observes
+        sort / encode / upload / manifest, and together they are the
+        write: within a tenth of horaedb_storage_write_seconds."""
+        root = f"funnel/write{rows}"
+        eng = await ObjectBasedStorage.try_new(
+            root, MemStore(), make_schema(), 2, SEGMENT_MS,
+            enable_compaction_scheduler=False, start_background_merger=False,
+        )
+        tracing.configure(sample=1.0)
+        shares = []
+        for seed in range(3):
+            before = {s: hist(FLUSH, table=root, stage=s) for s in stages}
+            w0 = hist("horaedb_storage_write_seconds", table=root)
+            with tracing.trace("write-test") as t:
+                await eng.write(WriteRequest(big_batch(make_schema(), rows, seed),
+                                             TimeRange(10, 1000)))
+            total = 0.0
+            for s in stages:
+                n, secs = hist(FLUSH, table=root, stage=s)
+                assert n - before[s][0] == 1, s
+                total += secs - before[s][1]
+            n, secs = hist("horaedb_storage_write_seconds", table=root)
+            assert n - w0[0] == 1 and total <= secs - w0[1]
+            shares.append(total / (secs - w0[1]))
+            # the stages ride on the storage_write span
+            span = next(s for s in t.spans if s.name == "storage_write")
+            assert set(stages) <= set(span.attrs["stages"])
+        # what lies between the stages is the loop's own scheduling, which a
+        # loaded test machine stretches: the best of three writes is the claim
+        assert max(shares) >= least, shares
+        await eng.close()
+
+    @async_test
+    async def test_a_compactions_write_is_not_a_flush(self, caplog):
+        """A compaction's write_sst goes to the compaction family
+        (`sst_*`), nothing of it to the flush family; the rows it took in
+        are counted, and the finished task leaves one INFO line."""
+        root = "funnel/compact"
+        cfg = StorageConfig(scheduler=SchedulerConfig(input_sst_min_num=2))
+        eng = await ObjectBasedStorage.try_new(
+            root, MemStore(), make_schema(), 2, SEGMENT_MS,
+            config=cfg, start_background_merger=False,
+        )
+        schema = make_schema()
+        for i in range(4):
+            await eng.write(WriteRequest(
+                make_batch(schema, [1, 2 + i], [0, 0], [10, 20], [float(i), 100.0 + i]),
+                TimeRange(10, 21)))
+        inputs = eng.manifest.all_ssts()
+        rows_in = sum(f.meta.num_rows for f in inputs)
+        assert rows_in == 8
+        flush0 = {s: hist(FLUSH, table=root, stage=s)[0]
+                  for s in ("sort", "encode", "upload", "sidecar", "manifest")}
+        comp0 = {s: hist("horaedb_compaction_stage_seconds", stage=s)[0]
+                 for s in ("scan", "encode", "commit", "cleanup", "sst_encode", "sst_upload")}
+        in0 = counter("horaedb_compaction_rows_total", dir="in")
+        out0 = counter("horaedb_compaction_rows_total", dir="out")
+        tracing.configure(sample=1.0)
+        with caplog.at_level(logging.INFO, logger="horaedb_tpu.storage.compaction.executor"):
+            eng.compaction_scheduler.pick_once()
+            for _ in range(750):
+                await asyncio.sleep(0.02)
+                if len(eng.manifest.all_ssts()) == 1:
+                    break
+            await eng.compaction_scheduler.executor.drain()
+        assert len(eng.manifest.all_ssts()) == 1
+        for s, n in flush0.items():
+            assert hist(FLUSH, table=root, stage=s)[0] == n, s
+        for s, n in comp0.items():
+            assert hist("horaedb_compaction_stage_seconds", stage=s)[0] == n + 1, s
+        assert counter("horaedb_compaction_rows_total", dir="in") - in0 == rows_in
+        assert counter("horaedb_compaction_rows_total", dir="out") - out0 == 5
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("compaction done:")]
+        assert len(lines) == 1, lines
+        assert "inputs=4" in lines[0] and "rows_in=8 rows_out=5" in lines[0]
+        for s in ("scan", "encode", "commit", "cleanup"):
+            assert f"'{s}':" in lines[0]
+        root_span = next(
+            tr for tr in tracing.recent(50) if tr["name"] == "compaction")
+        tree = tracing.get(root_span["trace_id"])
+        attrs = tree["root"]["attrs"]
+        assert (attrs["rows_in"], attrs["rows_out"]) == (8, 5) and attrs["bytes_out"] > 0
+        assert {"scan", "encode", "commit", "cleanup"} <= set(attrs["stages"])
+        await eng.close()
+
+    @async_test
+    async def test_scan_path_counts_routes_not_folds(self):
+        """horaedb_scan_path_total: one count a segment scan, named as
+        EXPLAIN's scan_paths names the route."""
+        eng = await ObjectBasedStorage.try_new(
+            "funnel/route", MemStore(), make_schema(), 2, SEGMENT_MS,
+            enable_compaction_scheduler=False, start_background_merger=False,
+        )
+        schema = make_schema()
+        for i in range(2):
+            await eng.write(WriteRequest(
+                make_batch(schema, [1, 2 + i], [0, 0], [10, 20], [float(i), 1.0]),
+                TimeRange(10, 21)))
+        names = ("host_merge", "device_merge_packed", "device_merge", "device_merge_sharded")
+        before = {n: counter("horaedb_scan_path_total", path=n) for n in names}
+        with scanstats.scan_stats() as st:
+            await collect(eng, ScanRequest(range=TimeRange(0, SEGMENT_MS)))
+        routes = sorted(k[len("path_"):] for k in st.counts if k.startswith("path_"))
+        assert len(routes) == 1 and routes[0] in names
+        moved = {n: counter("horaedb_scan_path_total", path=n) - before[n] for n in names}
+        assert moved == {n: (1.0 if n == routes[0] else 0.0) for n in names}
+        await eng.close()
+
+
+class TestKernelsAndCompiles:
+    def test_the_program_is_named_after_the_label(self, annotations):
+        def kernel(x):
+            return x * 2.0
+
+        f = xjit(kernel, kernel="xp_name")
+        text = f.lower(np.zeros(4, np.float32)).as_text()
+        assert "jit_xp_name" in text and "jit_kernel" not in text
+        f(np.zeros(4, np.float32))
+        assert annotations == ["xjit.xp_name"]
+
+    def test_every_compile_is_counted_and_lands_once(self):
+        """An eager jnp compile raises horaedb_xla_compile_seconds and the
+        request's compile lane; a compile inside an XJit call is in the
+        lane once (from the call's own record), not twice."""
+        import jax.numpy as jnp
+
+        xprof.register_metrics()
+        n0, s0 = hist("horaedb_xla_compile_seconds")
+        t0 = xprof.xla_totals()
+        with scanstats.scan_stats() as st:
+            # a shape no other test uses: a fresh eager compile
+            jnp.sort(jnp.arange(1237, dtype=jnp.float32) * 3.0).block_until_ready()
+        n1, s1 = hist("horaedb_xla_compile_seconds")
+        assert n1 > n0 and s1 > s0
+        assert st.counts["compile"] == n1 - n0
+        assert st.seconds["compile"] == pytest.approx(s1 - s0)
+        assert xprof.xla_totals()["compiles"] - t0["compiles"] == n1 - n0
+
+        @xjit(kernel="xp_once")
+        def f(x):
+            return jnp.cumsum(x * 5.0)
+
+        with scanstats.scan_stats() as st2:
+            f(np.arange(1239, dtype=np.float32)).block_until_ready()
+        n2, _ = hist("horaedb_xla_compile_seconds")
+        assert n2 == n1 + 1
+        assert st2.counts["compile"] == 1
+        (entry,) = xprof.kernel_entries(["xp_once"])
+        assert st2.seconds["compile"] == pytest.approx(entry["compile_seconds"], abs=1e-6)
+
+
+class TestServerSurface:
+    async def client(self, tmp_path):
+        from tests.test_server import make_client
+
+        return await make_client(tmp_path)
+
+    @async_test
+    async def test_loop_lag_is_measured(self, tmp_path):
+        client = await self.client(tmp_path)
+        try:
+            await asyncio.sleep(0.1)  # the heartbeat is running
+            n0, s0 = hist("horaedb_loop_lag_seconds")
+            assert n0 >= 2
+            time.sleep(0.2)  # jaxlint: disable=J018 the test holds the loop on purpose
+            await asyncio.sleep(0.05)
+            _, s1 = hist("horaedb_loop_lag_seconds")
+            assert 0.15 <= s1 - s0 <= 0.5
+        finally:
+            await client.close()
+
+    @async_test
+    async def test_kernels_count_every_xla_compile(self, tmp_path):
+        import jax.numpy as jnp
+
+        client = await self.client(tmp_path)
+        try:
+            x0 = (await (await client.get("/debug/kernels")).json())["xla"]
+            assert set(x0) == {"compiles", "compile_seconds", "cache_hits"}
+            jnp.sort(jnp.arange(1241, dtype=jnp.float32) * 7.0).block_until_ready()
+            x1 = (await (await client.get("/debug/kernels")).json())["xla"]
+            assert x1["compiles"] > x0["compiles"]
+            assert x1["compile_seconds"] > x0["compile_seconds"]
+        finally:
+            await client.close()
+
+    @async_test
+    async def test_the_server_owns_profiler_and_device_memory(self, tmp_path):
+        """POST /debug/profile/start|stop around one write leaves a trace
+        whose host lanes carry the funnel's names; /debug/memory has the
+        device's own statistics (none on the CPU)."""
+        from jax.profiler import ProfileData
+        from tests.test_engine import make_remote_write
+
+        client = await self.client(tmp_path)
+        try:
+            mem = await (await client.get("/debug/memory")).json()
+            assert isinstance(mem["device"], dict)
+            r = await client.post("/debug/profile/stop")
+            assert r.status == 409
+            r = await client.post("/debug/profile/start")
+            assert r.status == 400
+            out = tmp_path / "prof"
+            r = await client.post(f"/debug/profile/start?dir={out}")
+            assert r.status == 200 and (await r.json())["start_call_s"] >= 0
+            r = await client.post(f"/debug/profile/start?dir={out}")
+            assert r.status == 409
+            payload = make_remote_write(
+                [({"__name__": "profm", "host": "a"}, [(1000, 1.0)])])
+            assert (await client.post("/api/v1/write", data=payload)).status == 200
+            r = await client.post("/debug/profile/stop")
+            assert r.status == 200 and (await r.json())["stop_call_s"] >= 0
+            (pb,) = list(out.rglob("*.xplane.pb"))
+            names = {e.name for plane in ProfileData.from_file(str(pb)).planes
+                     for line in plane.lines for e in line.events}
+            assert {"ingest.parse", "flush.encode", "flush.upload",
+                    "flush.manifest"} <= names, sorted(n for n in names if "." in n)[:40]
+        finally:
+            await client.close()

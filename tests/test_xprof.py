@@ -81,22 +81,30 @@ class TestCompileCounter:
 
 class TestCatalog:
     def test_catalog_entry_shape_and_cost_envelope(self):
+        """The catalog entry's whole shape. The cost envelope is gone
+        (PR 27): it paid a second compile on the request that first
+        compiled a kernel and nothing read its numbers — so the first
+        call compiles ONCE, which horaedb_xla_compile_seconds shows."""
+        from horaedb_tpu.server.metrics import GLOBAL_METRICS
+
+        def xla_compiles() -> float:
+            return sum(v for fam, _t, sample, _k, v in GLOBAL_METRICS.snapshot_samples()
+                       if sample == "horaedb_xla_compile_seconds_count")
+
         @xjit(kernel="xp_cost")
         def f(x):
             return (x * 2.0).sum()
 
+        xprof.register_metrics()
+        before = xla_compiles()
         f(np.arange(32, dtype=np.float32))
+        assert xla_compiles() == before + 1
         (entry,) = xprof.kernel_entries(["xp_cost"])
-        for key in ("kernel", "compiles", "compile_seconds", "cache_entries",
-                    "signatures", "flops", "bytes_accessed",
-                    "arithmetic_intensity", "cost", "memory"):
-            assert key in entry, key
+        assert set(entry) == {"kernel", "instances", "compiles", "compile_seconds",
+                              "cache_entries", "signatures", "last_compile_ms"}
         assert entry["compiles"] == 1
         assert entry["compile_seconds"] > 0
-        # CPU XLA supports cost analysis in this image (smoke-verified);
-        # if a backend ever stops, the envelope is None — not a crash
-        if entry["cost"] is not None:
-            assert entry["cost"].get("flops", 0) >= 0
+        assert entry["signatures"] == {"(float32[32])": 1}
 
     def test_snapshot_totals_cover_new_compiles(self):
         before = xprof.snapshot()["total_compiles"]
