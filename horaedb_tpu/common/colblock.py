@@ -502,6 +502,18 @@ class ArrowLanes:
         self._chunks[name] = views
         return views
 
+    def chunk_edges(self, names) -> list[tuple[tuple, tuple]]:
+        """(first row, last row) over `names` of every non-empty chunk of
+        the common layout, read as arrow scalars: no lane is wrapped or
+        copied for it."""
+        cols = [self._table.column(n) for n in names]
+        b = self.bounds
+        return [
+            (tuple(c[int(b[i])].as_py() for c in cols),
+             tuple(c[int(b[i + 1]) - 1].as_py() for c in cols))
+            for i in range(len(b) - 1) if b[i + 1] > b[i]
+        ]
+
     def _materialize(self, name: str) -> np.ndarray:
         from horaedb_tpu.ops.blocks import arrow_column_to_numpy
 
@@ -519,6 +531,10 @@ class ArrowLanes:
         got = self._lanes.get(name)
         if got is not None:
             return got
+        if name not in self._chunks and self._table.column(name).num_chunks > 1:
+            # one arrow combine, not a numpy view a chunk and a concat:
+            # the same one copy in two calls instead of three a chunk
+            return self._materialize(name)
         views = self.chunks(name)
         if len(views) == 1:
             a = views[0]

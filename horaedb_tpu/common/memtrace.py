@@ -49,6 +49,7 @@ Kinds are a closed vocabulary:
 from __future__ import annotations
 
 import os
+import threading
 from contextlib import contextmanager
 from contextvars import ContextVar
 
@@ -125,12 +126,11 @@ def mode() -> str:
 
 
 class MemLedger:
-    """Per-query lineage accumulator. Unlocked dict updates, the same
-    concurrency posture as ScanStats: concurrent per-SST workers under
-    one query share the ledger via the copied context and the GIL makes
-    torn totals vanishingly unlikely next to segment-sized work."""
+    """Per-query lineage accumulator, locked as ScanStats is: the
+    per-SST decode workers and the per-segment merge workers of one query
+    share the ledger through the copied context, several at once."""
 
-    __slots__ = ("events", "device_bytes", "peak_delta", "top_sites")
+    __slots__ = ("events", "device_bytes", "peak_delta", "top_sites", "_lock")
 
     def __init__(self) -> None:
         # (stage, kind) -> [events, bytes]
@@ -138,14 +138,16 @@ class MemLedger:
         self.device_bytes = 0
         self.peak_delta: int | None = None
         self.top_sites: list[dict] = []
+        self._lock = threading.Lock()
 
     def add(self, stage: str, kind: str, nbytes: int) -> None:
-        cell = self.events.get((stage, kind))
-        if cell is None:
-            self.events[(stage, kind)] = [1, nbytes]
-        else:
-            cell[0] += 1
-            cell[1] += nbytes
+        with self._lock:
+            cell = self.events.get((stage, kind))
+            if cell is None:
+                self.events[(stage, kind)] = [1, nbytes]
+            else:
+                cell[0] += 1
+                cell[1] += nbytes
 
     def merge(self, other: "MemLedger") -> None:
         """Fold a fragment's ledger in (the cluster coordinator grafts
@@ -245,8 +247,10 @@ def track_bytes(nbytes: int, stage: str, kind: str = "copy") -> None:
     key = (stage, kind)
     bc = _BYTES_CHILD.get(key)
     if bc is None:  # non-canonical stage: resolve once, then cached
-        bc = _BYTES_CHILD[key] = MEM_BYTES.labels(*key)
+        # (events first: another thread that finds the bytes child reads
+        # the events child next)
         _EVENTS_CHILD[key] = MEM_EVENTS.labels(*key)
+        bc = _BYTES_CHILD[key] = MEM_BYTES.labels(*key)
     bc.inc(nbytes)
     _EVENTS_CHILD[key].inc()
     ledger = _ACTIVE.get()
@@ -264,7 +268,8 @@ def device_staged(nbytes: int, stage: str = "h2d") -> None:
     DEVICE_STAGING.inc(nbytes)
     ledger = _ACTIVE.get()
     if ledger is not None:
-        ledger.device_bytes += nbytes
+        with ledger._lock:
+            ledger.device_bytes += nbytes
 
 
 # ---------------------------------------------------------------------------

@@ -97,6 +97,14 @@ class ObjectStore(ABC):
     @abstractmethod
     async def delete(self, path: str) -> None: ...
 
+    async def delete_many(self, paths: list[str]) -> list[BaseException | None]:
+        """Delete each path; what each delete raised (None: deleted), in
+        order, never raising itself: the shape a compaction's clean-up
+        wants, ninety files a task of which sixty sidecars never existed.
+        A store with a cheaper way than one delete a path overrides."""
+        return await asyncio.gather(
+            *(self.delete(p) for p in paths), return_exceptions=True)
+
     @abstractmethod
     async def head(self, path: str) -> ObjectMeta: ...
 
@@ -313,6 +321,23 @@ class LocalStore(ObjectStore):
                 raise NotFound(f"object not found: {path}") from None
 
         await asyncio.to_thread(_delete)
+
+    async def delete_many(self, paths: list[str]) -> list[BaseException | None]:
+        """All of them in ONE thread hop: a hop and its wake-up of the
+        event loop cost more than an unlink."""
+        def _delete_all() -> list:
+            out: list = []
+            for path in paths:
+                try:
+                    os.remove(self._fs_path(path))
+                    out.append(None)
+                except FileNotFoundError:
+                    out.append(NotFound(f"object not found: {path}"))
+                except OSError as e:
+                    out.append(e)
+            return out
+
+        return await asyncio.to_thread(_delete_all)
 
     async def head(self, path: str) -> ObjectMeta:
         def _head() -> ObjectMeta:

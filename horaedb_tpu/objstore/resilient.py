@@ -403,6 +403,12 @@ class ResilientStore(ObjectStore):
     async def delete(self, path: str) -> None:
         await self._call("delete", self._inner.delete, path)
 
+    async def delete_many(self, paths: list[str]) -> list[BaseException | None]:
+        """One guarded call for the batch (breaker, deadline, metrics as
+        one `delete`); what a single path raised comes back as its value
+        and is not retried: the callers' deletes are best-effort."""
+        return await self._call("delete", self._inner.delete_many, paths)
+
     async def head(self, path: str) -> ObjectMeta:
         return await self._call("head", self._inner.head, path)
 

@@ -203,6 +203,12 @@ class Executor:
             await self._gc_rollups()
 
     async def _compact(self, task: Task, done: dict, stages: dict) -> None:
+        """The task's steps, and where each runs: `scan` decodes and
+        merges on threads of the default pool (`asyncio_<n>`, see below),
+        `encode` encodes its shards on the SST executor's threads and
+        awaits their puts, `cleanup` unlinks the inputs in one call of the
+        store; the event loop's thread runs what lies between (the
+        slicing, the manifest update)."""
         from horaedb_tpu.storage import visibility as vis_mod
 
         self.pre_check(task)
@@ -230,7 +236,15 @@ class Executor:
         applied_tombs = tuple(sorted(
             t.id for t in self._manifest.all_tombstones()
         ))
-        # Same merge pipeline as the scan path, on device, builtins kept.
+        # Same merge pipeline as the scan path (`_scan_segment`), builtins
+        # kept, and none of it on the event loop's thread: this coroutine
+        # awaits the parquet decodes (a default-pool thread hop for each
+        # batch's worth of rows: eight for thirty flushes of 2,000 rows),
+        # then ONE worker call that runs host_prep, the planner's merge
+        # (h2d, the kernel's dispatch, the wait on the device and d2h, or
+        # the host route) and materialize, so a merge of any size leaves
+        # the loop to the writers. What follows here on the loop is
+        # `Table.from_batches` and the zero-copy slicing.
         # Memory bound: device memory is O(scan_block_rows) (hierarchical
         # chunked scan), the parquet ENCODE streams to the store at
         # O(row group + chunk) (write_sst), and the merged host columns are
@@ -457,15 +471,11 @@ class Executor:
         paths = [path_gen.generate(i) for i in ids]
         bloom_paths = [path_gen.generate_bloom(i) for i in ids]
         enc_paths = [path_gen.generate_enc(i) for i in ids]
-        results = await asyncio.gather(
-            *(self._storage._store.delete(p) for p in paths),
-            *(self._storage._store.delete(p) for p in bloom_paths),
-            *(self._storage._store.delete(p) for p in enc_paths),
-            return_exceptions=True,
-        )
+        every = paths + bloom_paths + enc_paths
+        results = await self._storage._store.delete_many(every)
         from horaedb_tpu.objstore import NotFound
 
-        for p, r in zip(paths + bloom_paths + enc_paths, results):
+        for p, r in zip(every, results):
             if isinstance(r, NotFound):
                 continue
             if isinstance(r, BaseException):

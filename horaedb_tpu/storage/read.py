@@ -36,7 +36,7 @@ import time
 from collections import OrderedDict
 from contextvars import ContextVar
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import lru_cache, wraps
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -247,6 +247,9 @@ class _HostCalib:
     MIN_ROWS = 50_000     # below this, timer noise dominates the signal
     _sort = _HOST_SORT_S_PER_ROW
     _eval = _HOST_EVAL_S_PER_ROW
+    # merges run on worker threads, several at once: an observation is a
+    # read-modify-write of the estimate
+    _lock = threading.Lock()
 
     @staticmethod
     def enabled() -> bool:
@@ -263,17 +266,22 @@ class _HostCalib:
     @classmethod
     def observe_sort(cls, rows: int, secs: float) -> None:
         if rows >= cls.MIN_ROWS and secs > 0 and cls.enabled():
-            cls._sort += cls.ALPHA * (secs / rows - cls._sort)
+            with cls._lock:
+                cls._sort += cls.ALPHA * (secs / rows - cls._sort)
 
     @classmethod
     def observe_eval(cls, rows_terms: int, secs: float) -> None:
         if rows_terms >= cls.MIN_ROWS and secs > 0 and cls.enabled():
-            cls._eval += cls.ALPHA * (secs / rows_terms - cls._eval)
+            with cls._lock:
+                cls._eval += cls.ALPHA * (secs / rows_terms - cls._eval)
 
     @classmethod
     def reset(cls) -> None:
-        cls._sort = _HOST_SORT_S_PER_ROW
-        cls._eval = _HOST_EVAL_S_PER_ROW
+        with cls._lock:
+            cls._sort = _HOST_SORT_S_PER_ROW
+            cls._eval = _HOST_EVAL_S_PER_ROW
+
+
 # Block size past which an ambient mesh upgrades the packed merge to the
 # cross-chip sample-sort (parallel/merge.py). Below it the all-to-all's
 # fixed cost (extra device sort + exchange + per-device dispatch) outweighs
@@ -337,7 +345,27 @@ def _merge_rows(n: int) -> int:
 _warned_sharded_no_mesh = False
 
 
-@lru_cache(maxsize=2)
+def _kernel_cache(maxsize: int):
+    """`lru_cache` for a kernel builder, built under a lock: merges run
+    on worker threads, and two that meet a new key at once must get ONE
+    kernel. `lru_cache` alone would run the builder for each, and every
+    kernel object is a jit of its own: a second compile of the same
+    program, tens of seconds of it on a TPU."""
+    def wrap(build):
+        cached = lru_cache(maxsize=maxsize)(build)
+        lock = threading.Lock()
+
+        @wraps(build)
+        def get(*key, **kw):
+            with lock:
+                return cached(*key, **kw)
+
+        return get
+
+    return wrap
+
+
+@_kernel_cache(maxsize=2)
 def _packed_merge_kernel(do_dedup: bool):
     """Single-lane merge kernel: the whole (pk..., seq-rank) ordering rides
     one u64 (rejected rows pre-sunk to the all-ones sentinel on host), so
@@ -478,7 +506,7 @@ def _host_merge_indices(
     return final[keep] if keep is not None else final
 
 
-@lru_cache(maxsize=256)
+@_kernel_cache(maxsize=256)
 def _build_index_kernel(
     key_names: tuple[str, ...],
     sort_keys: tuple[str, ...],
@@ -875,7 +903,7 @@ def _host_lane(sorted_cols: dict, name: str, bit_lanes: frozenset) -> np.ndarray
     return a.view(np.float64) if name in bit_lanes else a
 
 
-@lru_cache(maxsize=256)
+@_kernel_cache(maxsize=256)
 def _build_scan_kernel(
     col_names: tuple[str, ...],
     sort_keys: tuple[str, ...],
@@ -956,6 +984,16 @@ def _lanes_presorted(lanes, sort_keys: tuple) -> bool:
     cached = lanes.presorted_cache.get(key)
     if cached is not None:
         return cached
+    # The chunk boundaries first, as arrow scalars: inputs that overlap
+    # (thirty flushes over the same series) fail here after a few scalar
+    # reads, before any chunk is wrapped as numpy. On a worker thread
+    # every arrow or numpy call that lets go of the GIL waits for it
+    # again behind the event loop; a lane wrapped chunk by chunk is three
+    # such calls a chunk.
+    edges = lanes.chunk_edges(key)
+    if any(nxt[0] < prev[1] for prev, nxt in zip(edges, edges[1:])):
+        lanes.presorted_cache[key] = False
+        return False
     chks = {k: lanes.chunks(k) for k in sort_keys}
     nch = len(chks[sort_keys[0]]) if chks[sort_keys[0]] else 0
     ok = True
@@ -1268,6 +1306,22 @@ class ParquetReader:
         evaluate on the encoded form, pages prune on zone maps, lanes
         decode through the sanctioned funnel) — per SST, so mixed v1/v2
         trees scan exactly with each file on its own path."""
+        got = await self._open_sst(sst, columns, predicate, use_block_cache)
+        return await asyncio.to_thread(got) if callable(got) else got
+
+    async def _open_sst(
+        self,
+        sst: SstFile,
+        columns: list[str] | None,
+        predicate: Predicate | None,
+        use_block_cache: bool = True,
+    ):
+        """Everything of `read_sst` that may await, up to the parquet
+        decode: the table itself where none is needed (pruned by its bloom
+        sidecar, served from encoded lanes or from the block cache), else
+        the decode as a zero-argument synchronous call for a worker thread,
+        which returns the table as `read_sst` does (counted, masked, a
+        vanished file raised as NotFound)."""
         # cooperative deadline per SST read: an expired query stops
         # paying IO + decode here, SST by SST (common/deadline.py)
         deadline_ctx.check("sst_read")
@@ -1339,7 +1393,15 @@ class ParquetReader:
                 with old_lock:  # wait out any in-flight read
                     old.close()
 
+        local = self._store.local_path(path)
+        # a store with no local files hands the object over as bytes
+        data = await self._store.get(path) if local is None else None
+
         def _read() -> pa.Table:
+            if data is not None:
+                return _read_pruned(
+                    pq.ParquetFile(io.BytesIO(data)), columns, predicate,
+                    rg_cache, meta_sink if rg_cache else None)
             with self._pf_cache_lock:
                 entry = self._pf_cache.get(path)
                 if entry is not None:
@@ -1353,9 +1415,6 @@ class ParquetReader:
                     finally:
                         handle_lock.release()
                 # handle busy with a concurrent read: open transient
-            local = self._store.local_path(path)
-            if local is None:
-                raise _NeedBytes()
             pf = pq.ParquetFile(local)
             my_lock = threading.Lock()
             my_lock.acquire()  # published pre-acquired: we read it first
@@ -1377,28 +1436,21 @@ class ParquetReader:
                     pf.close()  # transient handle (cache busy or lost race)
                 _close_evicted(evicted)
 
-        def _read_bytes(data: bytes) -> pa.Table:
-            pf = pq.ParquetFile(io.BytesIO(data))
-            return _read_pruned(pf, columns, predicate, rg_cache,
-                                            meta_sink if rg_cache else None)
+        def decode() -> pa.Table:
+            from horaedb_tpu.objstore import NotFound
 
-        from horaedb_tpu.objstore import NotFound
+            # a caller's `io_decode` stage marks a stage in progress on
+            # the thread that awaits; this marks the thread that decodes
+            try:
+                table = scanstats.SCAN.on_worker("io_decode", _read)
+            except FileNotFoundError as e:
+                # compaction deleted the file after the caller's manifest
+                # snapshot; normalized so scan layers can refresh + retry
+                raise NotFound(f"sst object vanished: {path}") from e
+            scanstats.note("bytes_scanned", int(table.nbytes))
+            return self._mask_visibility(sst, table)
 
-        # the callers' `io_decode` stage marks a stage in progress on the
-        # loop's thread; on_worker marks the thread that decodes
-        on_worker = scanstats.SCAN.on_worker
-        try:
-            table = await asyncio.to_thread(on_worker, "io_decode", _read)
-        except _NeedBytes:
-            data = await self._store.get(path)
-            table = await asyncio.to_thread(
-                on_worker, "io_decode", _read_bytes, data)
-        except FileNotFoundError as e:
-            # compaction deleted the file after the caller's manifest
-            # snapshot; normalized so scan layers can refresh + retry
-            raise NotFound(f"sst object vanished: {path}") from e
-        scanstats.note("bytes_scanned", int(table.nbytes))
-        return self._mask_visibility(sst, table)
+        return decode
 
     async def _enc_sidecar(self, sst: SstFile):
         """Cached decoded `.enc` sidecar of a format-v2 SST, or None
@@ -1666,11 +1718,22 @@ class ParquetReader:
     ) -> list[pa.RecordBatch]:
         """The fused device pipeline for one time segment.
 
+        The event loop's part is to start things and to take what they
+        give: `io_decode` awaits one parquet decode an SST (each on a
+        thread of the default pool), then `merge_wait` awaits
+        `_merge_segment`, ONE call on a thread of the same pool
+        (`asyncio_<n>`) that runs every stage from `host_prep` to
+        `materialize`, the wait on the device among them. Always, whatever
+        the size and the route: a compaction's merge and a query's are
+        the same call.
+
         Segments whose SSTs exceed `scan_block_rows` in total take the
         hierarchical path: per-chunk device passes (filter+merge+dedup) whose
         sorted outputs merge in a device tree — the blockwise/carry-state
         streaming shape of SURVEY §5.7 (LastValue dedup is idempotent across
         levels, so intermediate dedup is safe; Append mode never dedups).
+        That path (`_scan_segment_chunked`) and the binary-key host path
+        still run their merges on the loop's thread.
         """
         # shared prologue/epilogue with the chunked path lives in
         # _resolve_read_names/_output_names/_slice_batches
@@ -1699,17 +1762,64 @@ class ParquetReader:
                     use_block_cache=use_block_cache,
                 )
             # binary columns keep the single-block hybrid path
-        schema = self._schema
         read_names = self._resolve_read_names(projections, keep_builtin)
 
         with scanstats.stage("io_decode"):
-            tables = await asyncio.gather(
-                *(self.read_sst(s, read_names, predicate,
-                   use_block_cache=use_block_cache) for s in ssts)
+            opened = await asyncio.gather(
+                *(self._open_sst(s, read_names, predicate, use_block_cache)
+                  for s in ssts)
             )
-        tables = [t for t in tables if t.num_rows > 0]
+            # A thread hop, and the wake-up of the loop that ends it, is
+            # worth a batch of rows: an SST of more decodes on a thread of
+            # its own, smaller ones share a hop up to a batch between them
+            # (a compaction's inputs are thirty files of 2,000 rows a task:
+            # eight hops, not thirty).
+            jobs: list[list[int]] = []
+            rows = 0
+            for i, (sst, got) in enumerate(zip(ssts, opened)):
+                if not callable(got):
+                    continue
+                if not jobs or rows + sst.meta.num_rows > DEFAULT_SCAN_BATCH_SIZE:
+                    jobs.append([])
+                    rows = 0
+                jobs[-1].append(i)
+                rows += sst.meta.num_rows
+            def decode_job(job: list[int]) -> list[pa.Table]:
+                return [opened[i]() for i in job]
+
+            decoded = await asyncio.gather(
+                *(asyncio.to_thread(decode_job, job) for job in jobs))
+            for job, tables in zip(jobs, decoded):
+                for i, table in zip(job, tables):
+                    opened[i] = table
+        tables = [t for t in opened if t.num_rows > 0]
         if not tables:
             return []
+        # the loop starts the merge and takes its batches; everything
+        # between runs on one worker thread, and this stage is the await
+        # itself, the wait for a thread included
+        with scanstats.stage(scanstats.MERGE_WAIT):
+            return await asyncio.to_thread(
+                self._merge_segment, tables, predicate, read_names,
+                keep_builtin, batch_size,
+            )
+
+    def _merge_segment(
+        self,
+        tables: list[pa.Table],
+        predicate: Predicate | None,
+        read_names: list[str],
+        keep_builtin: bool,
+        batch_size: int,
+    ) -> list[pa.RecordBatch]:
+        """The synchronous tail of a segment scan, decoded tables in and
+        record batches out, as ONE call on a worker thread: `host_prep`,
+        the planner and its merge (`host_merge`, or `h2d`, the kernel's
+        dispatch, the `device_merge` wait and `d2h`) and `materialize`.
+        The thread's context is the coroutine's (asyncio.to_thread copies
+        it), so each stage reaches the same histogram, span, collector,
+        ledger and deadline as it did on the loop."""
+        schema = self._schema
         with scanstats.stage("host_prep"):
             tables = _order_tables_by_first_key(
                 tables, tuple(schema.primary_key_names) + (SEQ_COLUMN_NAME,)
@@ -1734,10 +1844,7 @@ class ParquetReader:
                 sorted_cols, perm, _keep, starts, kept, numeric_names,
                 binary_names, bit_lanes,
             ) = self._fused_pass(table, predicate)
-            # group-byte concatenation + arrow rebuild is CPU-bound
-            # host work: off the event loop (J018)
-            result = await asyncio.to_thread(
-                self._materialize_append_mode,
+            result = self._materialize_append_mode(
                 table, sorted_cols, np.asarray(perm), np.asarray(starts),
                 int(kept), numeric_names, binary_names, out_names, bit_lanes,
             )
@@ -2540,10 +2647,6 @@ class ParquetReader:
         return pa.RecordBatch.from_arrays(
             cols, schema=pa.schema([table.schema.field(n) for n in out_names])
         )
-
-
-class _NeedBytes(Exception):
-    pass
 
 
 def _select_row_groups(meta, arrow_schema, predicate) -> list[int]:

@@ -65,6 +65,15 @@ _STAGE_LANE = {
     "device_agg": "kernel",
 }
 
+# The loop-side stage of a segment scan's merge (storage/read.py
+# `_scan_segment`): the await of the worker thread that runs `host_prep` to
+# `materialize`, queueing included. Its count is merges run off the loop;
+# its sum less those inner stages is the wait for a thread. The inner
+# stages are observed under their own names, so a sum of stages leaves this
+# one out (attribution() does) or counts every merge twice. On the
+# profiler's timeline it is a wait by name: no idle gap is named after it.
+MERGE_WAIT = "merge_wait"
+
 STAGE_SECONDS = GLOBAL_METRICS.histogram(
     "horaedb_scan_stage_seconds",
     help="Per-stage scan time by lane (io_decode, host_prep, transfer, "
@@ -82,7 +91,7 @@ STAGE_SECONDS = GLOBAL_METRICS.histogram(
 # first-class because the compressed-domain scan's whole bet is moving
 # wall time from io_decode/transfer into this (much smaller) lane.
 for _lane in ("io_decode", "host_prep", "transfer", "kernel", "compile",
-              "decode"):
+              "decode", MERGE_WAIT):
     STAGE_SECONDS.labels(_lane)
 del _lane
 
@@ -137,13 +146,19 @@ class ScanStats:
     # pinned `memory` EXPLAIN verdict without per-handler wiring. None
     # under HORAEDB_MEMTRACE=off.
     mem: object = None
+    # a query's segments merge on worker threads, several at once, and
+    # every one of them folds into this collector
+    _lock: threading.Lock = field(
+        default_factory=threading.Lock, repr=False, compare=False)
 
     def add(self, stage: str, secs: float) -> None:
-        self.seconds[stage] = self.seconds.get(stage, 0.0) + secs
-        self.counts[stage] = self.counts.get(stage, 0) + 1
+        with self._lock:
+            self.seconds[stage] = self.seconds.get(stage, 0.0) + secs
+            self.counts[stage] = self.counts.get(stage, 0) + 1
 
     def count(self, stage: str, n: int = 1) -> None:
-        self.counts[stage] = self.counts.get(stage, 0) + n
+        with self._lock:
+            self.counts[stage] = self.counts.get(stage, 0) + n
 
     def as_dict(self) -> dict:
         out = {f"{k}_s": round(v, 4) for k, v in self.seconds.items()}
@@ -159,7 +174,8 @@ class ScanStats:
         lanes = {"io": 0.0, "host": 0.0, "transfer": 0.0, "kernel": 0.0,
                  "compile": 0.0, "decode": 0.0}
         for stage_name, secs in self.seconds.items():
-            lanes[_BOUND_LANE.get(stage_name, "host")] += secs
+            if stage_name != MERGE_WAIT:  # its inner stages are all here
+                lanes[_BOUND_LANE.get(stage_name, "host")] += secs
         bound = max(lanes, key=lanes.get) if any(lanes.values()) else None
         return {
             "lanes_s": {k: round(v, 6) for k, v in lanes.items()},
@@ -429,7 +445,8 @@ def kernel_use(name: str) -> None:
     steady-state budget as span())."""
     st = _ACTIVE.get()
     if st is not None:
-        st.kernels[name] = st.kernels.get(name, 0) + 1
+        with st._lock:
+            st.kernels[name] = st.kernels.get(name, 0) + 1
 
 
 def active() -> bool:
@@ -470,4 +487,5 @@ def note_max(name: str, n: int) -> None:
     that repeat per sub-query and would over-report if accumulated."""
     st = _ACTIVE.get()
     if st is not None:
-        st.counts[name] = max(st.counts.get(name, 0), n)
+        with st._lock:
+            st.counts[name] = max(st.counts.get(name, 0), n)

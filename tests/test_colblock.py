@@ -244,6 +244,30 @@ class TestArrowLanes:
         assert v["copies"] == 1  # the one sanctioned concat
         assert np.array_equal(a, np.arange(12, dtype=np.int64))
 
+    def test_chunk_edges_and_the_presorted_probe_wrap_nothing_on_overlap(self):
+        """The sortedness probe reads the chunk boundaries as arrow
+        scalars first: chunks that overlap are refused before one lane
+        is wrapped; chunks in order go on to the row-wise check."""
+        from horaedb_tpu.storage.read import _lanes_presorted
+
+        t = self.chunked_table()
+        lanes = colblock.ArrowLanes(t)
+        assert lanes.chunk_edges(("ts",)) == [((0,), (5,)), ((6,), (11,))]
+        assert _lanes_presorted(lanes, ("ts",)) is True
+        overlap = pa.Table.from_batches(list(reversed(t.to_batches())))
+        lanes = colblock.ArrowLanes(overlap)
+        with memtrace.mem_trace() as led:
+            assert _lanes_presorted(lanes, ("ts",)) is False
+            assert _lanes_presorted(lanes, ("ts",)) is False  # memoized
+        assert lanes._chunks == {} and lanes._lanes == {}
+        v = memtrace.verdict(led)
+        assert v["views"] == 0 and v["copies"] == 0
+        # the lane a device route asks for next: one arrow combine, one copy
+        with memtrace.mem_trace() as led:
+            a = lanes.lane("ts")
+        assert memtrace.verdict(led)["copies"] == 1 and lanes._chunks == {}
+        assert np.array_equal(a, np.r_[6:12, 0:6])
+
     def test_gather_sorted_matches_full_gather(self):
         lanes = colblock.ArrowLanes(self.chunked_table())
         idx = np.array([0, 3, 5, 6, 7, 11], dtype=np.int64)

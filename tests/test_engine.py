@@ -551,14 +551,14 @@ class TestLimitPushdown:
             await eng.write_parsed(PooledParser.decode(p))
 
         reader = eng.data_table.parquet_reader
-        orig = reader.read_sst
+        orig = reader._open_sst  # a segment scan opens each of its SSTs here
         touched = []
 
-        async def spy(sst, columns, predicate, **kw):
+        async def spy(sst, *args, **kw):
             touched.append(sst.id)
-            return await orig(sst, columns, predicate, **kw)
+            return await orig(sst, *args, **kw)
 
-        reader.read_sst = spy
+        reader._open_sst = spy
         t = await eng.query(
             QueryRequest(metric=b"cpu", start_ms=0, end_ms=10 * HOUR, limit=12)
         )
@@ -567,7 +567,7 @@ class TestLimitPushdown:
         assert len(touched) == 2, touched
         # values are the oldest 12
         assert t.column("value").to_pylist() == [float(i) for i in range(10)] + [100.0, 101.0]
-        reader.read_sst = orig
+        reader._open_sst = orig
         # unlimited query still sees everything
         t_all = await eng.query(QueryRequest(metric=b"cpu", start_ms=0, end_ms=10 * HOUR))
         assert t_all.num_rows == 50

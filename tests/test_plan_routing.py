@@ -12,6 +12,7 @@ import time
 
 import numpy as np
 import pyarrow as pa
+import pytest
 
 from horaedb_tpu.storage import scanstats
 from horaedb_tpu.storage.config import UpdateMode
@@ -155,6 +156,132 @@ class TestChunkedDeviceDoubleBuffer:
         await eng.close()
 
 
+class TestMergesAtOnce:
+    """A segment scan's merge runs on a worker thread, so several run at
+    once: what they share (the planner's rolling host estimate, the kernel
+    builders' caches, the collectors) must take it."""
+
+    @pytest.mark.parametrize("path", ["host", "device"])
+    @async_test
+    async def test_concurrent_segment_scans_equal_sequential(self, monkeypatch, path):
+        import asyncio
+        import math
+
+        from horaedb_tpu.objstore import MemStore
+        from horaedb_tpu.ops import filter as F
+        from horaedb_tpu.storage import ObjectBasedStorage, TimeRange, WriteRequest
+        from horaedb_tpu.storage.read import ParquetReader, _HostCalib
+
+        schema = pa.schema([("pk", pa.int64()), ("ts", pa.int64()), ("v", pa.float64())])
+        eng = await ObjectBasedStorage.try_new(
+            f"db-at-once-{path}", MemStore(), schema, num_primary_keys=2,
+            segment_duration_ms=3_600_000,
+            enable_compaction_scheduler=False, start_background_merger=False,
+        )
+        rng = np.random.default_rng(13)
+        rows = 20_000
+        for i in range(12):  # the same keys again and again: every SST overlaps
+            batch = pa.RecordBatch.from_pydict({
+                "pk": rng.integers(0, 3_000, rows),
+                "ts": rng.integers(0, 200, rows),
+                "v": np.full(rows, float(i)),
+            }, schema=schema)
+            await eng.write(WriteRequest(batch, TimeRange(0, 3_600_000)))
+        ssts = sorted(eng.manifest.all_ssts(), key=lambda f: f.id)
+        assert len(ssts) == 12
+        # 8 scans over overlapping sets of 5 SSTs (100,000 rows each: past
+        # _HostCalib.MIN_ROWS, so every host merge is an observation),
+        # every other one under a predicate
+        calls = [(ssts[i:i + 5], F.Compare("pk", "lt", 2_000) if i % 2 else None)
+                 for i in range(8)]
+        reader = eng.parquet_reader
+
+        async def scan(subset, predicate) -> pa.Table:
+            return pa.Table.from_batches(
+                await reader.scan_segment(subset, predicate, None, True))
+
+        in_flight, peak, lock = [0], [0], threading.Lock()
+        real = ParquetReader._merge_segment
+
+        def counted(self, *args):
+            with lock:
+                in_flight[0] += 1
+                peak[0] = max(peak[0], in_flight[0])
+            try:
+                return real(self, *args)
+            finally:
+                with lock:
+                    in_flight[0] -= 1
+
+        monkeypatch.setattr(ParquetReader, "_merge_segment", counted)
+        monkeypatch.setattr(_LinkProfile, "_cached", dict(FAST_LINK))
+        monkeypatch.setenv("HORAEDB_SCAN_PATH", path)
+        _HostCalib.reset()
+        try:
+            one_by_one = [await scan(*c) for c in calls]
+            assert peak[0] == 1
+            with scanstats.scan_stats() as st:
+                at_once = await asyncio.gather(*(scan(*c) for c in calls))
+            assert peak[0] >= 2, "the merges did not overlap"
+            for want, got in zip(one_by_one, at_once):
+                assert want.num_rows > 0 and got.equals(want)
+            # one collector took the stages of all eight, none lost
+            assert st.counts[scanstats.MERGE_WAIT] == 8
+            assert st.counts["materialize"] == 8
+            route = "path_host_merge" if path == "host" else "path_device_merge"
+            assert sum(v for k, v in st.counts.items() if k.startswith(route)) == 8
+            est = _HostCalib.sort_s_per_row()
+            assert math.isfinite(est) and est > 0
+            assert math.isfinite(_HostCalib.eval_s_per_row()) and _HostCalib.eval_s_per_row() > 0
+        finally:
+            _HostCalib.reset()
+            await eng.close()
+
+
+    def test_what_the_workers_share_loses_no_update(self):
+        """16 threads (more than cores) under a short switch interval fold
+        into one collector, one ledger and the planner's estimate: every
+        count arrives, and the estimate stays a convex mix of what it saw."""
+        import sys
+
+        from horaedb_tpu.common import memtrace
+        from horaedb_tpu.storage.read import _HostCalib
+
+        if memtrace.mode() == "off":
+            pytest.skip("no ledger under HORAEDB_MEMTRACE=off")
+        threads, rounds = 16, 2_000
+        st = scanstats.ScanStats()
+        ledger = memtrace.MemLedger()
+        start = threading.Barrier(threads)
+
+        def work():
+            start.wait(timeout=30)
+            for _ in range(rounds):
+                st.add("host_merge", 0.5)
+                st.count("path_host_merge")
+                ledger.add("host_prep", "copy", 3)
+                _HostCalib.observe_sort(100_000, 0.03)  # 300 ns a row
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        _HostCalib.reset()
+        try:
+            ts = [threading.Thread(target=work) for _ in range(threads)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in ts)
+            n = threads * rounds
+            assert st.counts["host_merge"] == st.counts["path_host_merge"] == n
+            assert st.seconds["host_merge"] == 0.5 * n
+            assert ledger.events[("host_prep", "copy")] == [n, 3 * n]
+            assert 200e-9 <= _HostCalib.sort_s_per_row() <= 300e-9 * (1 + 1e-9)
+        finally:
+            sys.setswitchinterval(old)
+            _HostCalib.reset()
+
+
 class TestMergeShapeClasses:
     def test_merge_rows_are_power_of_two_classes(self):
         from horaedb_tpu.storage.read import _merge_rows
@@ -238,9 +365,6 @@ class TestLinkProfile:
         with pytest.raises(RuntimeError, match="device unreachable"):
             _run(schema, n, cols)
         assert _LinkProfile._cached is None
-
-
-import pytest
 
 
 class TestPlannerSelfCalibration:

@@ -7,6 +7,7 @@ is measured; the scan-path counter counts routes."""
 
 import asyncio
 import logging
+import threading
 import time
 
 import numpy as np
@@ -292,6 +293,185 @@ class TestWritePath:
         assert len(routes) == 1 and routes[0] in names
         moved = {n: counter("horaedb_scan_path_total", path=n) - before[n] for n in names}
         assert moved == {n: (1.0 if n == routes[0] else 0.0) for n in names}
+        await eng.close()
+
+
+class TestMergeOffTheLoop:
+    """A segment scan's merge (host_prep, the planner's merge with its wait
+    on the device, materialize) is ONE call on a worker thread: the loop
+    starts it and takes its batches."""
+
+    SLOW_S = 0.3
+    STAGES = ("host_prep", "materialize", scanstats.MERGE_WAIT)
+
+    @staticmethod
+    def scan_hists() -> dict:
+        return {s: hist("horaedb_scan_stage_seconds", stage=s)
+                for s in ("host_prep", "host_merge", "kernel", "materialize",
+                          scanstats.MERGE_WAIT)}
+
+    @staticmethod
+    async def under_heartbeat(kind: str, work) -> tuple[float, object]:
+        """Run `work()` beside a heartbeat; how late the heartbeat was: its
+        worst wake-up (a 10 ms task of the test's own), or what the
+        server's `loop_lag_heartbeat` added to horaedb_loop_lag_seconds."""
+        from horaedb_tpu.server.main import loop_lag_heartbeat
+
+        loop = asyncio.get_running_loop()
+        worst = [0.0]
+
+        async def own() -> None:
+            while True:
+                due = loop.time() + 0.01
+                await asyncio.sleep(0.01)
+                worst[0] = max(worst[0], loop.time() - due)
+
+        beat = asyncio.create_task(own() if kind == "own" else loop_lag_heartbeat())
+        await asyncio.sleep(0.05)  # the heartbeat is running
+        _, s0 = hist("horaedb_loop_lag_seconds")
+        worst[0] = 0.0
+        try:
+            out = await work()
+            await asyncio.sleep(0.03)  # a wake-up held back would land here
+        finally:
+            beat.cancel()
+        late = worst[0] if kind == "own" else hist("horaedb_loop_lag_seconds")[1] - s0
+        return late, out
+
+    @pytest.mark.parametrize("heartbeat", ["own", "server"])
+    @async_test
+    async def test_the_loop_stays_free_and_the_stages_arrive(self, monkeypatch, heartbeat):
+        from horaedb_tpu.storage import read as read_mod
+
+        real = read_mod._plan_and_merge
+        ran_on: list[str] = []
+
+        def slow(*args, **kwargs):
+            # synchronous, as the wait on the device is
+            ran_on.append(threading.current_thread().name)
+            time.sleep(self.SLOW_S)  # jaxlint: disable=J018 the merge is slow on purpose
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(read_mod, "_plan_and_merge", slow)
+        root = f"funnel/offloop-{heartbeat}"
+        cfg = StorageConfig(scheduler=SchedulerConfig(input_sst_min_num=2))
+        eng = await ObjectBasedStorage.try_new(
+            root, MemStore(), make_schema(), 2, SEGMENT_MS,
+            config=cfg, start_background_merger=False,
+        )
+        schema = make_schema()
+
+        async def four_ssts():
+            for i in range(4):
+                await eng.write(WriteRequest(
+                    make_batch(schema, [1, 2 + i], [0, 0], [10, 20], [float(i), 100.0 + i]),
+                    TimeRange(10, 21)))
+
+        async def raw_scan():
+            with tracing.trace("offloop-scan") as t, scanstats.scan_stats() as st:
+                table = await collect(eng, ScanRequest(range=TimeRange(0, SEGMENT_MS)))
+            return t, st, table
+
+        async def compaction():
+            eng.compaction_scheduler.pick_once()
+            for _ in range(750):
+                await asyncio.sleep(0.02)
+                if len(eng.manifest.all_ssts()) == 1:
+                    break
+            await eng.compaction_scheduler.executor.drain()
+
+        async def free_loop(work, prepare=None):
+            """`work` beside the heartbeat, until one run finds the loop
+            free: a loaded test machine makes a heartbeat late now and then
+            on its own, a merge on the loop makes it late by SLOW_S every
+            time. What the last run left behind is what the caller checks."""
+            for _ in range(4):
+                if prepare is not None:
+                    await prepare()
+                ran_on.clear()
+                before = self.scan_hists()
+                late, out = await self.under_heartbeat(heartbeat, work)
+                if late < 0.1:
+                    break
+            assert late < 0.1, f"the loop was held {late:.3f} s by a {self.SLOW_S} s merge"
+            assert len(ran_on) == 1 and ran_on[0].startswith("asyncio_"), ran_on
+            return before, self.scan_hists(), out
+
+        await four_ssts()
+        tracing.configure(sample=1.0)
+        # -- the raw scan: the loop is free, all three sinks are fed ----------
+        before, after, (t, st, table) = await free_loop(raw_scan)
+        assert table.num_rows == 5
+        merged = [s for s in ("host_merge", "kernel") if after[s][0] > before[s][0]]
+        assert len(merged) == 1, (before, after)
+        for s in self.STAGES:
+            assert after[s][0] > before[s][0], s
+        # the await counted once a merge, and holds the merge's stages
+        assert after[scanstats.MERGE_WAIT][0] - before[scanstats.MERGE_WAIT][0] == 1
+        assert st.counts[scanstats.MERGE_WAIT] == 1
+        inner = {"host_merge": "host_merge", "kernel": "device_merge"}[merged[0]]
+        assert {"io_decode", inner, *self.STAGES} <= set(st.seconds)
+        assert st.seconds[scanstats.MERGE_WAIT] >= self.SLOW_S + st.seconds["materialize"]
+        # ... and is in no sum of lanes twice
+        lanes = st.attribution()["lanes_s"]
+        assert sum(lanes.values()) == pytest.approx(
+            sum(v for k, v in st.seconds.items() if k != scanstats.MERGE_WAIT), abs=1e-5)
+        (span,) = [sp for sp in t.spans if sp.name == "scan_segment"]
+        assert {"io_decode", inner, *self.STAGES} <= set(span.attrs["stages"])
+
+        # -- the compaction: the same call, from the executor's task ---------
+        # (a run that has to be made again finds the last one's output and
+        # four new files to merge)
+        before, after, _ = await free_loop(compaction, prepare=four_ssts)
+        assert len(eng.manifest.all_ssts()) == 1
+        assert after[scanstats.MERGE_WAIT][0] - before[scanstats.MERGE_WAIT][0] == 1
+        for s in self.STAGES:
+            assert after[s][0] > before[s][0], s
+        root_span = next(tr for tr in tracing.recent(50) if tr["name"] == "compaction")
+        tree = tracing.get(root_span["trace_id"])
+        assert tree["root"]["attrs"]["stages"]["scan"] >= self.SLOW_S
+        (seg,) = [c for c in tree["root"]["children"] if c["name"] == "scan_segment"]
+        assert {"host_prep", "materialize", scanstats.MERGE_WAIT} <= set(seg["attrs"]["stages"])
+        await eng.close()
+
+
+    @async_test
+    async def test_a_decode_hop_is_worth_a_batch_of_rows(self, monkeypatch):
+        """Small SSTs share a thread hop up to a batch of rows between
+        them, a larger one decodes on a thread of its own, and the merge is
+        one hop more: three files of two rows and one of 9,192 are three
+        hops, not five."""
+        from horaedb_tpu.storage.read import DEFAULT_SCAN_BATCH_SIZE, ParquetReader
+
+        eng = await ObjectBasedStorage.try_new(
+            "funnel/hops", MemStore(), make_schema(), 2, SEGMENT_MS,
+            enable_compaction_scheduler=False, start_background_merger=False,
+        )
+        schema = make_schema()
+        for i in range(3):
+            await eng.write(WriteRequest(
+                make_batch(schema, [1, 2 + i], [0, 0], [10, 20], [float(i), 100.0 + i]),
+                TimeRange(10, 21)))
+        big = DEFAULT_SCAN_BATCH_SIZE + 1000
+        await eng.write(WriteRequest(big_batch(schema, big, 5), TimeRange(10, 1000)))
+        hops: list[str] = []
+        real = asyncio.to_thread
+
+        async def counting(fn, *args, **kwargs):
+            hops.append(getattr(fn, "__qualname__", repr(fn)))
+            return await real(fn, *args, **kwargs)
+
+        monkeypatch.setattr(asyncio, "to_thread", counting)
+        with scanstats.scan_stats() as st:
+            table = await collect(eng, ScanRequest(range=TimeRange(0, SEGMENT_MS)))
+        assert table.num_rows >= big
+        scan_hops = [h for h in hops if h.startswith(ParquetReader.__name__ + ".")]
+        assert sorted(scan_hops) == [
+            "ParquetReader._merge_segment",
+            "ParquetReader._scan_segment.<locals>.decode_job",
+            "ParquetReader._scan_segment.<locals>.decode_job",
+        ], hops
+        assert st.counts["ssts_read"] == 4 and st.counts["io_decode"] == 1
         await eng.close()
 
 
