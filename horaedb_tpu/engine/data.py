@@ -1460,4 +1460,4 @@ class SampleManager:
             t, sid_dense.astype(np.int32), v, rng.start, bucket_ms,
             num_series=len(uniq), num_buckets=num_buckets,
         )
-        return [int(x) for x in uniq], {k: np.asarray(val) for k, val in out.items()}
+        return [int(x) for x in uniq], out

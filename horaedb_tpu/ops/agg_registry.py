@@ -112,6 +112,11 @@ SORTED_IMPLS: dict[str, AggImpl] = {
                 "rank prologue instead of cumsum"),
         AggImpl("lanes", "device", True, (),
                 "lane-parallel vmap scatter over partial grids"),
+        AggImpl("runs", "device", True, (),
+                "segmented scan over the sorted runs and a binary search "
+                "per cell: no scatter, the value lane's own dtype — what "
+                "64-bit lanes take on an accelerator, whose scatter over "
+                "them serialises"),
         AggImpl("reduceat", "host", False, ("cpu",),
                 "host run-boundary lane: np.add.reduceat over "
                 "searchsorted/diff boundaries — near memory-bandwidth "

@@ -17,13 +17,18 @@ u64 TSIDs to dense series indices before dispatch (ops/__init__ docstring).
 
 from __future__ import annotations
 
+import bisect
+import threading
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from horaedb_tpu.common.error import ensure
 from horaedb_tpu.common.xprof import xjit
-from horaedb_tpu.ops.sort import f64_order_i64
+from horaedb_tpu.ops.sort import f64_order_i64, pow2_rows
+from horaedb_tpu.server.metrics import GLOBAL_METRICS
 
 
 def _masked_index(index: jax.Array, valid: jax.Array, num_segments: int) -> jax.Array:
@@ -211,6 +216,295 @@ def f64_from_order_keys(keys: np.ndarray) -> np.ndarray:
     return out
 
 
+# smallest padded length of a fold: every row count a one-host hour of 10 s
+# samples can cut (1 to 361) is ONE row class, so one compiled program
+_MIN_FOLD_ROWS = 512
+# grid classes stop here: a larger grid keeps its own shape (one program a
+# shape, as before) instead of up to four times the cells in padding
+_MAX_CLASS_CELLS = 1 << 24
+
+FOLDS_TOTAL = GLOBAL_METRICS.counter(
+    "horaedb_pushdown_folds_total",
+    help="Folds of the aggregate pushdown (one sorted run reduced to its "
+         "grids), by the implementation that ran: a device program's "
+         "(scatter, runs, block, ...) or the host lane (reduceat).",
+    labelnames=("impl",),
+)
+FOLD_ROWS_TOTAL = GLOBAL_METRICS.counter(
+    "horaedb_pushdown_rows_total",
+    help="Rows the pushdown's folds took in: real = the scan's rows, "
+         "padded = the rows added to reach the program's row class.",
+    labelnames=("kind",),
+)
+for _kind in ("real", "padded"):
+    FOLD_ROWS_TOTAL.labels(_kind)
+del _kind
+
+
+# the largest compiled row class a smaller fold may ride instead of
+# compiling its own: 2^19 rows are 15 MB of lanes, a few milliseconds
+_MAX_RIDE_ROWS = 1 << 19
+
+
+class _RowClasses:
+    """The row classes the fold's program has been run at, by its other
+    classes (grid, flags, implementation, dtypes). A fold takes the smallest
+    of them that holds its rows (up to `_MAX_RIDE_ROWS`) before it adds its
+    own: a window that starts at a random second cuts row counts of every
+    size out of a segment, down to a handful, and the small classes are too
+    rare for any warm-up to meet — once the largest class a panel needs has
+    compiled, no cut of it compiles again."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._rows: dict[tuple, list[int]] = {}
+
+    def pick(self, key: tuple, natural: int) -> int:
+        with self._lock:
+            have = self._rows.setdefault(key, [])
+            for rows in have:
+                if natural <= rows <= _MAX_RIDE_ROWS:
+                    return rows
+            bisect.insort(have, natural)
+            return natural
+
+
+_ROW_CLASSES = _RowClasses()
+
+
+class FoldRun(NamedTuple):
+    """What one fold ran as: the implementation and, for a device program,
+    its classes (the padded row count and the padded grid)."""
+
+    impl: str
+    rows_real: int
+    rows_class: int      # 0 on the host lane: nothing is padded there
+    grid_class: tuple    # (series, buckets) of the program's grid
+
+
+def fold_classes(n: int, num_series: int, num_buckets: int) -> tuple[int, int, int]:
+    """(rows, series, buckets) a fold of n rows into a num_series x
+    num_buckets grid is padded to: power-of-two classes on all three axes,
+    so that compiled programs are shared by every window and panel of a
+    class (the batcher pads its three axes the same way)."""
+    rows = pow2_rows(n, floor=_MIN_FOLD_ROWS)
+    series, buckets = pow2_rows(num_series, floor=1), pow2_rows(num_buckets, floor=1)
+    if series * buckets > _MAX_CLASS_CELLS:
+        series, buckets = num_series, num_buckets
+    return rows, series, buckets
+
+
+def _fold_grids(ts, series_idx, values, row_ok, order_key, t0, bucket_ms,
+                num_series: int, num_buckets: int, with_minmax: bool,
+                impl: str | None):
+    """The fold's arithmetic, traceable: rows SORTED by (series, ts) to flat
+    [num_series * num_buckets] grids. Buckets, the in-grid mask, the cell
+    keys, then sum and count through the sorted-segment reduction `impl`
+    names (ops/blockagg.py) and, with `with_minmax`, min and max.
+
+    `row_ok` (bool or None) excludes rows without breaking the sorted runs:
+    an excluded row keeps its monotone key and rides the weight column with
+    weight 0. `order_key` (i64 or None) is the min lane of `f64_order_keys`:
+    the selections then reduce it (its max lane is made here: a NaN row is
+    the smallest key on one lane and the largest on the other) and come
+    back as i64 keys for `f64_from_order_keys`; without it they reduce
+    `values` themselves."""
+    from horaedb_tpu.ops.blockagg import (
+        _F32_EXACT,
+        _scatter_min_max,
+        _scatter_sum_count,
+        sorted_segment_min_max,
+        sorted_segment_sum_count,
+    )
+
+    num_cells = num_series * num_buckets
+    bucket = ((ts - t0) // bucket_ms).astype(jnp.int32)
+    ok = (
+        (bucket >= 0) & (bucket < num_buckets)
+        & (series_idx >= 0) & (series_idx < num_series)
+    )
+    if row_ok is not None:
+        ok = ok & row_ok
+    safe, _flat = masked_cell_keys(series_idx, bucket, ok, num_series, num_buckets)
+    # a grid too large for exact f32 cell-id recovery takes plain scatters
+    big = num_cells >= _F32_EXACT
+
+    def min_max(lane):
+        if big:
+            return _scatter_min_max(safe, lane, num_cells, valid=ok)
+        return sorted_segment_min_max(safe, lane, num_cells, impl=impl, valid=ok)
+
+    # typed zero fill: a weak 0.0 would promote integer values to float and
+    # bypass the dtype-preserving integer route
+    masked = jnp.where(ok, values, jnp.zeros((), values.dtype))
+    weights = ok.astype(values.dtype)
+    if big:
+        s, c = _scatter_sum_count(safe, masked, num_cells, w=weights)
+    else:
+        s, c = sorted_segment_sum_count(
+            safe, masked, num_cells, impl=impl, weights=weights,
+        )
+    out = {"sum": s, "count": c}
+    if with_minmax and order_key is not None:
+        key_max = jnp.where(order_key == _NAN_LOW, _NAN_HIGH, order_key)
+        out["min"] = min_max(order_key)[0]
+        out["max"] = min_max(key_max)[1]
+    elif with_minmax:
+        out["min"], out["max"] = min_max(values)
+    return out
+
+
+# The pushdown's fold as ONE compiled program a class: its shapes are the
+# padded rows (`fold_classes`) and its static arguments the padded grid,
+# `with_minmax` and the implementation; `t0` and `bucket_ms` are operands,
+# so a window's start never retraces.
+downsample_fold = xjit(
+    _fold_grids, kernel="downsample_fold",
+    static_argnames=("num_series", "num_buckets", "with_minmax", "impl"),
+)
+
+
+def _device_impl(choice: str, dtype) -> str:
+    """The implementation the program runs for the dispatcher's choice. An
+    f32 lane takes the choice. A wider lane (f64, integers) needs one that
+    keeps its dtype: the plain scatter where 64-bit lanes are native, the
+    segmented scan over the sorted runs on an accelerator, which emulates
+    them and serialises a scatter over them."""
+    if dtype == np.float32 or choice == "runs":
+        return choice
+    return "scatter" if device_f64_is_exact() else "runs"
+
+
+def _padded(lane: np.ndarray, rows: int, fill=None) -> np.ndarray:
+    """`lane` at `rows` rows: the tail repeats its last row (no fill given),
+    which keeps a sorted lane sorted."""
+    out = np.empty(rows, lane.dtype)
+    out[:len(lane)] = lane
+    if fill is None:
+        fill = lane[-1] if len(lane) else 0
+    out[len(lane):] = fill
+    return out
+
+
+def fold_sorted(
+    ts,
+    series_idx,
+    values,
+    t0,
+    bucket_ms,
+    num_series: int,
+    num_buckets: int,
+    with_minmax: bool = True,
+    valid=None,
+) -> tuple[dict, FoldRun]:
+    """One fold of the aggregate pushdown: concrete rows SORTED by (series,
+    ts) — the engine's scan order — to host [num_series, num_buckets] grids
+    (sum, count and, with `with_minmax`, min and max), and what ran.
+
+    The calibrated registry dispatcher is asked once. A host lane
+    (np.add.reduceat over run boundaries) computes the whole grid on the
+    host, with no f32-exact ceiling. Otherwise the rows are padded to their
+    class (`fold_classes`, or a larger one the program has already run at:
+    `_RowClasses`) and ONE program (`downsample_fold`) reduces them: padding rows
+    repeat the last row's key with weight 0, padded series and buckets are
+    sliced off here, and the grids come back in one transfer.
+
+    `valid` (optional bool) excludes rows (predicate / set-membership miss)
+    WITHOUT breaking the sorted runs: excluded rows must keep a monotone
+    series_idx (the searchsorted position, not -1).
+
+    f64 values take their min/max over i64 order keys built on the host
+    (`f64_order_keys`), whatever the backend: a selection is a stored sample
+    bit for bit. On an accelerator, values whose sums its f64 cannot carry
+    (`device_sums_hold`) take the host lane, recorded as the dispatcher's
+    choice like any other; an f64 jax array is refused there, since it has
+    already lost bits.
+
+    Stages (storage/scanstats.py): `fold_prep` (choice, order keys,
+    padding), `fold_h2d`, `fold_kernel` (dispatch and wait), `fold_d2h`; the
+    host lane is `fold_host`."""
+    from horaedb_tpu.common import tracing
+    from horaedb_tpu.ops import agg_registry
+    from horaedb_tpu.storage import scanstats
+
+    with scanstats.stage("fold_prep"):
+        f64 = jnp.result_type(values) == jnp.float64
+        ensure(
+            device_f64_is_exact() or not (f64 and isinstance(values, jax.Array)),
+            "an f64 device array has already lost bits on this backend: "
+            "hand the fold the host array",
+        )
+        ts, series_idx, values = np.asarray(ts), np.asarray(series_idx), np.asarray(values)
+        n = len(values)
+        if f64 and not device_sums_hold(values):
+            choice = agg_registry.record_choice("reduceat")
+        else:
+            choice = agg_registry.choose_sorted(
+                n, num_series * num_buckets, concrete=True)
+        host = agg_registry.is_host_impl(choice)
+        if not host:
+            impl = _device_impl(choice, values.dtype)
+            rows, series, buckets = fold_classes(n, num_series, num_buckets)
+            keyed = with_minmax and f64
+            rows = _ROW_CLASSES.pick(
+                (series, buckets, with_minmax, impl, values.dtype.str, keyed), rows)
+            row_ok = np.zeros(rows, bool)  # the padding rows stay excluded
+            row_ok[:n] = True if valid is None else valid
+            lanes = (
+                _padded(ts.astype(np.int64, copy=False), rows),
+                _padded(series_idx.astype(np.int32, copy=False), rows),
+                _padded(values, rows, 0),
+                row_ok,
+                _padded(f64_order_keys(values)[0], rows, 0) if keyed else None,
+            )
+    if host:
+        with scanstats.stage("fold_host"):
+            out = agg_registry.host_downsample_sorted(
+                ts, series_idx, values, t0, bucket_ms,
+                num_series=num_series, num_buckets=num_buckets,
+                with_minmax=with_minmax, valid=valid, impl=choice,
+            )
+            out.pop("mean")
+        run = FoldRun(choice, n, 0, (num_series, num_buckets))
+    else:
+        with scanstats.stage("fold_h2d"):
+            # jaxlint: disable=J001 the stage's fence: each lane names its own seconds
+            operands = jax.block_until_ready(jax.device_put(lanes))
+        with scanstats.stage("fold_kernel"):
+            # jaxlint: disable=J001 dispatch + wait IS this stage; the grids are fetched right after
+            flat = jax.block_until_ready(downsample_fold(
+                *operands, np.int64(t0), np.int64(bucket_ms),
+                num_series=series, num_buckets=buckets,
+                with_minmax=with_minmax, impl=impl,
+            ))
+        with scanstats.stage("fold_d2h"):
+            # jaxlint: disable=J001 the fold's one read-back: every grid in one device_get
+            grids = jax.device_get(flat)
+            out = {
+                k: g.reshape(series, buckets)[:num_series, :num_buckets]
+                for k, g in grids.items()
+            }
+            if keyed:
+                # the winners of the i64 order keys, back to f64 on the host
+                out["min"] = f64_from_order_keys(out["min"])
+                out["max"] = f64_from_order_keys(out["max"])
+        run = FoldRun(impl, n, rows, (series, buckets))
+    padding = max(0, run.rows_class - n)
+    FOLDS_TOTAL.labels(run.impl).inc()
+    FOLD_ROWS_TOTAL.labels("real").inc(n)
+    FOLD_ROWS_TOTAL.labels("padded").inc(padding)
+    scanstats.note("folds")
+    scanstats.note("fold_rows_real", n)
+    scanstats.note("fold_rows_padded", padding)
+    scanstats.note(
+        f"fold_class_{run.rows_class}x{run.grid_class[0]}x{run.grid_class[1]}")
+    tracing.add_attr(
+        agg_impl=run.impl, fold_rows_class=run.rows_class,
+        fold_grid_class=f"{run.grid_class[0]}x{run.grid_class[1]}",
+    )
+    return out, run
+
+
 def downsample_sorted(
     ts,
     series_idx,
@@ -224,116 +518,29 @@ def downsample_sorted(
 ) -> dict:
     """Downsample over rows SORTED by (series, ts) — the engine's natural
     scan-output order (pk = ids + timestamp), which makes the flat cell index
-    monotone. sum/count dispatch to the sorted-segment compaction
-    (ops/blockagg.py; MXU one-hot matmuls instead of a scatter, with
-    an automatic XLA fallback); min/max, when requested, use the
-    masked-reduce compaction (sorted_segment_min_max, scatter fallback).
+    monotone — to [num_series, num_buckets] grids: sum, count, mean and,
+    with `with_minmax`, min and max.
 
-    `valid` (optional bool) excludes rows (predicate / set-membership miss)
-    WITHOUT breaking the sorted runs: excluded rows must keep a monotone
-    series_idx (e.g. the searchsorted position, not -1) and are zeroed via
-    the compaction's weight column.
-
-    Concrete (non-traced) inputs consult the calibrated registry
-    dispatcher first: when the measured winner is a host lane
-    (np.add.reduceat over run boundaries), the WHOLE grid computes on host
-    — no device dispatch at all, and no f32-exact grid-size ceiling (host
-    keys are i64).
-
-    Concrete f64 values take their min/max over i64 order keys built on the
-    host (`f64_order_keys`), whatever the backend. On an accelerator, values
-    whose sums its f64 cannot carry (`device_sums_hold`) take the host
-    reduceat lane, recorded as the dispatcher's choice like any other; an
-    f64 jax array is refused there, since it has already lost bits.
-    """
-    from horaedb_tpu.ops import agg_registry
-    from horaedb_tpu.ops.blockagg import (
-        _F32_EXACT,
-        _scatter_min_max,
-        _scatter_sum_count,
-        sorted_segment_min_max,
-        sorted_segment_sum_count,
-    )
-
-    num_cells = num_series * num_buckets
-    traced = any(
-        isinstance(x, jax.core.Tracer)
-        for x in (ts, series_idx, values, valid)
-    )
-    # resolve the dispatcher ONCE and thread the choice through both
-    # reductions below — re-resolving per reduction would triple-count
-    # horaedb_agg_impl_total and re-read env/cache on the scan hot path
-    choice: str | None = None
-    order_keys = None
-    if not traced:
-        f64 = jnp.result_type(values) == jnp.float64
-        ensure(
-            device_f64_is_exact() or not (f64 and isinstance(values, jax.Array)),
-            "an f64 device array has already lost bits on this backend: "
-            "hand downsample_sorted the host array",
+    Two entries, one body (`_fold_grids`). Concrete inputs are one fold of
+    the pushdown (`fold_sorted`: the registry's choice, the padded program
+    or the host lane, host grids back). Traced inputs (inside a caller's own
+    jit or shard_map) run the body in that program, min/max over `values`
+    themselves."""
+    if any(isinstance(x, jax.core.Tracer) for x in (ts, series_idx, values, valid)):
+        flat = _fold_grids(
+            jnp.asarray(ts), jnp.asarray(series_idx), jnp.asarray(values),
+            None if valid is None else jnp.asarray(valid), None, t0, bucket_ms,
+            num_series, num_buckets, with_minmax, None,
         )
-        if f64 and not device_sums_hold(np.asarray(values)):
-            choice = agg_registry.record_choice("reduceat")
-        else:
-            choice = agg_registry.choose_sorted(
-                jnp.shape(values)[0], num_cells, concrete=True
-            )
-        if agg_registry.is_host_impl(choice):
-            return agg_registry.host_downsample_sorted(
-                ts, series_idx, values, t0, bucket_ms,
-                num_series=num_series, num_buckets=num_buckets,
-                with_minmax=with_minmax, valid=valid, impl=choice,
-            )
-        if with_minmax and f64:
-            order_keys = f64_order_keys(np.asarray(values))
-    ts = jnp.asarray(ts)
-    series_idx = jnp.asarray(series_idx)
-    values = jnp.asarray(values)
-    bucket = ((ts - t0) // bucket_ms).astype(jnp.int32)
-    ok = (
-        (bucket >= 0) & (bucket < num_buckets)
-        & (series_idx >= 0) & (series_idx < num_series)
+        out = {k: g.reshape(num_series, num_buckets) for k, g in flat.items()}
+        out["mean"] = out["sum"] / out["count"]
+        return out
+    out, _run = fold_sorted(
+        ts, series_idx, values, t0, bucket_ms, num_series, num_buckets,
+        with_minmax=with_minmax, valid=valid,
     )
-    if valid is not None:
-        ok = ok & jnp.asarray(valid)
-    safe, _flat = masked_cell_keys(series_idx, bucket, ok, num_series, num_buckets)
-    # a grid too large for exact f32 cell-id recovery takes plain scatters
-    big = num_cells >= _F32_EXACT
-
-    def min_max(lane):
-        if big:
-            return _scatter_min_max(safe, lane, num_cells, valid=ok)
-        return sorted_segment_min_max(safe, lane, num_cells, impl=choice, valid=ok)
-
-    # typed zero fill: a weak 0.0 would promote integer values to float and
-    # bypass the dtype-preserving integer scatter route
-    masked = jnp.where(ok, values, jnp.zeros((), values.dtype))
-    weights = ok.astype(values.dtype)
-    if big:
-        s, c = _scatter_sum_count(safe, masked, num_cells, w=weights)
-    else:
-        s, c = sorted_segment_sum_count(
-            safe, masked, num_cells, impl=choice, weights=weights,
-        )
-    shape = (num_series, num_buckets)
-    out = {
-        "sum": s.reshape(shape),
-        "count": c.reshape(shape),
-        "mean": (s / c).reshape(shape),
-    }
-    if with_minmax and order_keys is not None:
-        # a selection returns a stored sample bit for bit: reduce the i64
-        # order keys and map the winners back to f64 on the host
-        kmin, kmax = order_keys
-        mn, mx = min_max(kmin)
-        if kmax is not kmin:  # the block holds a NaN: its own max lane
-            mx = min_max(kmax)[1]
-        mn, mx = f64_from_order_keys(mn), f64_from_order_keys(mx)
-    elif with_minmax:
-        mn, mx = min_max(values)
-    if with_minmax:
-        out["min"] = mn.reshape(shape)
-        out["max"] = mx.reshape(shape)
+    with np.errstate(invalid="ignore", divide="ignore"):
+        out["mean"] = out["sum"] / out["count"]
     return out
 
 

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from horaedb_tpu.common.error import ensure
 from horaedb_tpu.common.xprof import xjit
@@ -275,6 +276,91 @@ def _block_min_max_xla(k_sorted, v, num_cells, block, ranks, valid=None):
     return g_mn, g_mx
 
 
+def _acc_lane(v):
+    """A value lane in its accumulation dtype: floats keep their width (the
+    engine's precision contract, data.py); integers widen to 64 bits, exact
+    and wrap-proof for narrow sums; bool included."""
+    if jnp.issubdtype(v.dtype, jnp.floating):
+        return v
+    if jnp.issubdtype(v.dtype, jnp.unsignedinteger):
+        return v.astype(jnp.uint64)
+    return v.astype(jnp.int64)
+
+
+def _lane_limits(dtype):
+    """(fill of an empty min cell, fill of an empty max cell) of a lane, as
+    jax.ops.segment_min/segment_max fill them."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.inf, -jnp.inf
+    info = jnp.iinfo(dtype)
+    return info.max, info.min
+
+
+def _runs_reduce(k_sorted, num_cells, lanes):
+    """Per-cell reductions of MONOTONE cell ids without a scatter: a
+    segmented inclusive scan over the sorted runs (Hillis-Steele: log2(n)
+    shifted elementwise passes, any dtype), then each cell reads the scan at
+    its run's last row, found by a binary search over the ids. `lanes` is a
+    list of (values, op, identity); the result is one [num_cells] array a
+    lane, `identity` where a cell has no row. Ids outside [0, num_cells)
+    are dropped. The order of a cell's additions is a tree's, not the
+    rows': sums agree with a sequential scatter to rounding, selections bit
+    for bit.
+
+    Why it exists: an accelerator that emulates 64-bit lanes serialises a
+    scatter over them (0.154 s for 512 K rows of f64 sums and i64 order
+    keys on a TPU v5e, against 0.0027 s for this, PERF.md section 6)."""
+    n = k_sorted.shape[0]
+    k = k_sorted.astype(jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+    vals = [v for v, _op, _ident in lanes]
+    d = 1
+    while d < n:
+        shifted_first = jnp.concatenate([jnp.ones((d,), bool), first[:-d]])
+        vals = [
+            jnp.where(first, v, op(
+                jnp.concatenate([jnp.full((d,), ident, v.dtype), v[:-d]]), v))
+            for v, (_v, op, ident) in zip(vals, lanes)
+        ]
+        first = first | shifted_first
+        d *= 2
+    cells = jnp.arange(num_cells, dtype=jnp.int32)
+    last = jnp.clip(jnp.searchsorted(k, cells, side="right") - 1, 0, n - 1)
+    hit = k[last] == cells
+    return [
+        jnp.where(hit, v[last], jnp.asarray(ident, v.dtype))
+        for v, (_v, _op, ident) in zip(vals, lanes)
+    ]
+
+
+@xjit(kernel="runs_sum_count", static_argnames=("num_cells",))
+def _runs_sum_count(k_sorted, v, num_cells, w=None):
+    vf = _acc_lane(v)
+    cw = jnp.ones_like(vf) if w is None else w.astype(vf.dtype)
+    s, c = _runs_reduce(k_sorted, num_cells, [(vf, jnp.add, 0), (cw, jnp.add, 0)])
+    return s, c
+
+
+@xjit(kernel="runs_min_max", static_argnames=("num_cells",))
+def _runs_min_max(k_sorted, v, num_cells, valid=None):
+    hi, lo = _lane_limits(v.dtype)
+    v_lo = v if valid is None else jnp.where(valid, v, jnp.asarray(hi, v.dtype))
+    v_hi = v if valid is None else jnp.where(valid, v, jnp.asarray(lo, v.dtype))
+    mn, mx = _runs_reduce(
+        k_sorted, num_cells, [(v_lo, jnp.minimum, hi), (v_hi, jnp.maximum, lo)])
+    return mn, mx
+
+
+def _runs_or_scatter(runs_fn, scatter_fn, k_sorted, *operands):
+    """`runs` needs monotone ids (a cell is ONE run); a stream that is not
+    (off the sorted contract) takes the scatter, which needs nothing."""
+    if isinstance(k_sorted, jax.core.Tracer):
+        return jax.lax.cond(jnp.all(k_sorted[1:] >= k_sorted[:-1]),
+                            runs_fn, scatter_fn, k_sorted, *operands)
+    monotone = bool(np.all(np.diff(np.asarray(k_sorted)) >= 0))
+    return (runs_fn if monotone else scatter_fn)(k_sorted, *operands)
+
+
 def _scatter_min_max(k, v, num_cells, valid=None):
     idx = jnp.clip(k, 0, num_cells).astype(jnp.int32)
     if valid is not None:
@@ -298,6 +384,7 @@ def sorted_segment_min_max(
     matmul) with a scatter fallback when any block exceeds the rank budget.
     `impl` takes the registry vocabulary: 'scatter'/'scatter_fused'/'lanes'
     map to the plain scatter (no fused/lane min-max variant exists),
+    'runs' is the segmented scan over the sorted runs (any dtype),
     'reduceat' is the host run-boundary lane (concrete inputs only), and
     every block_* name uses the masked-reduce compaction at its block/rank
     config (bf16/scan flags are sum-count-only and are ignored here). Rows
@@ -306,8 +393,8 @@ def sorted_segment_min_max(
     every impl's final scatter/clip) provided sentinel runs stay contiguous
     in the stream. +/-inf fills mark empty cells.
 
-    Non-f32 floats always take the dtype-preserving scatter or host
-    reduceat: the block path computes in f32, and a lax.cond joining
+    Non-f32 lanes always take a dtype-preserving implementation (scatter,
+    runs, host reduceat): the block path computes in f32, and a lax.cond joining
     f32/f64 branches would be a trace-time type error anyway."""
     ensure(num_cells < _F32_EXACT, f"num_cells {num_cells} exceeds f32-exact range")
     traced = (
@@ -322,7 +409,7 @@ def sorted_segment_min_max(
         impl = agg_registry.choose_sorted(
             k_sorted.shape[0], num_cells, concrete=not traced
         )
-    if jnp.asarray(v).dtype != jnp.float32 and impl != "reduceat":
+    if jnp.asarray(v).dtype != jnp.float32 and impl not in _ANY_DTYPE_IMPLS:
         impl = "scatter"
     if impl == "reduceat":
         ensure(not traced,
@@ -333,6 +420,11 @@ def sorted_segment_min_max(
         return agg_registry.host_reduceat_min_max(
             k_sorted, v, num_cells, valid=valid
         )
+    if impl == "runs":
+        return _runs_or_scatter(
+            lambda k, vv, ok: _runs_min_max(k, vv, num_cells, valid=ok),
+            lambda k, vv, ok: _scatter_min_max(k, vv, num_cells, valid=ok),
+            k_sorted, v, valid)
     if impl in ("scatter", "scatter_fused", "lanes"):
         return _scatter_min_max(k_sorted, v, num_cells, valid=valid)
     if impl != "block":
@@ -366,12 +458,7 @@ def _scatter_sum_count(k_sorted, v, num_cells, w=None):
     # contract, data.py; f32 stays the TPU trade-off). Integer inputs widen
     # to 64-bit accumulation: exact (the reason ints route here instead of
     # the f32 block compaction) and wrap-proof for narrow int sums.
-    if jnp.issubdtype(v.dtype, jnp.floating):
-        vf = v
-    elif jnp.issubdtype(v.dtype, jnp.unsignedinteger):
-        vf = v.astype(jnp.uint64)
-    else:
-        vf = v.astype(jnp.int64)  # bool included
+    vf = _acc_lane(v)
     cw = jnp.ones_like(vf) if w is None else w.astype(vf.dtype)
     s = jax.ops.segment_sum(vf, k, num_cells + 1)[:-1]
     c = jax.ops.segment_sum(cw, k, num_cells + 1)[:-1]
@@ -409,8 +496,12 @@ _BLOCK_VARIANTS = {
     "block_scan": (DEFAULT_BLOCK, DEFAULT_RANKS, False, True),
 }
 
+# the implementations that keep the value lane's own dtype (f64, integers);
+# every other one accumulates f32
+_ANY_DTYPE_IMPLS = ("scatter", "runs", "reduceat")
+
 _SORTED_IMPL_NAMES = (
-    "auto", "scatter", "scatter_fused", "lanes", "reduceat",
+    "auto", "scatter", "scatter_fused", "lanes", "reduceat", "runs",
     *_BLOCK_VARIANTS,
 )
 
@@ -525,6 +616,7 @@ def sorted_segment_sum_count(
 
     `impl` overrides the strategy explicitly (A/B harnesses) with any
     registry name (ops/agg_registry.py): scatter | scatter_fused | lanes |
+    runs (segmented scan over the sorted runs, any dtype, no scatter) |
     reduceat (host, concrete inputs only) | block | block_wide | block_r32
     | block_bf16 | block_scan. None reads HORAEDB_SORTED_IMPL at trace
     time; 'auto' asks the calibrated registry dispatcher — note that
@@ -545,11 +637,11 @@ def sorted_segment_sum_count(
         impl = agg_registry.choose_sorted(
             k_sorted.shape[0], num_cells, concrete=not traced
         )
-    if jnp.asarray(v).dtype != jnp.float32 and impl != "reduceat":
+    if jnp.asarray(v).dtype != jnp.float32 and impl not in _ANY_DTYPE_IMPLS:
         # non-f32 inputs take a dtype-preserving route: the compactions
         # accumulate f32, which loses exactness for integer sums above
-        # 2^24 (scatter and the host reduceat widen ints to 64-bit instead
-        # — exact), and a cond joining f32/f64 branches cannot trace
+        # 2^24 (scatter, runs and the host reduceat widen ints to 64-bit
+        # instead — exact), and a cond joining f32/f64 branches cannot trace
         impl = "scatter"
     if impl == "reduceat":
         ensure(not traced,
@@ -562,6 +654,11 @@ def sorted_segment_sum_count(
         )
     if impl == "scatter":
         return _scatter_sum_count(k_sorted, v, num_cells, w=weights)
+    if impl == "runs":
+        return _runs_or_scatter(
+            lambda k, vv, ww: _runs_sum_count(k, vv, num_cells, w=ww),
+            lambda k, vv, ww: _scatter_sum_count(k, vv, num_cells, w=ww),
+            k_sorted, v, weights)
     if impl == "scatter_fused":
         return _scatter_fused_sum_count(k_sorted, v, num_cells, w=weights)
     if impl == "lanes":

@@ -75,12 +75,16 @@ def _sort_perm(keys: tuple[jax.Array, ...]) -> jax.Array:
     return lexsort_perm(keys)
 
 
-def sort_permutation(keys: list) -> jax.Array:
-    """Stable permutation ordering rows by `keys` (most-significant first).
+def sort_permutation(keys: list) -> np.ndarray:
+    """Stable permutation ordering rows by `keys` (most-significant first),
+    on the host.
 
     Rows pad to their power-of-two class with all-ones keys: pads tie with
     nothing smaller, start behind every real row and every pass is stable,
-    so they stay at the tail and the first n entries are the answer."""
+    so they stay at the tail and the first n entries are the answer. They
+    are cut off on the host: a slice of the device array is an eager
+    `dynamic_slice` that compiles for every new n (a self-telemetry write's
+    series count: five compiles a window, PR 29)."""
     n = int(keys[0].shape[0])
     padded = pow2_rows(n)
     lanes = []
@@ -92,7 +96,7 @@ def sort_permutation(keys: list) -> jax.Array:
                 [lane, xp.full(padded - n, np.iinfo(np.uint64).max, dtype=xp.uint64)]
             )
         lanes.append(lane)
-    return _sort_perm(tuple(lanes))[:n]
+    return np.asarray(_sort_perm(tuple(lanes)))[:n]
 
 
 def apply_permutation(columns: dict[str, jax.Array], perm: jax.Array) -> dict[str, jax.Array]:

@@ -40,7 +40,6 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from horaedb_tpu.common import tracing
 from horaedb_tpu.engine.engine import QueryRequest
 from horaedb_tpu.storage import scanstats
 from horaedb_tpu.promql import (
@@ -420,13 +419,9 @@ class RangeEvaluator:
         t0 = self.start - self.step - o
         req = _to_query(sel, t0, int(self.steps[-1]) - o, bucket_ms=self.step)
         scanstats.note("promql_pushdowns")
+        # the fold itself puts its implementation and classes on this span
+        # (ops/aggregate.py fold_sorted: agg_impl, fold_rows_class, ...)
         res = await self._engine.query(req)
-        # span attribution: which aggregation kernel the calibrated
-        # registry dispatcher served this pushdown with (visible on
-        # /debug/traces next to the scan stage timings)
-        from horaedb_tpu.ops import agg_registry
-
-        tracing.add_attr(agg_impl=agg_registry.last_choice())
         if res is None:
             return []
         tsids, grids = res

@@ -1157,6 +1157,17 @@ def _explain_payload(st, mode: str, admission_verdict: dict | None = None) -> di
         "shape_class": batch_classes[0] if batch_classes else None,
         "window_wait_s": round(st.seconds.get("batch_window", 0.0), 6),
     }
+    # the pushdown's folds (ops/aggregate.py fold_sorted): how many, the
+    # classes their programs ran as ("<rows>x<series>x<buckets>", rows 0 =
+    # the host lane) and the rows they took in against the rows of padding
+    fold_verdict = {
+        "folds": counts.get("folds", 0),
+        "classes": sorted(
+            k[len("fold_class_"):] for k in counts if k.startswith("fold_class_")
+        ),
+        "rows_real": counts.get("fold_rows_real", 0),
+        "rows_padded": counts.get("fold_rows_padded", 0),
+    }
     compile_s = st.seconds.get("compile", 0.0)
     total_s = sum(att["lanes_s"].values())
     kernels = []
@@ -1198,6 +1209,7 @@ def _explain_payload(st, mode: str, admission_verdict: dict | None = None) -> di
         "encoding": encoding,
         "serving": serving_verdict,
         "batching": batching_verdict,
+        "fold": fold_verdict,
         # memory provenance (common/memtrace.py): the buffer-lineage
         # verdict — bytes allocated/copied per stage, copies vs views,
         # device staging bytes, peak-delta + top sites under deep mode.

@@ -2296,19 +2296,30 @@ class ParquetReader:
         packed_ok: bool = False,
     ) -> dict:
         """Aggregate pushdown: scan one segment and reduce it to dense
-        [num_series, num_buckets] grids ON DEVICE — raw rows never cross back
-        to host (SURVEY's #1 offload target: scan->filter->aggregate fused).
+        [num_series, num_buckets] grids, so that no raw row leaves the
+        reader (SURVEY's #1 offload target: scan->filter->aggregate).
 
         `series_ids` is a SORTED array of series keys; dense output row i
         corresponds to series_ids[i], rows with other keys are dropped.
-        Dedup semantics are preserved: the fused kernel sorts and
-        last-value-dedups before the reduction, exactly like the
-        materializing path. Correct whenever duplicates cannot span segments
+        Dedup semantics are those of the materializing path (filter first,
+        last value wins). Correct whenever duplicates cannot span segments
         (true for any schema whose primary key includes the timestamp, e.g.
         the metric-engine data table).
 
-        Segments above `scan_block_rows` route through the hierarchical scan
-        and aggregate its sorted output run — device memory stays bounded.
+        The routes, by what the caller and the backend allow:
+        - `packed_ok` (the metric engine): the HOST packs (sid, ts, seq-rank)
+          into one key, sorts and dedups (`_packed_downsample_pass`), and
+          the surviving rows are ONE fold (`ops/aggregate.py fold_sorted`:
+          the padded `downsample_fold` program, or the host reduceat lane);
+        - otherwise the fused device pass sorts and dedups; on a backend
+          whose f64 is exact (the CPU) the same program reduces the rows
+          where they lie, and on one whose f64 is not (an accelerator) or
+          under a mesh the sorted rows come back to the host once and take
+          the fold, its selections on i64 order keys and its sums in the
+          device's f64 where `device_sums_hold`, else on the host;
+        - segments above `scan_block_rows` route through the hierarchical
+          scan and fold each of its sorted batches: device memory stays
+          bounded.
 
         Returns host numpy grids: sum and count, plus min/max when
         `with_minmax` (no mean — callers derive it after combining partials).
@@ -2357,18 +2368,14 @@ class ParquetReader:
                         num_series, num_buckets, with_minmax, valid_np=valid_np,
                     )
             else:
-                with scanstats.stage("device_agg"):
-                    out = agg_ops.downsample_sorted(
-                        ts_np, sid_np, val_np, t0, bucket_ms,
-                        num_series=num_series, num_buckets=num_buckets,
-                        with_minmax=with_minmax, valid=valid_np,
-                    )
-                # lane attribution: which registry impl the calibrated
-                # dispatcher ran this fold on (host reduceat vs a device
-                # kernel decides whether device_agg even touched a device)
-                from horaedb_tpu.ops import agg_registry
-
-                scanstats.note("agg_impl_" + agg_registry.last_choice())
+                out, run = agg_ops.fold_sorted(
+                    ts_np, sid_np, val_np, t0, bucket_ms,
+                    num_series=num_series, num_buckets=num_buckets,
+                    with_minmax=with_minmax, valid=valid_np,
+                )
+                # lane attribution: the implementation this fold ran (the
+                # host reduceat or a device program), from the fold itself
+                scanstats.note("agg_impl_" + run.impl)
             grids["sum"] += np.asarray(out["sum"])
             grids["count"] += np.asarray(out["count"])
             if with_minmax:

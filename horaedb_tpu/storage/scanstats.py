@@ -90,8 +90,16 @@ STAGE_SECONDS = GLOBAL_METRICS.histogram(
 # encoded-lane expansion stage (storage/encoding.py + ops/decode.py) —
 # first-class because the compressed-domain scan's whole bet is moving
 # wall time from io_decode/transfer into this (much smaller) lane.
+# The lanes of one fold of the aggregate pushdown (ops/aggregate.py
+# `fold_sorted`), each under its own label: `fold_prep` (host: the
+# dispatcher's choice, order keys, padding to the row class), `fold_h2d`,
+# `fold_kernel` (dispatch of `downsample_fold` and the wait for it),
+# `fold_d2h` (the grids back, sliced and decoded); `fold_host` is the whole
+# fold where the host lane (reduceat) serves it.
+FOLD_STAGES = ("fold_prep", "fold_h2d", "fold_kernel", "fold_d2h", "fold_host")
+
 for _lane in ("io_decode", "host_prep", "transfer", "kernel", "compile",
-              "decode", MERGE_WAIT):
+              "decode", MERGE_WAIT, *FOLD_STAGES):
     STAGE_SECONDS.labels(_lane)
 del _lane
 
@@ -128,6 +136,9 @@ _BOUND_LANE = {
     "transfer": "transfer",
     "device_merge": "kernel",
     "device_agg": "kernel",
+    "fold_h2d": "transfer",
+    "fold_d2h": "transfer",
+    "fold_kernel": "kernel",
     "kernel": "kernel",
     "compile": "compile",
     "decode": "decode",

@@ -619,7 +619,7 @@ class ObjectBasedStorage(ColumnarStorage):
             # off on real accelerators
             perm = np.lexsort(tuple(reversed(keys)))
         else:
-            perm = np.asarray(sort_ops.sort_permutation(keys))
+            perm = sort_ops.sort_permutation(keys)
         return batch.take(pa.array(perm))
 
     def _writer_kwargs(self, fast: bool = False) -> dict:

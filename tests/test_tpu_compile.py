@@ -186,6 +186,31 @@ def test_downsample_sorted_f64(spec):
     )
 
 
+@pytest.mark.parametrize("rows,series,buckets", [
+    (512, 1, 64),          # TSBS single-groupby-1-1-1: one host's hour, any cut of it
+    # TSBS double-groupby-1 (the cell tsbs100.double-groupby-1): 100 hosts x 12 h cut in two by a
+    # segment's edge, the larger part 216,000 to 432,000 rows; the smaller part rides its class
+    (1 << 18, 128, 16),
+    (1 << 19, 128, 16),
+    (1 << 20, 1024, 32),   # chip_smoke's all-hosts 5-minute panel
+])
+@pytest.mark.parametrize("impl", ["runs", "scatter"])
+def test_downsample_fold(spec, rows, series, buckets, impl):
+    """ops/aggregate.py `downsample_fold`: the pushdown's fold as ONE program a
+    class, at the server's dtypes (f64 values, their i64 order keys);
+    `runs` is what an accelerator's 64-bit lanes take, `scatter` its branch
+    for a stream that is not monotone."""
+    rows_class, series_class, buckets_class = agg_ops.fold_classes(rows, series, buckets)
+    assert (rows_class, series_class, buckets_class) == (rows, series, buckets)
+    compile_for_chip(
+        agg_ops.downsample_fold,
+        spec(rows, jnp.int64), spec(rows, jnp.int32), spec(rows, jnp.float64),
+        spec(rows, jnp.bool_), spec(rows, jnp.int64),
+        spec((), jnp.int64), spec((), jnp.int64),
+        num_series=series, num_buckets=buckets, with_minmax=True, impl=impl,
+    )
+
+
 def test_min_max_over_order_keys(spec):
     """The pushdown's selections: min/max of the i64 order keys the host
     builds from the f64 values (ops/aggregate.py f64_order_keys)."""
