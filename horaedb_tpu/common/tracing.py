@@ -173,6 +173,7 @@ _ACTIVE: ContextVar[tuple[Trace, Span] | None] = ContextVar(
 )
 
 _ring_lock = threading.Lock()
+_stage_lock = threading.Lock()
 _ring: "OrderedDict[str, Trace]" = OrderedDict()
 
 
@@ -298,8 +299,11 @@ def add_stage(stage: str, seconds: float) -> None:
     cur = _ACTIVE.get()
     if cur is None:
         return
-    stages = cur[1].attrs.setdefault("stages", {})
-    stages[stage] = round(stages.get(stage, 0.0) + seconds, 6)
+    # a query's segments fold on worker threads, several at once, under
+    # the one span their coroutines share
+    with _stage_lock:
+        stages = cur[1].attrs.setdefault("stages", {})
+        stages[stage] = round(stages.get(stage, 0.0) + seconds, 6)
 
 
 def recent(limit: int = 50, min_ms: float | None = None) -> list[dict]:

@@ -265,7 +265,11 @@ class _RowClasses:
             for rows in have:
                 if natural <= rows <= _MAX_RIDE_ROWS:
                     return rows
-            bisect.insort(have, natural)
+            at = bisect.bisect_left(have, natural)
+            if at == len(have) or have[at] != natural:
+                # once: a class past the ride limit is met again by every
+                # fold of its size
+                have.insert(at, natural)
             return natural
 
 

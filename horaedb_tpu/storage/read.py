@@ -37,6 +37,8 @@ from collections import OrderedDict
 from contextvars import ContextVar
 from dataclasses import dataclass
 from functools import lru_cache, wraps
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1056,6 +1058,36 @@ def _rows_presorted(arrays: dict, sort_keys: tuple) -> bool:
 # ---------------------------------------------------------------------------
 
 
+class _FoldSpec(NamedTuple):
+    """What an aggregate pushdown folds a segment's rows into: dense
+    [len(series_ids), num_buckets] grids, row i for `series_ids[i]` (a
+    SORTED array of series keys), bucket k for [t0 + k * bucket_ms, ...)."""
+
+    series_ids: np.ndarray
+    t0: int
+    bucket_ms: int
+    num_buckets: int
+    with_minmax: bool
+
+    def empty_grids(self) -> dict:
+        shape = (len(self.series_ids), self.num_buckets)
+        grids = {"sum": np.zeros(shape), "count": np.zeros(shape)}
+        if self.with_minmax:
+            grids["min"] = np.full(shape, np.inf)
+            grids["max"] = np.full(shape, -np.inf)
+        return grids
+
+    def dense_sid(self, col: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """(dense position, hit mask). Misses keep their MONOTONE
+        searchsorted position (not -1): the sorted-segment compaction
+        needs monotone keys, and misses are excluded via the reduction's
+        weight column instead of a key sentinel."""
+        pos = np.searchsorted(self.series_ids, col)
+        pos_c = np.clip(pos, 0, max(0, len(self.series_ids) - 1))
+        hit = self.series_ids[pos_c] == col
+        return pos_c.astype(np.int32), hit
+
+
 class ParquetReader:
     """Per-SST parquet access + the per-segment device pipeline
     (reference: read.rs ParquetReader/build_df_plan)."""
@@ -1148,24 +1180,32 @@ class ParquetReader:
     def _tombstoned(self, sst_id: int) -> bool:
         return sst_id in self._evicted_ids
 
+    def _footer_known(self, sst_id: int) -> bool:
+        with self._blk_lock:
+            return sst_id in self._meta_cache
+
     def _assemble_cached(self, sst_id: int, get, predicate):
         """Serve a read purely from cache when the footer is known and every
-        pruned row group is resident; None = fall through to IO."""
+        pruned row group is resident: (table, kept row groups). No table =
+        fall through to IO, with the row groups the footer walk kept where
+        it ran (`_read_pruned` does not walk it again). The walk is Python
+        over every row group of the footer: for a worker thread, never
+        the loop's."""
         with self._blk_lock:
             entry = self._meta_cache.get(sst_id)
         if entry is None:
-            return None
+            return None, None
         meta, arrow_schema = entry
         keep = _select_row_groups(meta, arrow_schema, predicate)
         if not keep:
-            return arrow_schema.empty_table()
+            return arrow_schema.empty_table(), keep
         parts = []
         for rg in keep:
             t = get(rg)
             if t is None:
-                return None
+                return None, keep
             parts.append(t)
-        return memtrace.tracked_concat_tables(parts, "host_prep")
+        return memtrace.tracked_concat_tables(parts, "host_prep"), keep
 
     def _rg_cache_hooks(self, sst_id: int, cols_key: tuple):
         """(get, put) closures for _read_pruned, or None when disabled.
@@ -1318,10 +1358,13 @@ class ParquetReader:
     ):
         """Everything of `read_sst` that may await, up to the parquet
         decode: the table itself where none is needed (pruned by its bloom
-        sidecar, served from encoded lanes or from the block cache), else
-        the decode as a zero-argument synchronous call for a worker thread,
-        which returns the table as `read_sst` does (counted, masked, a
-        vanished file raised as NotFound)."""
+        sidecar or served from encoded lanes), else the read as a
+        zero-argument synchronous call for a worker thread, which returns
+        the table as `read_sst` does (counted, masked, a vanished file
+        raised as NotFound). The call begins with the block cache's probe:
+        the footer walk behind it (`_select_row_groups`) is Python over
+        every row group, so it runs once an SST a read and never here, on
+        the loop's thread."""
         # cooperative deadline per SST read: an expired query stops
         # paying IO + decode here, SST by SST (common/deadline.py)
         deadline_ctx.check("sst_read")
@@ -1373,14 +1416,22 @@ class ParquetReader:
         scanstats.note("ssts_read")
         cols_key = tuple(sorted(columns)) if columns is not None else ("*",)
         rg_cache = self._rg_cache_hooks(sst.id, cols_key) if use_block_cache else None
-        if rg_cache is not None:
-            cached = self._assemble_cached(sst.id, rg_cache[0], predicate)
-            if cached is not None:
-                # block-cache-served reads charge the same materialized
-                # bytes as cold reads: usage metering must not depend on
-                # which cache layer answered an identical query
-                scanstats.note("bytes_scanned", int(cached.nbytes))
-                return self._mask_visibility(sst, cached)
+        local = self._store.local_path(path)
+        # the row groups the footer's statistics keep, once the walk has
+        # run: it runs once an SST a read, and on a worker
+        keep = data = None
+        if local is None:
+            # a store with no local files hands the object over as bytes,
+            # worth a GET only where the block cache cannot serve: there
+            # the probe takes a hop of its own, before the GET
+            if rg_cache is not None and self._footer_known(sst.id):
+                cached, keep = await asyncio.to_thread(
+                    scanstats.SCAN.on_worker, "io_decode",
+                    self._assemble_cached, sst.id, rg_cache[0], predicate)
+                if cached is not None:
+                    scanstats.note("bytes_scanned", int(cached.nbytes))
+                    return self._mask_visibility(sst, cached)
+            data = await self._store.get(path)
 
         def meta_sink(meta, arrow_schema) -> None:
             with self._blk_lock:
@@ -1393,15 +1444,19 @@ class ParquetReader:
                 with old_lock:  # wait out any in-flight read
                     old.close()
 
-        local = self._store.local_path(path)
-        # a store with no local files hands the object over as bytes
-        data = await self._store.get(path) if local is None else None
+        def pruned(pf: pq.ParquetFile, kept) -> pa.Table:
+            return _read_pruned(pf, columns, predicate, rg_cache,
+                                meta_sink if rg_cache else None, kept)
 
         def _read() -> pa.Table:
             if data is not None:
-                return _read_pruned(
-                    pq.ParquetFile(io.BytesIO(data)), columns, predicate,
-                    rg_cache, meta_sink if rg_cache else None)
+                return pruned(pq.ParquetFile(io.BytesIO(data)), keep)
+            kept = None
+            if rg_cache is not None:
+                cached, kept = self._assemble_cached(
+                    sst.id, rg_cache[0], predicate)
+                if cached is not None:
+                    return cached
             with self._pf_cache_lock:
                 entry = self._pf_cache.get(path)
                 if entry is not None:
@@ -1410,8 +1465,7 @@ class ParquetReader:
                 pf, handle_lock = entry
                 if handle_lock.acquire(blocking=False):
                     try:
-                        return _read_pruned(pf, columns, predicate, rg_cache,
-                                            meta_sink if rg_cache else None)
+                        return pruned(pf, kept)
                     finally:
                         handle_lock.release()
                 # handle busy with a concurrent read: open transient
@@ -1428,8 +1482,7 @@ class ParquetReader:
                         if len(self._pf_cache) > self._pf_cache_cap:
                             _, evicted = self._pf_cache.popitem(last=False)
             try:
-                return _read_pruned(pf, columns, predicate, rg_cache,
-                                            meta_sink if rg_cache else None)
+                return pruned(pf, kept)
             finally:
                 my_lock.release()
                 if not inserted:
@@ -1447,6 +1500,9 @@ class ParquetReader:
                 # compaction deleted the file after the caller's manifest
                 # snapshot; normalized so scan layers can refresh + retry
                 raise NotFound(f"sst object vanished: {path}") from e
+            # block-cache-served reads charge the same materialized bytes
+            # as cold reads: usage metering must not depend on which cache
+            # layer answered an identical query
             scanstats.note("bytes_scanned", int(table.nbytes))
             return self._mask_visibility(sst, table)
 
@@ -1719,13 +1775,16 @@ class ParquetReader:
         """The fused device pipeline for one time segment.
 
         The event loop's part is to start things and to take what they
-        give: `io_decode` awaits one parquet decode an SST (each on a
-        thread of the default pool), then `merge_wait` awaits
-        `_merge_segment`, ONE call on a thread of the same pool
-        (`asyncio_<n>`) that runs every stage from `host_prep` to
-        `materialize`, the wait on the device among them. Always, whatever
-        the size and the route: a compaction's merge and a query's are
-        the same call.
+        give: `io_decode` awaits the reads of the segment's SSTs
+        (`_decode_segment`: hops of a batch of rows on threads of the
+        default pool, the block cache's probe and the footer walk with the
+        parquet decode), then `merge_wait` awaits `_merge_segment`, ONE
+        call on a thread of the same pool (`asyncio_<n>`) that runs every
+        stage from `host_prep` to `materialize`, the wait on the device
+        among them. Always, whatever the size and the route: a
+        compaction's merge and a query's are the same call, and an
+        aggregate pushdown (`scan_segment_downsample`) has the same shape
+        with `fold_wait` and `_fold_segment` in their place.
 
         Segments whose SSTs exceed `scan_block_rows` in total take the
         hierarchical path: per-chunk device passes (filter+merge+dedup) whose
@@ -1764,6 +1823,30 @@ class ParquetReader:
             # binary columns keep the single-block hybrid path
         read_names = self._resolve_read_names(projections, keep_builtin)
 
+        tables = await self._decode_segment(
+            ssts, read_names, predicate, use_block_cache)
+        if not tables:
+            return []
+        # the loop starts the merge and takes its batches; everything
+        # between runs on one worker thread, and this stage is the await
+        # itself, the wait for a thread included
+        with scanstats.stage(scanstats.MERGE_WAIT):
+            return await asyncio.to_thread(
+                self._merge_segment, tables, predicate, read_names,
+                keep_builtin, batch_size,
+            )
+
+    async def _decode_segment(
+        self,
+        ssts: list[SstFile],
+        read_names: list[str],
+        predicate: Predicate | None,
+        use_block_cache: bool,
+    ) -> list[pa.Table]:
+        """The `io_decode` stage of a segment: every SST opened
+        (`_open_sst`: what may await) and read on worker threads; the
+        tables that hold rows, in the order of `ssts`. What the loop does
+        here is await."""
         with scanstats.stage("io_decode"):
             opened = await asyncio.gather(
                 *(self._open_sst(s, read_names, predicate, use_block_cache)
@@ -1784,6 +1867,7 @@ class ParquetReader:
                     rows = 0
                 jobs[-1].append(i)
                 rows += sst.meta.num_rows
+
             def decode_job(job: list[int]) -> list[pa.Table]:
                 return [opened[i]() for i in job]
 
@@ -1792,17 +1876,7 @@ class ParquetReader:
             for job, tables in zip(jobs, decoded):
                 for i, table in zip(job, tables):
                     opened[i] = table
-        tables = [t for t in opened if t.num_rows > 0]
-        if not tables:
-            return []
-        # the loop starts the merge and takes its batches; everything
-        # between runs on one worker thread, and this stage is the await
-        # itself, the wait for a thread included
-        with scanstats.stage(scanstats.MERGE_WAIT):
-            return await asyncio.to_thread(
-                self._merge_segment, tables, predicate, read_names,
-                keep_builtin, batch_size,
-            )
+        return [t for t in opened if t.num_rows > 0]
 
     def _merge_segment(
         self,
@@ -2321,77 +2395,32 @@ class ParquetReader:
           scan and fold each of its sorted batches: device memory stays
           bounded.
 
+        The event loop's part is awaits only: `io_decode` awaits the reads
+        of the segment's SSTs (`_decode_segment`, as a materialising scan:
+        hops of a batch of rows, the block cache's probe and the footer
+        walk on the worker), then `fold_wait` awaits `_fold_segment`, ONE
+        call on a thread of the default pool (`asyncio_<n>`) that runs
+        `host_prep`, `pack_sort` and the fold's stages (`fold_prep`,
+        `fold_h2d`, `fold_kernel`, `fold_d2h`, or `fold_host`), the wait on
+        the device among them. Always, whatever the size and the route.
+        The chunked route alone still folds on the loop's thread.
+
         Returns host numpy grids: sum and count, plus min/max when
         `with_minmax` (no mean — callers derive it after combining partials).
         """
-        import jax.numpy as jnp
-
-        num_series = len(series_ids)
-        grids = {
-            "sum": np.zeros((num_series, num_buckets)),
-            "count": np.zeros((num_series, num_buckets)),
-        }
-        if with_minmax:
-            grids["min"] = np.full((num_series, num_buckets), np.inf)
-            grids["max"] = np.full((num_series, num_buckets), -np.inf)
-
-        def dense_sid(col: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-            """(dense position, hit mask). Misses keep their MONOTONE
-            searchsorted position (not -1): the sorted-segment compaction
-            needs monotone keys, and misses are excluded via the reduction's
-            weight column instead of a key sentinel."""
-            pos = np.searchsorted(series_ids, col)
-            pos_c = np.clip(pos, 0, max(0, len(series_ids) - 1))
-            hit = series_ids[pos_c] == col
-            return pos_c.astype(np.int32), hit
-
-        from horaedb_tpu.parallel.mesh import active_mesh
-
-        mesh = active_mesh()
-
-        def accumulate_sorted(ts_np, sid_np, val_np, valid_np=None):
-            """Fold one sorted run into the grids (sorted-segment fast path).
-            With an ambient multi-device mesh installed, rows shard over
-            "rows" and the output grid over "series" (SURVEY §2.5's
-            shard_map-over-SST-partitions); partials combine via psum/pmin/
-            pmax over ICI. Single device: the local sorted kernel.
-            `valid_np` excludes rows via the reduction's weight column
-            (sid_np must stay monotone for excluded rows too)."""
-            # cooperative deadline between device-lane launches: each fold
-            # is one kernel dispatch — an expired query stops dispatching
-            # (host-side check; never traced into the kernel body)
-            deadline_ctx.check("device_lane")
-            if mesh is not None:
-                with scanstats.stage("device_agg"):
-                    out = self._sharded_accumulate(
-                        mesh, ts_np, sid_np, val_np, t0, bucket_ms,
-                        num_series, num_buckets, with_minmax, valid_np=valid_np,
-                    )
-            else:
-                out, run = agg_ops.fold_sorted(
-                    ts_np, sid_np, val_np, t0, bucket_ms,
-                    num_series=num_series, num_buckets=num_buckets,
-                    with_minmax=with_minmax, valid=valid_np,
-                )
-                # lane attribution: the implementation this fold ran (the
-                # host reduceat or a device program), from the fold itself
-                scanstats.note("agg_impl_" + run.impl)
-            grids["sum"] += np.asarray(out["sum"])
-            grids["count"] += np.asarray(out["count"])
-            if with_minmax:
-                grids["min"] = np.minimum(grids["min"], np.asarray(out["min"]))
-                grids["max"] = np.maximum(grids["max"], np.asarray(out["max"]))
-
+        spec = _FoldSpec(series_ids, t0, bucket_ms, num_buckets, with_minmax)
         total_rows = sum(s.meta.num_rows for s in ssts)
         if total_rows > self._scan_block_rows and len(ssts) > 1:
             # bounded-memory path: hierarchical scan yields merged, deduped,
             # pk-sorted batches; fold each into the grids
+            grids = spec.empty_grids()
             batches = await self._scan_segment_chunked(
                 ssts, predicate, None, False, batch_size=self._scan_block_rows
             )
             for b in batches:
-                sp, hit = dense_sid(arrow_column_to_numpy(b.column(series_column)))
-                accumulate_sorted(
+                sp, hit = spec.dense_sid(arrow_column_to_numpy(b.column(series_column)))
+                self._accumulate_sorted(
+                    spec, grids,
                     arrow_column_to_numpy(b.column(ts_column)),
                     sp,
                     arrow_column_to_numpy(b.column(value_column)),
@@ -2400,14 +2429,40 @@ class ParquetReader:
             return grids
 
         read_names = self._resolve_read_names(None, False)
-        with scanstats.stage("io_decode"):
-            tables = await asyncio.gather(
-                *(self.read_sst(s, read_names, predicate,
-                   use_block_cache=use_block_cache) for s in ssts)
-            )
-        tables = [t for t in tables if t.num_rows > 0]
+        tables = await self._decode_segment(
+            ssts, read_names, predicate, use_block_cache)
         if not tables:
-            return grids
+            return spec.empty_grids()
+        # the loop starts the fold and takes its grids; everything between
+        # runs on one worker thread, and this stage is the await itself,
+        # the wait for a thread included
+        with scanstats.stage(scanstats.FOLD_WAIT):
+            return await asyncio.to_thread(
+                self._fold_segment, tables, predicate, ts_column,
+                value_column, series_column, spec, packed_ok,
+            )
+
+    def _fold_segment(
+        self,
+        tables: list[pa.Table],
+        predicate: Predicate | None,
+        ts_column: str,
+        value_column: str,
+        series_column: str,
+        spec: "_FoldSpec",
+        packed_ok: bool,
+    ) -> dict:
+        """The synchronous tail of a segment's pushdown, decoded tables in
+        and host grids out, as ONE call on a worker thread: `host_prep`
+        (order, concat, the dense series index), then `pack_sort` and one
+        fold (`packed_ok`), or the fused pass and its fold. The thread's
+        context is the coroutine's (asyncio.to_thread copies it), so each
+        stage reaches the same histogram, span, collector, ledger and
+        deadline as it did on the loop; the grids are made here and handed
+        back, never shared with the loop while the call runs."""
+        from horaedb_tpu.parallel.mesh import active_mesh
+
+        grids = spec.empty_grids()
         with scanstats.stage("host_prep"):
             tables = _order_tables_by_first_key(
                 tables,
@@ -2417,7 +2472,7 @@ class ParquetReader:
                 memtrace.tracked_concat_tables(tables, "host_prep"),
                 "host_prep",
             )
-            sid, sid_hit = dense_sid(
+            sid, sid_hit = spec.dense_sid(
                 arrow_column_to_numpy(
                     memtrace.tracked_combine(
                         table.column(series_column), "host_prep"
@@ -2425,16 +2480,16 @@ class ParquetReader:
                 )
             )
 
-        fast = (
-            self._packed_downsample_pass(table, predicate, sid, sid_hit,
-                                         ts_column, value_column, num_series)
-            if packed_ok else None
-        )
-        if fast is not None:
-            ts_s, sid_s, val_s = fast
-            if len(ts_s):
-                accumulate_sorted(ts_s, sid_s, val_s)
-            return grids
+        if packed_ok:
+            with scanstats.stage("pack_sort"):
+                fast = self._packed_downsample_pass(
+                    table, predicate, sid, sid_hit, ts_column, value_column,
+                    len(spec.series_ids))
+            if fast is not None:
+                ts_s, sid_s, val_s = fast
+                if len(ts_s):
+                    self._accumulate_sorted(spec, grids, ts_s, sid_s, val_s)
+                return grids
 
         # the hit mask rides the fused pass's permutation as an int lane so
         # set-membership misses stay excludable after the device sort; the
@@ -2446,12 +2501,13 @@ class ParquetReader:
         (sorted_cols, _perm, keep, _starts, _kept, _num, _bin,
          bit_lanes) = self._fused_pass(table, predicate, extra_arrays=extra)
         row_ok = keep if all_hit else keep & (sorted_cols["__sidok__"] != 0)
-        if mesh is not None or not agg_ops.device_f64_is_exact():
+        if active_mesh() is not None or not agg_ops.device_f64_is_exact():
             # the merged/deduped rows leave the fused pass for the sorted
             # reduction: sharded over the mesh, or (a backend whose f64 is
             # not exact) with its selections on integer lanes; misses keep
             # their monotone position and are zeroed via the weight column
-            accumulate_sorted(
+            self._accumulate_sorted(
+                spec, grids,
                 np.asarray(sorted_cols[ts_column]).astype(np.int64),
                 np.asarray(sorted_cols["__sid__"]).astype(np.int32),
                 _host_lane(sorted_cols, value_column, bit_lanes),
@@ -2467,14 +2523,53 @@ class ParquetReader:
             sorted_cols["__sid__"],
             values,
             row_ok,
-            t0,
-            bucket_ms,
-            num_series=num_series,
-            num_buckets=num_buckets,
+            spec.t0,
+            spec.bucket_ms,
+            num_series=len(spec.series_ids),
+            num_buckets=spec.num_buckets,
         )
-        for k in list(grids):
-            grids[k] = np.asarray(out[k])
-        return grids
+        return {k: np.asarray(out[k]) for k in grids}
+
+    def _accumulate_sorted(
+        self, spec: "_FoldSpec", grids: dict, ts_np, sid_np, val_np,
+        valid_np=None,
+    ) -> None:
+        """Fold one sorted run into `grids` (sorted-segment fast path).
+        With an ambient multi-device mesh installed, rows shard over
+        "rows" and the output grid over "series" (SURVEY §2.5's
+        shard_map-over-SST-partitions); partials combine via psum/pmin/
+        pmax over ICI. Single device: the local sorted kernel.
+        `valid_np` excludes rows via the reduction's weight column
+        (sid_np must stay monotone for excluded rows too)."""
+        from horaedb_tpu.parallel.mesh import active_mesh
+
+        # cooperative deadline between device-lane launches: each fold
+        # is one kernel dispatch — an expired query stops dispatching
+        # (host-side check; never traced into the kernel body)
+        deadline_ctx.check("device_lane")
+        num_series = len(spec.series_ids)
+        mesh = active_mesh()
+        if mesh is not None:
+            with scanstats.stage("device_agg"):
+                out = self._sharded_accumulate(
+                    mesh, ts_np, sid_np, val_np, spec.t0, spec.bucket_ms,
+                    num_series, spec.num_buckets, spec.with_minmax,
+                    valid_np=valid_np,
+                )
+        else:
+            out, run = agg_ops.fold_sorted(
+                ts_np, sid_np, val_np, spec.t0, spec.bucket_ms,
+                num_series=num_series, num_buckets=spec.num_buckets,
+                with_minmax=spec.with_minmax, valid=valid_np,
+            )
+            # lane attribution: the implementation this fold ran (the
+            # host reduceat or a device program), from the fold itself
+            scanstats.note("agg_impl_" + run.impl)
+        grids["sum"] += np.asarray(out["sum"])
+        grids["count"] += np.asarray(out["count"])
+        if spec.with_minmax:
+            grids["min"] = np.minimum(grids["min"], np.asarray(out["min"]))
+            grids["max"] = np.maximum(grids["max"], np.asarray(out["max"]))
 
     # packed-key sort budget: sid | ts-offset | seq-rank must fit below the
     # sink bit (63). Exceeding any budget falls back to the fused lexsort.
@@ -2688,8 +2783,10 @@ def _read_pruned(
     predicate: Predicate | None,
     rg_cache=None,   # optional (get(rg), put(rg, table)) hooks
     meta_sink=None,  # optional callback stashing (metadata, schema_arrow)
+    keep_groups: list[int] | None = None,  # from a walk that already ran
 ) -> pa.Table:
-    keep_groups = _select_row_groups(pf.metadata, pf.schema_arrow, predicate)
+    if keep_groups is None:
+        keep_groups = _select_row_groups(pf.metadata, pf.schema_arrow, predicate)
     if meta_sink is not None:
         meta_sink(pf.metadata, pf.schema_arrow)
     if not keep_groups:

@@ -74,6 +74,16 @@ _STAGE_LANE = {
 # profiler's timeline it is a wait by name: no idle gap is named after it.
 MERGE_WAIT = "merge_wait"
 
+# The same for the aggregate pushdown (`scan_segment_downsample`): the
+# loop-side await of `_fold_segment`, the worker call that runs `host_prep`,
+# `pack_sort` and the fold's stages. Its count is raw segments folded off
+# the loop (one a segment a query); its sum less those inner stages is the
+# wait for a thread and for the GIL. The pushdown has no span of its own:
+# its stages land on the caller's span, whose readers take them off the
+# span's duration, so this one stays out of the span's `stages` too.
+FOLD_WAIT = "fold_wait"
+WAIT_STAGES = frozenset((MERGE_WAIT, FOLD_WAIT))
+
 STAGE_SECONDS = GLOBAL_METRICS.histogram(
     "horaedb_scan_stage_seconds",
     help="Per-stage scan time by lane (io_decode, host_prep, transfer, "
@@ -95,11 +105,13 @@ STAGE_SECONDS = GLOBAL_METRICS.histogram(
 # dispatcher's choice, order keys, padding to the row class), `fold_h2d`,
 # `fold_kernel` (dispatch of `downsample_fold` and the wait for it),
 # `fold_d2h` (the grids back, sliced and decoded); `fold_host` is the whole
-# fold where the host lane (reduceat) serves it.
+# fold where the host lane (reduceat) serves it. `pack_sort` is what comes
+# before them on the packed route (`_packed_downsample_pass`: the host
+# predicate, the key packing, one stable argsort, the gathers).
 FOLD_STAGES = ("fold_prep", "fold_h2d", "fold_kernel", "fold_d2h", "fold_host")
 
 for _lane in ("io_decode", "host_prep", "transfer", "kernel", "compile",
-              "decode", MERGE_WAIT, *FOLD_STAGES):
+              "decode", MERGE_WAIT, FOLD_WAIT, "pack_sort", *FOLD_STAGES):
     STAGE_SECONDS.labels(_lane)
 del _lane
 
@@ -185,7 +197,7 @@ class ScanStats:
         lanes = {"io": 0.0, "host": 0.0, "transfer": 0.0, "kernel": 0.0,
                  "compile": 0.0, "decode": 0.0}
         for stage_name, secs in self.seconds.items():
-            if stage_name != MERGE_WAIT:  # its inner stages are all here
+            if stage_name not in WAIT_STAGES:  # their inner stages are all here
                 lanes[_BOUND_LANE.get(stage_name, "host")] += secs
         bound = max(lanes, key=lanes.get) if any(lanes.values()) else None
         return {
@@ -351,7 +363,8 @@ def _fold(st: "ScanStats | None", stage: str, child, dt: float) -> None:
     if st is not None:
         st.add(stage, dt)
     child.observe(dt)
-    tracing.add_stage(stage, dt)
+    if stage != FOLD_WAIT:
+        tracing.add_stage(stage, dt)
 
 
 class _Stage:

@@ -23,6 +23,7 @@ os.environ.setdefault("HORAEDB_AGG_CALIB_N", "20000")
 import asyncio
 import faulthandler
 import functools
+import gc
 import io
 import signal
 
@@ -51,6 +52,20 @@ def _pending_task_stacks() -> str:
     for task in tasks:
         task.print_stack(file=out)
     return out.getvalue()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _settled_heap():
+    """What the files before this one left alive in the worker's process
+    (jax's caches, compiled programs, metric children: 1e5 objects and more)
+    is swept once and frozen, so that a full collection inside this file
+    walks this file's objects only. Unfrozen, one full pass late in a whole
+    run holds every thread 0.2-0.7 s (measured under `-n 6`, PR 31), which
+    the tests that time the event loop or run under a 50 ms deadline read
+    as the program's own lateness."""
+    gc.collect()
+    gc.freeze()
+    yield
 
 
 @pytest.fixture(autouse=True)

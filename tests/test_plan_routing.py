@@ -282,6 +282,174 @@ class TestMergesAtOnce:
             _HostCalib.reset()
 
 
+class TestFoldsAtOnce:
+    """A segment's aggregate pushdown folds on a worker thread, so eight
+    callers' folds run at once: the answers are the sequential ones bit for
+    bit, a deadline still ends a call, and what the folds share (the row
+    classes, the dispatcher's record) takes it."""
+
+    SERIES = 50
+
+    @classmethod
+    async def overlapping(cls, root: str):
+        """An engine over 12 SSTs that write the same (series, ts) cells
+        again and again (pk2 pinned: the packed route's contract), so that
+        every subset of them dedups across its files."""
+        from horaedb_tpu.objstore import MemStore
+        from horaedb_tpu.storage import ObjectBasedStorage, TimeRange, WriteRequest
+
+        schema = pa.schema([("pk1", pa.int64()), ("pk2", pa.int64()),
+                            ("ts", pa.int64()), ("value", pa.float64())])
+        eng = await ObjectBasedStorage.try_new(
+            root, MemStore(), schema, num_primary_keys=3,
+            segment_duration_ms=3_600_000,
+            enable_compaction_scheduler=False, start_background_merger=False,
+        )
+        rng = np.random.default_rng(17)
+        rows = 6_000
+        for _ in range(12):
+            cell = rng.choice(cls.SERIES * 400, rows, replace=False)
+            batch = pa.RecordBatch.from_pydict({
+                "pk1": cell % cls.SERIES, "pk2": np.zeros(rows, np.int64),
+                "ts": cell // cls.SERIES * 10, "value": rng.normal(size=rows) * 1e3,
+            }, schema=schema)
+            await eng.write(WriteRequest(batch, TimeRange(0, 3_600_000)))
+        ssts = sorted(eng.manifest.all_ssts(), key=lambda f: f.id)
+        assert len(ssts) == 12
+        return eng, ssts
+
+    @classmethod
+    def pushdown(cls, eng, subset, predicate, packed_ok):
+        return eng.parquet_reader.scan_segment_downsample(
+            subset, predicate, "ts", "value", "pk1", np.arange(0, cls.SERIES, 2),
+            0, 500, 8, packed_ok=packed_ok)
+
+    @pytest.mark.parametrize("packed_ok", [True, False], ids=["packed", "fused"])
+    @async_test
+    async def test_concurrent_pushdowns_equal_sequential(self, monkeypatch, packed_ok):
+        import asyncio
+
+        from horaedb_tpu.ops import filter as F
+        from horaedb_tpu.storage.read import ParquetReader
+
+        eng, ssts = await self.overlapping(f"db-folds-at-once-{packed_ok}")
+        # 8 pushdowns over overlapping sets of 5 SSTs, half of the series
+        # asked for, every other call under a predicate
+        calls = [(ssts[i:i + 5], F.Compare("ts", "lt", 3_000) if i % 2 else None)
+                 for i in range(8)]
+        in_flight, peak, lock = [0], [0], threading.Lock()
+        real = ParquetReader._fold_segment
+
+        def counted(self, *args):
+            with lock:
+                in_flight[0] += 1
+                peak[0] = max(peak[0], in_flight[0])
+            try:
+                time.sleep(0.02)  # jaxlint: disable=J018 long enough to meet the next
+                return real(self, *args)
+            finally:
+                with lock:
+                    in_flight[0] -= 1
+
+        monkeypatch.setattr(ParquetReader, "_fold_segment", counted)
+        try:
+            one_by_one = [await self.pushdown(eng, *c, packed_ok) for c in calls]
+            assert peak[0] == 1
+            with scanstats.scan_stats() as st:
+                at_once = await asyncio.gather(
+                    *(self.pushdown(eng, *c, packed_ok) for c in calls))
+            assert peak[0] >= 2, "the folds did not overlap"
+            for want, got in zip(one_by_one, at_once):
+                assert want["count"].sum() > 0 and set(got) == {"sum", "count", "min", "max"}
+                for k in want:  # bit for bit: the same rows, program and order
+                    np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+            # one collector took the stages of all eight, none lost
+            assert st.counts[scanstats.FOLD_WAIT] == 8
+            assert st.counts["host_prep"] == 8
+            assert st.counts.get("pack_sort", 0) == (8 if packed_ok else 0)
+            assert st.counts.get("device_merge", 0) == (0 if packed_ok else 8)
+        finally:
+            await eng.close()
+
+    @async_test
+    async def test_a_deadline_that_expires_mid_call_still_raises(self, monkeypatch):
+        """The budget runs out while the worker sorts: the check before the
+        fold's dispatch raises there, and the caller gets what it got when
+        the tail ran on the loop."""
+        from horaedb_tpu.common import deadline as deadline_ctx
+        from horaedb_tpu.common.error import DeadlineExceeded
+        from horaedb_tpu.ops import aggregate as agg_ops
+        from horaedb_tpu.storage.read import ParquetReader
+
+        eng, ssts = await self.overlapping("db-folds-deadline")
+        now = [0.0]
+        real = ParquetReader._packed_downsample_pass
+        folds = []
+
+        def sort_past_the_budget(self, *args):
+            out = real(self, *args)
+            now[0] = 5.0
+            return out
+
+        monkeypatch.setattr(ParquetReader, "_packed_downsample_pass", sort_past_the_budget)
+        monkeypatch.setattr(agg_ops, "fold_sorted", lambda *a, **k: folds.append(1))
+        try:
+            with deadline_ctx.deadline_scope(deadline_ctx.Deadline(1.0, clock=lambda: now[0])):
+                with pytest.raises(DeadlineExceeded) as err:
+                    await self.pushdown(eng, ssts[:3], None, True)
+            assert err.value.at == "device_lane" and not folds
+        finally:
+            await eng.close()
+
+    def test_what_the_folds_share_loses_no_update(self):
+        """16 threads (more than cores) under a short switch interval pick
+        row classes and record the dispatcher's choice: every class is
+        kept once and in order, a pick never shrinks, every record counts."""
+        import sys
+
+        from horaedb_tpu.ops import agg_registry
+        from horaedb_tpu.ops.aggregate import _MAX_RIDE_ROWS, _RowClasses
+        from tests.test_stage_funnel import counter
+
+        threads, rounds = 16, 500
+        classes = _RowClasses()
+        naturals = [1 << p for p in range(9, 22)]  # 512 .. 2^21: past the ride limit too
+        start = threading.Barrier(threads)
+        wrong: list[tuple] = []
+
+        def work(seed: int):
+            rng = np.random.default_rng(seed)
+            start.wait(timeout=30)
+            for _ in range(rounds):
+                natural = int(rng.choice(naturals))
+                key = ("grid", int(rng.integers(0, 3)))
+                got = classes.pick(key, natural)
+                if got < natural or (got != natural and got > _MAX_RIDE_ROWS):
+                    wrong.append((natural, got))
+                agg_registry.record_choice("reduceat")
+
+        before = counter("horaedb_agg_impl_total", impl="reduceat")
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            ts = [threading.Thread(target=work, args=(i,)) for i in range(threads)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in ts)
+        finally:
+            sys.setswitchinterval(old)
+        assert not wrong, wrong[:5]
+        assert len(classes._rows) == 3
+        for have in classes._rows.values():
+            assert have == sorted(set(have)) and set(have) <= set(naturals)
+            # a class at or under the limit is ridden by every smaller pick:
+            # only the first such class and the ones above the limit remain
+            assert sum(1 for r in have if r <= _MAX_RIDE_ROWS) >= 1
+        assert counter("horaedb_agg_impl_total", impl="reduceat") - before == threads * rounds
+
+
 class TestMergeShapeClasses:
     def test_merge_rows_are_power_of_two_classes(self):
         from horaedb_tpu.storage.read import _merge_rows

@@ -467,11 +467,215 @@ class TestMergeOffTheLoop:
         assert table.num_rows >= big
         scan_hops = [h for h in hops if h.startswith(ParquetReader.__name__ + ".")]
         assert sorted(scan_hops) == [
+            "ParquetReader._decode_segment.<locals>.decode_job",
+            "ParquetReader._decode_segment.<locals>.decode_job",
             "ParquetReader._merge_segment",
-            "ParquetReader._scan_segment.<locals>.decode_job",
-            "ParquetReader._scan_segment.<locals>.decode_job",
         ], hops
         assert st.counts["ssts_read"] == 4 and st.counts["io_decode"] == 1
+        await eng.close()
+
+
+class TestFoldOffTheLoop:
+    """A segment's aggregate pushdown (host_prep, the packed sort, the fold
+    with its wait on the device) is ONE call on a worker thread: the loop
+    opens the SSTs, awaits the reads, starts the fold and takes its grids."""
+
+    SLOW_S = 0.3
+    SERIES = 4
+
+    @staticmethod
+    async def engine(root: str, store=None):
+        return await ObjectBasedStorage.try_new(
+            root, store or MemStore(), make_schema(), 3, SEGMENT_MS,
+            enable_compaction_scheduler=False, start_background_merger=False,
+        )
+
+    @classmethod
+    async def write_ssts(cls, eng, files: int, rows: int) -> None:
+        """`files` SSTs of `rows` rows each: series pk1 in [0, SERIES), pk2
+        pinned (the packed route's contract), every (series, ts) once."""
+        schema = make_schema()
+        for i in range(files):
+            n = np.arange(i * rows, (i + 1) * rows)
+            await eng.write(WriteRequest(
+                make_batch(schema, n % cls.SERIES, np.zeros(rows), 10 + n // cls.SERIES,
+                           n.astype(np.float64)),
+                TimeRange(10, 10 + files * rows)))
+
+    @classmethod
+    def pushdown(cls, eng, ssts, packed_ok: bool = True):
+        return eng.parquet_reader.scan_segment_downsample(
+            ssts, None, "ts", "value", "pk1", np.arange(cls.SERIES), 0, 2000, 8,
+            packed_ok=packed_ok)
+
+    @pytest.mark.parametrize("heartbeat", ["own", "server"])
+    @async_test
+    async def test_the_loop_stays_free_and_the_stages_arrive(self, monkeypatch, heartbeat):
+        from horaedb_tpu.ops import aggregate as agg_ops
+
+        real = agg_ops.fold_sorted
+        ran_on: list[str] = []
+
+        def slow(*args, **kwargs):
+            # synchronous, as the wait on the device is
+            ran_on.append(threading.current_thread().name)
+            time.sleep(self.SLOW_S)  # jaxlint: disable=J018 the fold is slow on purpose
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(agg_ops, "fold_sorted", slow)
+        eng = await self.engine(f"funnel/fold-offloop-{heartbeat}")
+        await self.write_ssts(eng, files=4, rows=500)
+        ssts = sorted(eng.manifest.all_ssts(), key=lambda f: f.id)
+        names = ("io_decode", "host_prep", "pack_sort", "fold_prep", "fold_host",
+                 "transfer", "kernel", scanstats.FOLD_WAIT)
+
+        async def two_segments():
+            # two calls at once under one span and one collector, as a
+            # query's two segments are
+            with tracing.trace("offloop-fold") as t, scanstats.scan_stats() as st:
+                grids = await asyncio.gather(
+                    self.pushdown(eng, ssts[:2]), self.pushdown(eng, ssts[2:]))
+            return t, st, grids
+
+        tracing.configure(sample=1.0)
+        for _ in range(4):  # a loaded machine is late now and then on its own
+            ran_on.clear()
+            before = {s: hist("horaedb_scan_stage_seconds", stage=s) for s in names}
+            late, (t, st, grids) = await TestMergeOffTheLoop.under_heartbeat(
+                heartbeat, two_segments)
+            if late < 0.1:
+                break
+        assert late < 0.1, f"the loop was held {late:.3f} s by a {self.SLOW_S} s fold"
+        assert len(ran_on) == 2 and all(n.startswith("asyncio_") for n in ran_on), ran_on
+        assert sum(g["count"].sum() for g in grids) == 2000
+        after = {s: hist("horaedb_scan_stage_seconds", stage=s) for s in names}
+        # the fold's own lanes: the host lane, or the program's four
+        device = {"fold_prep", "fold_h2d", "fold_kernel", "fold_d2h"}
+        ran = {"fold_prep", "fold_host"} if "fold_host" in st.seconds else device
+        inner = {"host_prep", "pack_sort", *ran}
+        assert {"io_decode", scanstats.FOLD_WAIT, *inner} == set(st.seconds)
+        lane = {"fold_h2d": "transfer", "fold_d2h": "transfer", "fold_kernel": "kernel"}
+        for s in inner | {"io_decode"}:  # histogram, collector, span: all three
+            assert after[lane.get(s, s)][0] > before[lane.get(s, s)][0], s
+            assert st.counts[s] == 2, s
+        (root,) = [sp for sp in t.spans if sp.name == "offloop-fold"]
+        assert set(root.attrs["stages"]) == inner | {"io_decode"}
+        # the await: counted once a segment, holds the call's stages, and is
+        # in no sum of lanes, the span's among them
+        assert after[scanstats.FOLD_WAIT][0] - before[scanstats.FOLD_WAIT][0] == 2
+        assert st.counts[scanstats.FOLD_WAIT] == 2
+        assert st.seconds[scanstats.FOLD_WAIT] >= sum(st.seconds[s] for s in inner)
+        assert st.seconds[scanstats.FOLD_WAIT] >= 2 * self.SLOW_S
+        work = sum(v for k, v in st.seconds.items() if k != scanstats.FOLD_WAIT)
+        assert sum(st.attribution()["lanes_s"].values()) == pytest.approx(work, abs=1e-5)
+        assert sum(root.attrs["stages"].values()) == pytest.approx(work, abs=1e-4)
+        assert WAITS.search("scan." + scanstats.FOLD_WAIT)
+        assert not WAITS.search("scan.pack_sort")
+        await eng.close()
+
+    @async_test
+    async def test_thirty_small_ssts_decode_in_hops_of_a_batch_of_rows(self, monkeypatch):
+        """As a materialising scan's: thirty files of 1,100 rows share a
+        hop up to a batch of rows between them (seven a hop: five hops),
+        and the fold is one hop more."""
+        from horaedb_tpu.storage.read import DEFAULT_SCAN_BATCH_SIZE, ParquetReader
+
+        eng = await self.engine("funnel/fold-hops")
+        await self.write_ssts(eng, files=30, rows=1100)
+        ssts = sorted(eng.manifest.all_ssts(), key=lambda f: f.id)
+        assert len(ssts) == 30 and 7 * 1100 <= DEFAULT_SCAN_BATCH_SIZE < 8 * 1100
+        hops: list[str] = []
+        real = asyncio.to_thread
+
+        async def counting(fn, *args, **kwargs):
+            hops.append(getattr(fn, "__qualname__", repr(fn)))
+            return await real(fn, *args, **kwargs)
+
+        monkeypatch.setattr(asyncio, "to_thread", counting)
+        with scanstats.scan_stats() as st:
+            grids = await self.pushdown(eng, ssts)
+        assert grids["count"].sum() == 33_000
+        scan_hops = [h for h in hops if h.startswith(ParquetReader.__name__ + ".")]
+        assert sorted(scan_hops) == [
+            *["ParquetReader._decode_segment.<locals>.decode_job"] * 5,
+            "ParquetReader._fold_segment",
+        ], hops
+        assert st.counts["ssts_read"] == 30 and st.counts["io_decode"] == 1
+        await eng.close()
+
+    @async_test
+    async def test_the_works_names_are_opened_by_the_workers(self, monkeypatch):
+        """What the loop's thread opens of a pushdown are the two stages
+        whose bodies await: `io_decode`, as a materialising scan's loop
+        does, and `fold_wait`, a wait by name. Every stage of the work
+        itself is opened by the worker that runs it."""
+        eng = await self.engine("funnel/fold-annotations")
+        await self.write_ssts(eng, files=2, rows=500)
+        opened_by: dict[str, set] = {}
+
+        class ByThread:
+            def __init__(self, name, **_kw):
+                opened_by.setdefault(name, set()).add(threading.current_thread().name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(scanstats, "TraceAnnotation", ByThread)
+        await self.pushdown(eng, eng.manifest.all_ssts())
+        loop_thread = threading.current_thread().name
+        on_loop = {n for n, who in opened_by.items() if loop_thread in who}
+        assert on_loop == {"scan.io_decode", "scan.fold_wait"}
+        assert WAITS.search("scan.fold_wait")
+        on_workers = {n for n, who in opened_by.items() if who - {loop_thread}}
+        work = {"scan.io_decode", "scan.host_prep", "scan.pack_sort", "scan.fold_prep"}
+        assert work <= on_workers
+        assert not any(WAITS.search(n) for n in on_workers)
+        await eng.close()
+
+    @pytest.mark.parametrize("store", ["mem", "local"])
+    @async_test
+    async def test_the_footer_is_walked_once_an_sst_and_never_on_the_loop(
+            self, monkeypatch, tmp_path, store):
+        """Cold (the parquet file's own footer) and warm (the cached footer
+        in front of the block cache's probe), over a store that hands out
+        local files and one that hands out bytes: `_select_row_groups`
+        runs once an SST a query, on a worker."""
+        from horaedb_tpu.objstore import LocalStore
+        from horaedb_tpu.storage import read as read_mod
+
+        eng = await self.engine(
+            f"funnel/fold-footer-{store}",
+            LocalStore(str(tmp_path)) if store == "local" else MemStore())
+        await self.write_ssts(eng, files=3, rows=600)
+        ssts = sorted(eng.manifest.all_ssts(), key=lambda f: f.id)
+        real = read_mod._select_row_groups
+        walks: list[str] = []
+
+        def walk(*args):
+            walks.append(threading.current_thread().name)
+            return real(*args)
+
+        monkeypatch.setattr(read_mod, "_select_row_groups", walk)
+        loop_thread = threading.current_thread().name
+        answers = []
+        for served in ("cold", "warm", "warm"):
+            walks.clear()
+            with scanstats.scan_stats() as st:
+                answers.append(await self.pushdown(eng, ssts))
+            assert len(walks) == 3 and loop_thread not in walks, (served, walks)
+            assert st.counts["ssts_read"] == 3
+        assert answers[0]["count"].sum() == 1800
+        for again in answers[1:]:
+            for k, g in answers[0].items():
+                np.testing.assert_array_equal(again[k], g)
+        # the raw scan opens its SSTs through the same call
+        walks.clear()
+        table = await collect(eng, ScanRequest(range=TimeRange(0, SEGMENT_MS)))
+        assert table.num_rows == 1800
+        assert len(walks) == 3 and loop_thread not in walks, walks
         await eng.close()
 
 
