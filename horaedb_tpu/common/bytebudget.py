@@ -1,6 +1,6 @@
 """Unified byte-budget pool registry.
 
-The engine grew five byte-bounded caches, each tracking its own bytes
+The engine grew four byte-bounded caches, each tracking its own bytes
 with its own gauge family and its own eviction discipline:
 
 - ``scan``      — decoded row-group block cache (storage/read.py
@@ -8,8 +8,6 @@ with its own gauge family and its own eviction discipline:
 - ``sidecar``   — encoded-lane sidecar cache (storage/read.py
                   `_enc_cache`, per ParquetReader)
 - ``result``    — serving result cache (serving/cache.py RESULT_CACHE)
-- ``residency`` — device block residency (serving/residency.py
-                  RESIDENCY_CACHE; charges host table + device lanes)
 - ``rollup``    — decoded rollup artifacts (storage/rollup.py _CACHE)
 
 This module re-homes them behind ONE registry: each cache keeps its own
@@ -36,8 +34,8 @@ import weakref
 
 from horaedb_tpu.server.metrics import GLOBAL_METRICS
 
-# The five pools, pre-registered so the families render from boot.
-POOLS = ("scan", "sidecar", "result", "residency", "rollup")
+# The four pools, pre-registered so the families render from boot.
+POOLS = ("scan", "sidecar", "result", "rollup")
 
 POOL_BYTES = GLOBAL_METRICS.gauge(
     "horaedb_pool_bytes",

@@ -6,7 +6,7 @@ engine could SEE an allocation or a copy: ROOFLINE §4's copy-tax figure
 was hand-derived. This module is the instrument. Every data-plane
 hand-off (pooled-parser append, memtable seal/drain, flush encode,
 parquet materialize, encoded-lane decode, host_prep lane conversion,
-`jax.device_put` staging, cache/residency fills, the cluster wire codec)
+`jax.device_put` staging, cache fills, the cluster wire codec)
 reports through ONE cheap funnel:
 
     track(buf, "materialize", "copy")        # size read off the buffer
@@ -63,8 +63,7 @@ KINDS = ("alloc", "copy", "view", "reuse")
 # children), the same eager zero-state contract every other family keeps.
 STAGES = (
     "parse", "append", "seal", "flush_encode", "materialize", "host_prep",
-    "decode", "h2d", "result_fill", "residency_fill", "rollup_fill",
-    "wire_codec",
+    "decode", "h2d", "result_fill", "rollup_fill", "wire_codec",
 )
 
 MEM_BYTES = GLOBAL_METRICS.counter(

@@ -187,9 +187,9 @@ class MetricEngine:
         horaedb_series_cardinality).
 
         `serving`: ServingTierConfig for the dashboard serving tier
-        (horaedb_tpu/serving — compaction-time rollups, the result
-        cache, device block residency). None = defaults (ON: the tier
-        is bit-exact vs forced-cold scans by construction).
+        (horaedb_tpu/serving — compaction-time rollups and the result
+        cache). None = defaults (ON: the tier is bit-exact vs
+        forced-cold scans by construction).
 
         `read_only`: cluster replica mode (horaedb_tpu/cluster): open a
         read-only VIEW over a root a writer process owns on the shared

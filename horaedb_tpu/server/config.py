@@ -349,9 +349,9 @@ class MetricEngineConfig:
     # transitions, both admission-controlled as a low-weight tenant.
     rules: RulesConfig = field(default_factory=RulesConfig)
     # Serving tier for repeated dashboard traffic ([metric_engine.serving],
-    # horaedb_tpu/serving): compaction-time rollups, the invalidation-
-    # correct result cache, hot-block device residency. ON by default —
-    # answers are bit-exact vs forced-cold scans (HORAEDB_SERVING=off).
+    # horaedb_tpu/serving): compaction-time rollups and the invalidation-
+    # correct result cache. ON by default — answers are bit-exact vs
+    # forced-cold scans (HORAEDB_SERVING=off).
     serving: "ServingTierConfig" = field(
         default_factory=lambda: _serving_mod().ServingTierConfig()
     )

@@ -1114,8 +1114,8 @@ def _explain_payload(st, mode: str, admission_verdict: dict | None = None) -> di
     # serving-tier verdict (horaedb_tpu/serving): did the result cache
     # serve this query (hit), was it computed + stored (miss), or was the
     # tier off/bypassed (bypass / None when the query never reached the
-    # choke point); which rollup resolution(s) substituted for raw
-    # segment scans; and the residency split of the blocks touched.
+    # choke point); and which rollup resolution(s) substituted for raw
+    # segment scans.
     if counts.get("serving_cache_hit"):
         cache_verdict = "hit"
     elif counts.get("serving_cache_miss"):
@@ -1138,8 +1138,6 @@ def _explain_payload(st, mode: str, admission_verdict: dict | None = None) -> di
         "rollup_segments": counts.get("rollup_segments", 0),
         "rollup_rows_read": counts.get("rollup_rows_read", 0),
         "raw_segments": counts.get("raw_segments", 0),
-        "blocks_resident": counts.get("blocks_resident", 0),
-        "blocks_fetched": counts.get("blocks_fetched", 0),
     }
     # query-batcher verdict (server/batching.py): how many compatible
     # grid queries shared this query's stacked kernel launch (1 = ran
@@ -1859,7 +1857,7 @@ async def handle_debug_slowlog(request: web.Request) -> web.Response:
 
 async def handle_debug_memory(request: web.Request) -> web.Response:
     """`GET /debug/memory`: the data-plane memory observatory on one
-    page — unified pool occupancy (all five byte-budgeted caches through
+    page — unified pool occupancy (all four byte-budgeted caches through
     the common/bytebudget registry), process RSS, the per-stage copy-tax
     table accumulated since boot, and the memtrace mode. Every number is
     a read-back of state the process already keeps; the handler computes
@@ -2743,8 +2741,8 @@ async def build_app(config: Config, store=None) -> web.Application:
         # and the series-cardinality limit ([metric_engine.limits])
         retention_period_ms=config.metric_engine.retention.period_ms(),
         max_series=config.metric_engine.limits.max_series,
-        # serving tier ([metric_engine.serving]): rollups + result cache +
-        # device residency, bit-exact vs HORAEDB_SERVING=off
+        # serving tier ([metric_engine.serving]): rollups + result cache,
+        # bit-exact vs HORAEDB_SERVING=off
         serving=config.metric_engine.serving,
         parser_pool=pool,
     )

@@ -2,7 +2,7 @@
 
 Production dashboard traffic is ~99% repeated panels re-scanning the same
 sealed SSTs every refresh interval. This package turns that repeat work
-into O(1)-ish lookups with three stacked layers, each honest about its
+into O(1)-ish lookups with two stacked layers, each honest about its
 shortcuts (EXPLAIN `serving` verdict, `horaedb_serving_*` families, and
 the `HORAEDB_SERVING=off` forced-cold switch):
 
@@ -25,13 +25,6 @@ the `HORAEDB_SERVING=off` forced-cold switch):
    hit. Flush/compaction/delete events additionally purge the table's
    entries eagerly (the funnel: `serving_invalidate`), and concurrent
    same-key fills collapse to one computation (single-flight).
-
-3. **Hot-block device residency** (serving/residency.py): a
-   byte-bounded cache of decoded column blocks keyed
-   (sst id, row group, column set), admission gated by a touch-count
-   heat signal, pinned via `jax.device_put` — repeat scans of hot SSTs
-   skip object-store IO + parquet decode, and on accelerator backends
-   the pinned lanes are HBM-resident.
 
 jaxlint J013 enforces the funnel discipline: result-cache/rollup READS
 happen only at the planner choke point (engine/data.py) and the serving/
@@ -93,29 +86,11 @@ ROLLUP_ROWS = GLOBAL_METRICS.counter(
     "horaedb_serving_rollup_rows_total",
     help="Pre-aggregated rollup rows read in place of raw rows.",
 )
-RESIDENT_BYTES = GLOBAL_METRICS.gauge(
-    "horaedb_serving_resident_bytes",
-    help="Decoded column-block bytes pinned in the device residency "
-         "cache (HBM on accelerator backends).",
-)
-RESIDENT_BLOCKS = GLOBAL_METRICS.gauge(
-    "horaedb_serving_resident_blocks",
-    help="Column blocks pinned in the device residency cache.",
-)
-RESIDENCY = GLOBAL_METRICS.counter(
-    "horaedb_serving_residency_total",
-    help="Block reads by residency outcome: resident (served from the "
-         "pinned tier, no IO/decode), fetched (decoded from store or "
-         "host cache), admitted (block newly pinned by the heat gate).",
-    labelnames=("result",),
-)
 
 for _r in ("hit", "miss", "bypass"):
     CACHE_REQUESTS.labels(_r)
 for _r in ("flush", "compact", "delete"):
     INVALIDATIONS.labels(_r)
-for _r in ("resident", "fetched", "admitted"):
-    RESIDENCY.labels(_r)
 for _r in ("1m", "1h"):
     ROLLUPS_BUILT.labels(_r)
     ROLLUP_SUBSTITUTIONS.labels(_r)
@@ -123,7 +98,7 @@ for _r in ("1m", "1h"):
 
 def serving_env_off() -> bool:
     """The honesty switch: HORAEDB_SERVING=off forces every query cold
-    (no result cache, no rollup substitution, no residency) so serving
+    (no result cache, no rollup substitution) so serving
     answers can be asserted bit-exact against first-principles scans.
     Read per query, not at import, so tests and operators can flip it
     live."""
@@ -175,12 +150,6 @@ class ServingTierConfig:
     rollup_cache: ReadableSize = field(
         default_factory=lambda: ReadableSize.mb(16)
     )
-    # device residency byte budget (process-global; 0 disables)
-    residency: ReadableSize = field(
-        default_factory=lambda: ReadableSize.mb(64)
-    )
-    # touches of a block before the heat gate admits it to residency
-    residency_admit_after: int = 2
 
     @classmethod
     def from_dict(cls, d: dict | None) -> "ServingTierConfig":
@@ -199,7 +168,7 @@ class ServingTierConfig:
             kwargs["rollup_resolutions"] = [
                 parse_resolution(v) for v in kwargs["rollup_resolutions"]
             ]
-        for k in ("result_cache", "rollup_cache", "residency"):
+        for k in ("result_cache", "rollup_cache"):
             if k in kwargs:
                 kwargs[k] = ReadableSize.parse(kwargs[k])
         return cls(**kwargs)
@@ -207,13 +176,11 @@ class ServingTierConfig:
 
 class ServingTier:
     """One engine's handle on the (process-global) serving tier: the
-    config plus the shared result cache and residency cache, sized at
-    engine open. Installed on each SampleManager as the planner's single
-    entry into the tier."""
+    config plus the shared result cache, sized at engine open. Installed
+    on each SampleManager as the planner's single entry into the tier."""
 
     def __init__(self, config: "ServingTierConfig | None" = None):
         from horaedb_tpu.serving import cache as cache_mod
-        from horaedb_tpu.serving import residency as residency_mod
 
         self.config = config or ServingTierConfig()
         self.cache = cache_mod.RESULT_CACHE
@@ -222,10 +189,6 @@ class ServingTier:
 
             cache_mod.configure(self.config.result_cache.as_bytes())
             rollup_mod.configure_cache(self.config.rollup_cache.as_bytes())
-            residency_mod.configure(
-                self.config.residency.as_bytes(),
-                admit_after=self.config.residency_admit_after,
-            )
 
     def active(self) -> bool:
         """Serving layers may be consulted for this query (config on AND

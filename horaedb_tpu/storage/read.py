@@ -1210,91 +1210,31 @@ class ParquetReader:
     def _rg_cache_hooks(self, sst_id: int, cols_key: tuple):
         """(get, put) closures for _read_pruned, or None when disabled.
 
-        The serving tier's device residency cache (serving/residency.py)
-        rides these hooks as a tier ABOVE the host block cache: a
-        heat-admitted block serves from its pinned entry (noted
-        `blocks_resident` — no store IO, no parquet decode, and on
-        accelerator backends the lanes are already HBM handles), while
-        every non-resident touch feeds the heat gate. Blocks served here
-        are pre-visibility, exactly like the host cache (read_sst masks
-        after assembly), so a later tombstone can never be skipped."""
+        `get` probes the reader's byte-bounded LRU of decoded row groups
+        (`_blk_cache`, keyed `(sst, row group, columns)`), `put` is its
+        size-gated insert. Blocks are cached pre-visibility (read_sst
+        masks after assembly), so a later tombstone can never be
+        skipped."""
         if self._blk_cache_cap <= 0:
             return None
-        from horaedb_tpu.serving import RESIDENCY, serving_env_off
-        from horaedb_tpu.serving.residency import RESIDENCY_CACHE
-
-        # the honesty switch disables this layer too: HORAEDB_SERVING=off
-        # must force genuinely cold reads (store GET + parquet decode) —
-        # serving answers are A/B'd against that oracle, and a pinned
-        # block silently riding the "forced cold" run would exonerate a
-        # residency-layer defect
-        residency = (
-            RESIDENCY_CACHE
-            if RESIDENCY_CACHE.enabled and not serving_env_off() else None
-        )
-        # per-read probe dedup: _assemble_cached probes every kept row
-        # group and falls through to _read_pruned on a partial hit, which
-        # probes them again — without this one query double-counts
-        # blocks_resident/blocks_fetched and ticks the heat gate twice
-        # per block (admission after fewer distinct scans than
-        # residency_admit_after documents)
-        seen: set[int] = set()
 
         def get(rg: int):
-            first = rg not in seen
-            seen.add(rg)
-            if residency is not None:
-                t = residency.resident_block(sst_id, rg, cols_key)
-                if t is not None:
-                    if first:
-                        scanstats.note("blocks_resident")
-                        RESIDENCY.labels("resident").inc()
-                    return t
+            k = (sst_id, rg, cols_key)
             with self._blk_lock:
-                t = self._blk_cache.get((sst_id, rg, cols_key))
+                t = self._blk_cache.get(k)
                 if t is not None:
-                    self._blk_cache.move_to_end((sst_id, rg, cols_key))
-            if t is not None and residency is not None and first:
-                scanstats.note("blocks_fetched")
-                RESIDENCY.labels("fetched").inc()
-                if self._tombstoned(sst_id):
-                    return t
-                # host-cache hits feed the heat gate too: the second touch
-                # of a hot block promotes it to the pinned tier. On
-                # promotion the HOST entry is dropped: both tiers retain
-                # the same pa.Table object, and charging table.nbytes to
-                # both budgets double-counted every hot block's resident
-                # bytes (the doppelganger audit, tests/test_memtrace.py)
-                if residency.note_fetch(sst_id, rg, cols_key, t):
-                    with self._blk_lock:
-                        old = self._blk_cache.pop(
-                            (sst_id, rg, cols_key), None
-                        )
-                        if old is not None:
-                            self._blk_cache_bytes -= old.nbytes
+                    self._blk_cache.move_to_end(k)
             return t
 
         def put(rg: int, table: pa.Table) -> None:
             size = table.nbytes
-            if residency is not None:
-                scanstats.note("blocks_fetched")
-                RESIDENCY.labels("fetched").inc()
-                if not self._tombstoned(sst_id):
-                    # residency admission runs BEFORE the host-cache size
-                    # gate: its budget (and cap//4 dominate-check) is its
-                    # own — a block too big for the host cache can still
-                    # earn a device pin. An admitted block skips the host
-                    # insert entirely: the pinned tier serves it first on
-                    # every later get(), so a host copy would be pure
-                    # double-charged residency (the doppelganger audit)
-                    if residency.note_fetch(sst_id, rg, cols_key, table):
-                        return
             if size > self._blk_cache_cap // 4:
                 return  # one entry must not dominate the cache
+            k = (sst_id, rg, cols_key)
             with self._blk_lock:
-                if self._tombstoned(sst_id) or (sst_id, rg, cols_key) in self._blk_cache:
+                if self._tombstoned(sst_id) or k in self._blk_cache:
                     return
-                self._blk_cache[(sst_id, rg, cols_key)] = table
+                self._blk_cache[k] = table
                 self._blk_cache_bytes += size
                 while self._blk_cache_bytes > self._blk_cache_cap and self._blk_cache:
                     _k, old = self._blk_cache.popitem(last=False)
@@ -1725,11 +1665,6 @@ class ParquetReader:
             self._evicted_ids[file_id] = None
             while len(self._evicted_ids) > 65536:
                 self._evicted_ids.popitem(last=False)
-        # device residency rides the same eviction funnel: a compaction-
-        # deleted SST's pinned blocks die with it (serving/residency.py)
-        from horaedb_tpu.serving.residency import RESIDENCY_CACHE
-
-        RESIDENCY_CACHE.evict_sst(file_id)
         if entry is not None:
             pf, handle_lock = entry
             with handle_lock:  # wait out any in-flight read

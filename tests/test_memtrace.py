@@ -8,9 +8,8 @@ Pins:
 - route shapes: cold scan allocates + copies, the cache-hit route
   allocates NOTHING new, the encoded route reports decode-stage allocs,
   the rollup read reports the fill once (then serves from cache silently);
-- the doppelganger audit (the double-count regression): a block promoted
-  from the host scan cache to the device residency tier is charged to
-  exactly ONE pool;
+- the scan pool's gauge: a cold scan's decoded blocks show in the
+  unified registry and leave it when their SST is evicted;
 - memtrace's own cost: off mode is a string compare, default mode stays
   microseconds-free per event (the <2% query-p50 bound is measured by
   tools/mem_smoke.py on real scans — these bounds only catch a runaway).
@@ -58,19 +57,10 @@ T0 = (1_700_000_000_000 // SEGMENT_MS + 1) * SEGMENT_MS
 
 @pytest.fixture(autouse=True)
 def default_mode():
-    """Every test starts in default ("") mode and restores the prior.
-    The global device-residency cache is disabled too: an earlier test
-    module that booted a server leaves it configured, and a warm scan
-    would then pay promotion copies these route-shape pins don't expect."""
-    from horaedb_tpu.serving.residency import RESIDENCY_CACHE
-
+    """Every test starts in default ("") mode and restores the prior."""
     prior = memtrace.mode()
     memtrace.configure("")
-    RESIDENCY_CACHE.clear()
-    RESIDENCY_CACHE.configure(0)
     yield
-    RESIDENCY_CACHE.clear()
-    RESIDENCY_CACHE.configure(0)
     memtrace.configure(prior)
 
 
@@ -463,74 +453,26 @@ class TestRouteAccounting:
 
 
 # ---------------------------------------------------------------------------
-# The doppelganger audit — satellite 1's double-count regression
+# The scan pool's gauge through a real storage tree
 
 
-class TestDoppelgangerAudit:
+class TestScanPoolGauge:
     @async_test
-    async def test_promoted_block_charged_to_exactly_one_pool(self):
-        """A hot block promoted from the host scan cache to the device
-        residency tier must be charged to residency ONLY: the host entry
-        is dropped on promotion (read.py _rg_cache_hooks), so the same
-        pa.Table never bills two budgets. Before the fix both pools held
-        (and charged) the identical table object."""
-        from horaedb_tpu.serving.residency import RESIDENCY_CACHE
-
-        RESIDENCY_CACHE.clear()
-        RESIDENCY_CACHE.configure(64 * 1024 * 1024, admit_after=2)
-        eng = await new_engine(MemStore())
-        try:
-            await write_rows(eng, seed=5)
-            # scan 1: store read -> host-cache insert (heat 1)
-            # scan 2: host-cache hit -> heat 2 -> promoted, host entry
-            #         dropped
-            # scan 3: served resident
-            for _ in range(3):
-                await scan_verdict(eng)
-            reader = eng.parquet_reader
-            resident_tables = {
-                id(t) for (t, _lanes, _nb) in
-                RESIDENCY_CACHE._blocks.values()
-            }
-            assert resident_tables, "no block was promoted"
-            host_tables = {id(t) for t in reader._blk_cache.values()}
-            assert not (resident_tables & host_tables), (
-                "a promoted block is still held (and charged) by the "
-                "host scan cache — the double-count regression"
-            )
-            # the host budget reflects the drop exactly
-            assert reader._blk_cache_bytes == sum(
-                t.nbytes for t in reader._blk_cache.values()
-            )
-            assert RESIDENCY_CACHE.resident_bytes > 0
-        finally:
-            await eng.close()
-            RESIDENCY_CACHE.clear()
-            RESIDENCY_CACHE.configure(0)
-
-    @async_test
-    async def test_pool_gauges_track_scan_and_residency(self):
+    async def test_pool_gauges_track_scan_fill_and_eviction(self):
         """The unified registry's refresh() sees the live reader's scan
-        pool and the residency pool move when blocks promote."""
-        from horaedb_tpu.serving.residency import RESIDENCY_CACHE
-
-        RESIDENCY_CACHE.clear()
-        RESIDENCY_CACHE.configure(64 * 1024 * 1024, admit_after=2)
+        pool fill on a cold scan and drain when the SSTs are evicted."""
         eng = await new_engine(MemStore())
         try:
+            before = GLOBAL_POOLS.refresh()["scan"]["bytes"]
             await write_rows(eng, seed=6)
             await scan_verdict(eng)
-            after_cold = GLOBAL_POOLS.refresh()
-            assert after_cold["scan"]["bytes"] > 0
-            await scan_verdict(eng)
-            promoted = GLOBAL_POOLS.refresh()
-            assert promoted["residency"]["bytes"] > 0
-            # conservation: promotion MOVES bytes between pools; the
-            # residency charge may exceed the host charge it replaced
-            # (device lanes are a real second copy), but the host pool
-            # must have shrunk
-            assert promoted["scan"]["bytes"] < after_cold["scan"]["bytes"]
+            reader = eng.parquet_reader
+            after_cold = GLOBAL_POOLS.refresh()["scan"]
+            assert after_cold["bytes"] > before
+            assert after_cold["bytes"] - before == reader._blk_cache_bytes
+            for sst_id in {k[0] for k in reader._blk_cache}:
+                reader.evict_cached(sst_id)
+            assert not reader._blk_cache
+            assert GLOBAL_POOLS.refresh()["scan"]["bytes"] == before
         finally:
             await eng.close()
-            RESIDENCY_CACHE.clear()
-            RESIDENCY_CACHE.configure(0)

@@ -565,10 +565,10 @@ EXPLAIN_ENCODING_KEYS = {
     "pages_pruned", "runs_skipped", "decode_impls",
 }
 # serving-tier verdict (horaedb_tpu/serving): result-cache outcome,
-# rollup substitution, residency split
+# rollup substitution
 EXPLAIN_SERVING_KEYS = {
     "cache", "rollup", "rollup_resolutions", "rollup_segments",
-    "rollup_rows_read", "raw_segments", "blocks_resident", "blocks_fetched",
+    "rollup_rows_read", "raw_segments",
 }
 
 
@@ -746,7 +746,7 @@ class TestDebugMemory:
     @async_test
     async def test_debug_memory_renders_all_pools(self, tmp_path):
         """/debug/memory: the unified registry's occupancy snapshot —
-        all five pools with the pinned row shape, the process RSS, the
+        all four pools with the pinned row shape, the process RSS, the
         memtrace mode, and the per-stage copy-tax table (non-empty after
         one write+query touched the data plane)."""
         from horaedb_tpu.common.bytebudget import POOLS

@@ -2,17 +2,18 @@
 
 The contract under test is the tentpole's honesty clause: every answer
 the serving tier produces — result-cache hits, rollup-substituted range
-queries, residency-served blocks — must be EXACTLY the answer a forced
-cold scan produces (`HORAEDB_SERVING=off`), including after flushes,
-compactions, deletes, and reopen. Sample values are integer-valued
-floats so float64 summation is exact under any association order; the
-equality asserts are then bit-exact, not approximate.
+queries — must be EXACTLY the answer a forced cold scan produces
+(`HORAEDB_SERVING=off`), including after flushes, compactions, deletes,
+and reopen. Sample values are integer-valued floats so float64 summation
+is exact under any association order; the equality asserts are then
+bit-exact, not approximate.
 """
 
 from __future__ import annotations
 
 import asyncio
 import os
+import re
 
 import numpy as np
 import pytest
@@ -21,7 +22,6 @@ from horaedb_tpu.engine import MetricEngine, QueryRequest
 from horaedb_tpu.objstore import MemStore
 from horaedb_tpu.serving import ServingTierConfig
 from horaedb_tpu.serving.cache import RESULT_CACHE, ResultCache
-from horaedb_tpu.serving.residency import RESIDENCY_CACHE, DeviceBlockCache
 from horaedb_tpu.storage import scanstats
 from horaedb_tpu.storage import rollup as rollup_mod
 from horaedb_tpu.storage.config import SchedulerConfig, StorageConfig
@@ -37,16 +37,12 @@ DAY = 24 * HOUR
 @pytest.fixture(autouse=True)
 def _clean_serving(monkeypatch):
     """Isolate the process-global serving state per test: the honesty
-    switch unset, both caches empty and at a known capacity."""
+    switch unset, the result cache empty and at a known capacity."""
     monkeypatch.delenv("HORAEDB_SERVING", raising=False)
     RESULT_CACHE.clear()
     RESULT_CACHE.configure(64 << 20)
-    RESIDENCY_CACHE.clear()
-    RESIDENCY_CACHE.configure(0)
     yield
     RESULT_CACHE.clear()
-    RESIDENCY_CACHE.clear()
-    RESIDENCY_CACHE.configure(0)
 
 
 def small_compactions() -> StorageConfig:
@@ -713,84 +709,52 @@ class TestResultCacheUnit:
         asyncio.run(run())
 
 
-class TestResidency:
-    def test_heat_gate_admission_and_byte_bound(self):
-        import pyarrow as pa
-
-        cache = DeviceBlockCache(capacity_bytes=1 << 20, admit_after=2)
-        t = pa.table({"ts": np.arange(100, dtype=np.int64),
-                      "value": np.arange(100, dtype=np.float64)})
-        key = (1, 0, ("ts", "value"))
-        assert cache.resident_block(*key) is None
-        assert cache.note_fetch(*key, t) is False   # heat 1: below the gate
-        assert cache.resident_block(*key) is None
-        assert cache.note_fetch(*key, t) is True    # heat 2: admitted
-        got = cache.resident_block(*key)
-        assert got is not None and got.equals(t)
-        # the budget charges BOTH copies: the host table and the pinned
-        # device lanes (on the CPU test backend the pins are host buffers
-        # of the same width — still real bytes)
-        assert cache.resident_bytes >= t.nbytes
-        # eviction funnel: the SST dies, its blocks die with it
-        cache.evict_sst(1)
-        assert cache.resident_block(*key) is None
-        assert cache.resident_bytes == 0
-
-    def test_lru_pressure_evicts_oldest(self):
-        import pyarrow as pa
-
-        t = pa.table({"v": np.arange(1000, dtype=np.float64)})  # ~8KB
-        # each admitted block costs ~2x t.nbytes (host table + the pinned
-        # device copy of the numeric lane — both charged to the budget)
-        cache = DeviceBlockCache(capacity_bytes=10 * t.nbytes, admit_after=1)
-        for sst in range(8):
-            cache.note_fetch(sst, 0, ("v",), t)
-        assert cache.resident_bytes <= 10 * t.nbytes
-        assert cache.resident_block(0, 0, ("v",)) is None
-        assert cache.resident_block(7, 0, ("v",)) is not None
-
+class TestBlockCacheUnderServing:
     @async_test
-    async def test_repeat_scans_serve_resident_blocks_exactly(self):
-        """Integration: with the result cache off (so every query really
-        scans) and residency on, the second identical scan admits the
-        hot blocks and the third serves them — bit-exact, with the
-        blocks_resident provenance EXPLAIN surfaces."""
+    async def test_repeat_scans_serve_cached_blocks_exactly(self):
+        """Integration: with the result cache off every query really
+        scans. The first answer is the cold one; the third identical
+        query finds its decoded blocks in the reader's block cache and
+        answers bit-exactly, against the first and against the
+        forced-cold oracle."""
         from horaedb_tpu.common.size_ext import ReadableSize
-        from horaedb_tpu.serving import RESIDENCY
 
         eng = await open_serving_engine(
             MemStore(),
-            serving=ServingTierConfig(
-                result_cache=ReadableSize.mb(0),
-                residency=ReadableSize.mb(32),
-                residency_admit_after=2,
-            ),
+            serving=ServingTierConfig(result_cache=ReadableSize.mb(0)),
         )
         try:
             await seed_two_sst_segments(eng, hours=1)
             await compact_drain(eng)
             req = QueryRequest(metric=b"cpu", start_ms=0, end_ms=HOUR)
-            res0 = RESIDENCY.labels("resident").value
-            adm0 = RESIDENCY.labels("admitted").value
-            first = await eng.query(req)     # fetch (heat 1)
-            second = await eng.query(req)    # fetch (heat 2) -> admit
-            assert RESIDENCY.labels("admitted").value > adm0
-            with scanstats.scan_stats() as st:
-                third = await eng.query(req)  # served from the pinned tier
-            assert RESIDENCY.labels("resident").value > res0
-            assert st.counts.get("blocks_resident", 0) >= 1
-            assert_same_answer(second, first)
-            assert RESIDENCY_CACHE.resident_bytes > 0
-            # the honesty switch bypasses residency too: the forced-cold
-            # oracle must pay the real store GET + decode, never ride a
-            # pinned block (or it could not catch a residency defect)
-            with scanstats.scan_stats() as st_cold:
-                cold = await forced_cold(eng, req)
-            assert not st_cold.counts.get("blocks_resident")
-            assert not st_cold.counts.get("blocks_fetched")
-            assert_same_answer(third, cold)
+            reader = eng.data_table.parquet_reader
+            assert not reader._blk_cache
+            first = await eng.query(req)
+            cached = dict(reader._blk_cache)
+            assert cached, "the cold scan cached no block"
+            await eng.query(req)
+            third = await eng.query(req)
+            # the repeat scans decoded nothing anew: the same table
+            # objects are still the cache's entries
+            assert set(reader._blk_cache) == set(cached)
+            assert all(reader._blk_cache[k] is t for k, t in cached.items())
+            assert_same_answer(third, first)
+            assert_same_answer(third, await forced_cold(eng, req))
         finally:
             await eng.close()
+
+
+class TestServingConfigKeys:
+    @pytest.mark.parametrize(
+        "key,value", [("residency", "64MiB"), ("residency_admit_after", 2)],
+    )
+    def test_unknown_keys_refused_by_name(self, key, value):
+        """A config file carrying a key the tier does not have is refused
+        at boot, and the error names the key."""
+        from horaedb_tpu.common.error import HoraeError
+
+        with pytest.raises(HoraeError, match=re.escape(repr([key]))):
+            ServingTierConfig.from_dict({"enabled": True, key: value})
 
 
 class TestServingKeyContract:

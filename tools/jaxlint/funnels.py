@@ -73,19 +73,17 @@ DECODE_SHAPED_TAILS = {"cumsum", "unpackbits", "associative_scan", "accumulate"}
 _ENC_NAME_RE = re.compile(r"(^|_)enc(oded)?(_|$)|encoded|^payload$")
 
 # J013: the serving-tier funnel (horaedb_tpu/serving + storage/rollup.py).
-# READ side: cache lookups / rollup planning / residency probes belong at
-# the planner choke point (engine/data.py) and in the tier's own modules
-# (storage/read.py hosts the residency hooks). WRITE side: cache/residency
+# READ side: cache lookups / rollup planning belong at the planner choke
+# point (engine/data.py) and in the tier's own modules. WRITE side: cache
 # mutation belongs to the invalidation funnel — the storage write commit,
 # the compaction commit, the tombstone path (all in storage/storage.py /
-# compaction/executor.py), the manifest's record store, and the reader's
-# eviction hooks.
+# compaction/executor.py) and the manifest's record store. The reader
+# (storage/read.py) is below the tier and calls neither side.
 J013_MODULES = ("horaedb_tpu/",)
 J013_READ_EXEMPT = (
     "horaedb_tpu/serving/",
     "horaedb_tpu/engine/data.py",
     "horaedb_tpu/storage/rollup.py",
-    "horaedb_tpu/storage/read.py",
 )
 J013_WRITE_EXEMPT = (
     "horaedb_tpu/serving/",
@@ -93,18 +91,15 @@ J013_WRITE_EXEMPT = (
     "horaedb_tpu/storage/compaction/executor.py",
     "horaedb_tpu/storage/manifest/",
     "horaedb_tpu/storage/rollup.py",
-    "horaedb_tpu/storage/read.py",
     # the replica's snapshot swap IS its flush/delete commit — the swap
     # routes through serving_invalidate with the mutation's time range
     "horaedb_tpu/cluster/replica.py",
 )
 SERVING_READ_FUNCS = {
     "serving_get", "serving_single_flight", "plan_rollups", "read_rollup",
-    "resident_block",
 }
 SERVING_WRITE_FUNCS = {
-    "serving_put", "serving_invalidate", "note_fetch", "evict_sst",
-    "evict_rollup",
+    "serving_put", "serving_invalidate", "evict_rollup",
 }
 
 # J014: the invalidation funnel's CONSUMER set. serving_subscribe /

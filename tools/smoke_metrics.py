@@ -123,9 +123,6 @@ REQUIRED_FAMILIES = (
     "horaedb_serving_rollups_built_total",
     "horaedb_serving_rollup_substitutions_total",
     "horaedb_serving_rollup_rows_total",
-    "horaedb_serving_resident_bytes",
-    "horaedb_serving_resident_blocks",
-    "horaedb_serving_residency_total",
     # streaming rule engine (horaedb_tpu/rules): families render from
     # boot (zero states pre-registered); the rule flow below moves the
     # eval/tick/transition counters
@@ -178,7 +175,7 @@ REQUIRED_FAMILIES = (
     'horaedb_scan_stage_seconds_bucket{stage="batch_window"',
     # memory observatory (common/memtrace.py + common/bytebudget.py):
     # lineage counters pre-register every (stage, kind) child and the
-    # pool registry pre-registers all five byte-budgeted caches, so
+    # pool registry pre-registers all four byte-budgeted caches, so
     # every family renders the zero state from boot
     "horaedb_mem_bytes_total",
     'horaedb_mem_bytes_total{stage="host_prep",kind="copy"',
@@ -190,7 +187,6 @@ REQUIRED_FAMILIES = (
     'horaedb_pool_bytes{pool="scan"',
     'horaedb_pool_bytes{pool="sidecar"',
     'horaedb_pool_bytes{pool="result"',
-    'horaedb_pool_bytes{pool="residency"',
     'horaedb_pool_bytes{pool="rollup"',
     "horaedb_pool_entries",
     "horaedb_pool_capacity_bytes",
