@@ -7,7 +7,8 @@ scalars so changing a constant does NOT trigger an XLA recompile — only the
 tree *shape* is static.
 
 The same tree drives host-side SST/row-group pruning via min-max statistics
-(`prune_range`), mirroring parquet page pruning in the reference.
+(`prune_range`; `prune_lanes` over all of a footer's row groups at once),
+mirroring parquet page pruning in the reference.
 """
 
 from __future__ import annotations
@@ -521,3 +522,88 @@ def _prune(pred: Predicate, stats: dict[str, tuple]) -> bool:
     if isinstance(pred, Not):
         return True  # can't cheaply invert interval logic; stay conservative
     raise HoraeError(f"unknown predicate node: {pred!r}")
+
+
+# -- the same over many chunks at once ----------------------------------------
+
+def prune_lanes(pred: Predicate, lanes: dict, n: int, scalar) -> np.ndarray:
+    """`prune_range` over `n` chunks in array operations: keep[i] is what
+    `prune_range(pred, <chunk i's stats>)` returns, for every i.
+
+    `lanes[column]` is `(lo, hi, usable)`, three arrays of `n`: a chunk's
+    min and max in the column's own domain (uint64, int64 or float64, so
+    that a u64 id above 2**63 and an int64 timestamp both compare exactly)
+    and whether the chunk has them (one without is kept, as a column
+    missing from `stats` is). A column not in `lanes` has no statistics in
+    any chunk; `lanes[column] is None` says it has some that are not
+    numbers. A leaf on such a column, or whose literal the lane's dtype
+    cannot hold exactly (a negative number against uint64, a fraction
+    against integers, a string), is left to `scalar(node)`: the caller's
+    `prune_range` of that node a chunk, as an array."""
+    if isinstance(pred, (Compare, InSet)):
+        if pred.column not in lanes:
+            return np.ones(n, dtype=bool)
+        lane = lanes[pred.column]
+        if lane is None:
+            return scalar(pred)
+        lo, hi, usable = lane
+        if isinstance(pred, Compare):
+            v = _lane_literal(pred.literal, lo.dtype)
+            if v is None:
+                return scalar(pred)
+            if pred.op == "eq":
+                hit = (lo <= v) & (v <= hi)
+            elif pred.op == "ne":
+                hit = ~((lo == hi) & (hi == v))
+            elif pred.op == "lt":
+                hit = lo < v
+            elif pred.op == "le":
+                hit = lo <= v
+            elif pred.op == "gt":
+                hit = hi > v
+            else:
+                hit = hi >= v
+            return hit | ~usable
+        vals = [_lane_literal(v, lo.dtype) for v in pred.values]
+        if any(v is None for v in vals):
+            return scalar(pred)
+        vals = np.sort(np.array(vals, dtype=lo.dtype))
+        if lo.dtype.kind == "f":
+            vals = vals[~np.isnan(vals)]  # equal to nothing, and sorted last
+        # some value in [lo, hi]: more values <= hi than values < lo (a NaN
+        # bound holds none, and `lo <= hi` is false of it)
+        hit = np.searchsorted(vals, lo, "left") < np.searchsorted(vals, hi, "right")
+        return (hit & (lo <= hi)) | ~usable
+    if isinstance(pred, (InSetProbe, Not)):
+        return np.ones(n, dtype=bool)  # as _prune: stay conservative
+    if isinstance(pred, And):
+        out = np.ones(n, dtype=bool)
+        for c in pred.children:
+            out &= prune_lanes(c, lanes, n, scalar)
+        return out
+    if isinstance(pred, Or):
+        out = np.zeros(n, dtype=bool)
+        for c in pred.children:
+            out |= prune_lanes(c, lanes, n, scalar)
+        return out
+    raise HoraeError(f"unknown predicate node: {pred!r}")
+
+
+def _lane_literal(v, dt: np.dtype):
+    """`v` as a scalar of a lane's dtype, where comparing it there gives
+    what Python gives for `v` against the lane's values as Python numbers
+    (exact, whatever the magnitudes); else None. Plain ints, floats and
+    numpy integers only: a bool, a numpy float or anything else compares
+    by rules of its own, which the scalar form keeps."""
+    if isinstance(v, np.integer):
+        v = int(v)
+    elif type(v) is float and dt.kind != "f" and v.is_integer():
+        v = int(v)
+    if type(v) is int:
+        if dt.kind == "f":
+            return np.float64(v) if abs(v) <= 1 << 53 else None
+        info = np.iinfo(dt)
+        return dt.type(v) if info.min <= v <= info.max else None
+    if type(v) is float and dt.kind == "f":
+        return np.float64(v)
+    return None

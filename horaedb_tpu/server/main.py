@@ -1187,6 +1187,12 @@ def _explain_payload(st, mode: str, admission_verdict: dict | None = None) -> di
             # partial-result provenance: SSTs a degraded store could not
             # serve (the query answered 503; this names what was missing)
             "unavailable": counts.get("ssts_unavailable", 0),
+            # of the reads that pruned row groups by the predicate: served
+            # from the min/max lanes kept with the cached footer, or by a
+            # walk over the footer's metadata objects (the first read of an
+            # SST, or a leaf the lanes cannot decide)
+            "footer_lanes": counts.get("footer_lanes", 0),
+            "footer_walks": counts.get("footer_walks", 0),
         },
         # tombstone provenance (storage/visibility.py): delete records
         # that masked rows in this scan, and how many rows they masked

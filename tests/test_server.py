@@ -595,7 +595,9 @@ class TestExplain:
                 assert set(plan["ssts"]) == {"selected", "read",
                                              "bloom_pruned",
                                              "retention_pruned",
-                                             "unavailable"}
+                                             "unavailable",
+                                             "footer_lanes",
+                                             "footer_walks"}
                 assert isinstance(plan["compile_s"], (int, float))
                 assert isinstance(plan["steady_s"], (int, float))
                 assert plan["regions"] >= 1

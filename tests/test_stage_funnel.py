@@ -16,6 +16,7 @@ import pytest
 from horaedb_tpu.common import tracing, xprof
 from horaedb_tpu.common.xprof import xjit
 from horaedb_tpu.objstore import MemStore
+from horaedb_tpu.ops import filter as F
 from horaedb_tpu.server.metrics import GLOBAL_METRICS
 from horaedb_tpu.storage import (
     ObjectBasedStorage,
@@ -503,10 +504,10 @@ class TestFoldOffTheLoop:
                 TimeRange(10, 10 + files * rows)))
 
     @classmethod
-    def pushdown(cls, eng, ssts, packed_ok: bool = True):
+    def pushdown(cls, eng, ssts, packed_ok: bool = True, predicate=None, **kw):
         return eng.parquet_reader.scan_segment_downsample(
-            ssts, None, "ts", "value", "pk1", np.arange(cls.SERIES), 0, 2000, 8,
-            packed_ok=packed_ok)
+            ssts, predicate, "ts", "value", "pk1", np.arange(cls.SERIES), 0, 2000, 8,
+            packed_ok=packed_ok, **kw)
 
     @pytest.mark.parametrize("heartbeat", ["own", "server"])
     @async_test
@@ -638,14 +639,19 @@ class TestFoldOffTheLoop:
         assert not any(WAITS.search(n) for n in on_workers)
         await eng.close()
 
+    # every row of `write_ssts` passes: the selection prunes nothing
+    EVERY_ROW = F.Compare("ts", "ge", 10)
+
     @pytest.mark.parametrize("store", ["mem", "local"])
     @async_test
     async def test_the_footer_is_walked_once_an_sst_and_never_on_the_loop(
             self, monkeypatch, tmp_path, store):
-        """Cold (the parquet file's own footer) and warm (the cached footer
-        in front of the block cache's probe), over a store that hands out
-        local files and one that hands out bytes: `_select_row_groups`
-        runs once an SST a query, on a worker."""
+        """Over a store that hands out local files and one that hands out
+        bytes: `_select_row_groups` runs once an SST a query, on a worker,
+        cold (the parquet file's own footer) and warm (the cached footer
+        in front of the block cache's probe); the footer's metadata objects
+        are walked by the cold pass alone, which leaves the min/max lanes
+        with the cached footer, and they go with it."""
         from horaedb_tpu.objstore import LocalStore
         from horaedb_tpu.storage import read as read_mod
 
@@ -654,31 +660,80 @@ class TestFoldOffTheLoop:
             LocalStore(str(tmp_path)) if store == "local" else MemStore())
         await self.write_ssts(eng, files=3, rows=600)
         ssts = sorted(eng.manifest.all_ssts(), key=lambda f: f.id)
-        real = read_mod._select_row_groups
-        walks: list[str] = []
+        reader = eng.parquet_reader
+        calls: dict[str, list[str]] = {"select": [], "walk": []}
 
-        def walk(*args):
-            walks.append(threading.current_thread().name)
-            return real(*args)
+        def counted(kind, real):
+            def call(*args):
+                calls[kind].append(threading.current_thread().name)
+                return real(*args)
+            return call
 
-        monkeypatch.setattr(read_mod, "_select_row_groups", walk)
+        monkeypatch.setattr(read_mod, "_select_row_groups",
+                            counted("select", read_mod._select_row_groups))
+        monkeypatch.setattr(read_mod, "_row_group_stats",
+                            counted("walk", read_mod._row_group_stats))
         loop_thread = threading.current_thread().name
-        answers = []
-        for served in ("cold", "warm", "warm"):
-            walks.clear()
+
+        async def one_pass(walked: int, **kw) -> dict:
+            for threads in calls.values():
+                threads.clear()
             with scanstats.scan_stats() as st:
-                answers.append(await self.pushdown(eng, ssts))
-            assert len(walks) == 3 and loop_thread not in walks, (served, walks)
+                grids = await self.pushdown(eng, ssts, predicate=self.EVERY_ROW, **kw)
+            assert len(calls["select"]) == 3 and len(calls["walk"]) == walked, calls
+            assert loop_thread not in calls["select"] + calls["walk"], calls
             assert st.counts["ssts_read"] == 3
+            assert st.counts.get("footer_walks", 0) == walked
+            assert st.counts.get("footer_lanes", 0) == 3 - walked
+            return grids
+
+        answers = [await one_pass(3), await one_pass(0), await one_pass(0)]
+        assert all(reader._meta_cache[s.id].lanes is not None for s in ssts)
+        # the lanes go with the footer, and the next read of that SST walks
+        reader.evict_cached(ssts[0].id)
+        assert ssts[0].id not in reader._meta_cache
+        answers.append(await one_pass(1))
+        # outside the block cache a read walks and keeps nothing
+        kept = {i: f.lanes for i, f in reader._meta_cache.items()}
+        answers.append(await one_pass(3, use_block_cache=False))
+        assert {i: f.lanes for i, f in reader._meta_cache.items()} == kept
         assert answers[0]["count"].sum() == 1800
         for again in answers[1:]:
             for k, g in answers[0].items():
                 np.testing.assert_array_equal(again[k], g)
-        # the raw scan opens its SSTs through the same call
-        walks.clear()
-        table = await collect(eng, ScanRequest(range=TimeRange(0, SEGMENT_MS)))
+        # the raw scan opens its SSTs through the same call; with no
+        # predicate nothing of the footer's metadata is read at all
+        for threads in calls.values():
+            threads.clear()
+        with scanstats.scan_stats() as st:
+            table = await collect(eng, ScanRequest(range=TimeRange(0, SEGMENT_MS)))
         assert table.num_rows == 1800
-        assert len(walks) == 3 and loop_thread not in walks, walks
+        assert len(calls["select"]) == 3 and not calls["walk"], calls
+        assert loop_thread not in calls["select"]
+        assert "footer_walks" not in st.counts and "footer_lanes" not in st.counts
+        await eng.close()
+
+    @async_test
+    async def test_footer_prunes_are_counted_by_what_served_them(self):
+        """horaedb_scan_footer_prunes_total{served}: a cold pushdown over
+        three SSTs walks three footers, two warm ones prune from their
+        lanes; the query's own collector shows the same."""
+        eng = await self.engine("funnel/fold-footer-counter")
+        await self.write_ssts(eng, files=3, rows=600)
+        ssts = eng.manifest.all_ssts()
+        family = "horaedb_scan_footer_prunes_total"
+        before = {k: counter(family, served=k) for k in ("walk", "lanes")}
+        per_query = []
+        for _ in range(3):
+            with scanstats.scan_stats() as st:
+                await self.pushdown(eng, ssts, predicate=self.EVERY_ROW)
+            per_query.append((st.counts.get("footer_walks", 0), st.counts.get("footer_lanes", 0)))
+        assert per_query == [(3, 0), (0, 3), (0, 3)]
+        moved = {k: counter(family, served=k) - before[k] for k in before}
+        assert moved == {"walk": 3, "lanes": 6}
+        # without a predicate nothing is pruned and nothing counted
+        await self.pushdown(eng, ssts)
+        assert {k: counter(family, served=k) - before[k] for k in before} == moved
         await eng.close()
 
 
