@@ -239,6 +239,17 @@ FOLD_ROWS_TOTAL = GLOBAL_METRICS.counter(
 for _kind in ("real", "padded"):
     FOLD_ROWS_TOTAL.labels(_kind)
 del _kind
+PACK_TOTAL = GLOBAL_METRICS.counter(
+    "horaedb_pushdown_pack_total",
+    help="Packed passes of the aggregate pushdown (a segment's surviving "
+         "rows put in (series, ts) order before its fold), by the work they "
+         "took: in_order (the rows arrived in order), sorted (one sort, no "
+         "(series, ts) repeated) or dedup (repeats, settled by __seq__).",
+    labelnames=("order",),
+)
+for _order in ("in_order", "sorted", "dedup"):
+    PACK_TOTAL.labels(_order)
+del _order
 
 
 # the largest compiled row class a smaller fold may ride instead of

@@ -1157,7 +1157,9 @@ def _explain_payload(st, mode: str, admission_verdict: dict | None = None) -> di
     }
     # the pushdown's folds (ops/aggregate.py fold_sorted): how many, the
     # classes their programs ran as ("<rows>x<series>x<buckets>", rows 0 =
-    # the host lane) and the rows they took in against the rows of padding
+    # the host lane) and the rows they took in against the rows of padding;
+    # `pack_order` counts the packed passes before them by the work each
+    # took (storage/read.py _packed_downsample_pass)
     fold_verdict = {
         "folds": counts.get("folds", 0),
         "classes": sorted(
@@ -1165,6 +1167,8 @@ def _explain_payload(st, mode: str, admission_verdict: dict | None = None) -> di
         ),
         "rows_real": counts.get("fold_rows_real", 0),
         "rows_padded": counts.get("fold_rows_padded", 0),
+        "pack_order": {o: counts.get("pack_" + o, 0)
+                       for o in ("in_order", "sorted", "dedup")},
     }
     compile_s = st.seconds.get("compile", 0.0)
     total_s = sum(att["lanes_s"].values())

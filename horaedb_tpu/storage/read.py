@@ -2330,8 +2330,9 @@ class ParquetReader:
         the metric-engine data table).
 
         The routes, by what the caller and the backend allow:
-        - `packed_ok` (the metric engine): the HOST packs (sid, ts, seq-rank)
-          into one key, sorts and dedups (`_packed_downsample_pass`), and
+        - `packed_ok` (the metric engine): the HOST orders the rows it keeps
+          by one (sid, ts) key, sorting and dedupping only where they need
+          it (`_packed_downsample_pass`), and
           the surviving rows are ONE fold (`ops/aggregate.py fold_sorted`:
           the padded `downsample_fold` program, or the host reduceat lane);
         - otherwise the fused device pass sorts and dedups; on a backend
@@ -2520,28 +2521,35 @@ class ParquetReader:
             grids["min"] = np.minimum(grids["min"], np.asarray(out["min"]))
             grids["max"] = np.maximum(grids["max"], np.asarray(out["max"]))
 
-    # packed-key sort budget: sid | ts-offset | seq-rank must fit below the
-    # sink bit (63). Exceeding any budget falls back to the fused lexsort.
+    # packed-key budget: sid | ts-offset | seq-rank must fit in 63 bits.
+    # Exceeding any budget falls back to the fused lexsort.
     _PACK_SID_BITS = 17
     _PACK_TS_BITS = 34   # ~198 days of ms offsets within one scan
-    _PACK_SEQ_BITS = 12  # distinct write sequences per segment
+    _PACK_SEQ_BITS = 12  # distinct write sequences among a segment's duplicates
 
     def _packed_downsample_pass(
         self, table, predicate, sid, sid_valid, ts_column, value_column, num_series
     ):
         """Single-key replacement for the fused kernel's 6-lane lexsort on
-        the downsample pushdown path: (dense sid, ts, seq-rank) pack into
-        one u64, the predicate evaluates on host, rejected rows sink above
-        bit 63, and one stable integer argsort (radix on host) yields the
-        merge permutation — ~10x cheaper than the multi-key device lexsort
-        at this path's fixed shape. Dedup stays filter-first/last-value:
-        among surviving rows of one (sid, ts) cell the max seq-rank (the
-        sort's last) wins, matching the fused kernel's semantics.
+        the downsample pushdown path. The predicate evaluates on the host
+        and only the rows it and the series set keep are ordered, by one
+        u64 key (dense sid, ts offset). The pass does as much as the rows
+        ask for, and counts which (`horaedb_pushdown_pack_total{order}`):
+        - `in_order`: the keys already rise strictly (a compacted segment:
+          sorted, deduplicated SSTs concatenated in first-key order), so the
+          survivors are the answer as they lie;
+        - `sorted`: one stable argsort (timsort: close to linear over a
+          segment's few per-SST runs) and no two keys equal;
+        - `dedup`: some (sid, ts) repeats, and only then is `__seq__` ranked
+          and packed under the key: among one cell's survivors the newest
+          write wins, the last in concatenation order among equal seqs,
+          matching the fused kernel's filter-first/last-value semantics.
 
         Returns (ts, sid, values) as pk-sorted, deduped, fully-valid host
         lanes for accumulate_sorted, or None when the shape exceeds the
-        pack budgets (huge spans, >2^12 distinct seqs, append mode) — the
-        caller then runs the general fused pass.
+        pack budgets (huge spans, append mode, more than 2^12 distinct seqs
+        among survivors that need dedup) — the caller then runs the general
+        fused pass.
 
         CONTRACT (why scan_segment_downsample gates this on `packed_ok`):
         dedup here is by (sid, ts), NOT the full schema pk. The caller must
@@ -2558,55 +2566,83 @@ class ParquetReader:
         ts_np = arrow_column_to_numpy(
             memtrace.tracked_combine(table.column(ts_column), "host_prep")
         )
-        n = len(ts_np)
-        if n == 0:
+        if len(ts_np) == 0:
+            self._count_pack("in_order")
             return (np.empty(0, np.int64),) * 3
-        seq_np = arrow_column_to_numpy(
-            memtrace.tracked_combine(
-                table.column(SEQ_COLUMN_NAME), "host_prep"
-            )
-        )
-        uniq_seq = np.unique(seq_np)
-        if len(uniq_seq) > (1 << self._PACK_SEQ_BITS):
-            return None
         ts_min = int(ts_np.min())
         span = int(ts_np.max()) - ts_min
         if span >= (1 << self._PACK_TS_BITS):
             return None
-        mask = memtrace.tracked_copy(sid_valid, "host_prep")
+        mask = sid_valid
         if predicate is not None:
-            mask = mask & filter_ops.eval_predicate_host(predicate, table)
-        srank = (
-            np.searchsorted(uniq_seq, seq_np).astype(np.uint64)
-            if len(uniq_seq) > 1 else np.zeros(n, np.uint64)
+            mask = mask & self._predicate_mask(predicate, table, {ts_column: ts_np})
+        idx = np.flatnonzero(mask)
+        ts_k = ts_np[idx]
+        sid_k = sid[idx]
+        key = (
+            (sid_k.astype(np.uint64) << np.uint64(self._PACK_TS_BITS))
+            | (ts_k - ts_min).astype(np.uint64)
         )
-        shift_ts = np.uint64(self._PACK_SEQ_BITS)
-        shift_sid = np.uint64(self._PACK_SEQ_BITS + self._PACK_TS_BITS)
-        packed = (
-            (sid.astype(np.int64).astype(np.uint64) << shift_sid)
-            | ((ts_np - ts_min).astype(np.uint64) << shift_ts)
-            | srank
-        )
-        sink = np.uint64(1 << 63)
-        packed = np.where(mask, packed, sink)
-        perm = np.argsort(packed, kind="stable")
-        packed_s = packed[perm]
-        # keep-last within each (sid, ts) group among surviving rows
-        group = packed_s >> shift_ts
-        keep = np.empty(n, dtype=bool)
-        if n > 1:
-            keep[:-1] = group[:-1] != group[1:]
-        keep[-1] = True
-        keep &= packed_s < sink
-        idx = perm[keep]
+        order = "in_order"
+        if len(key) > 1 and not (key[1:] > key[:-1]).all():
+            perm = np.argsort(key, kind="stable")
+            key = key[perm]
+            order = "sorted"
+            if (key[1:] == key[:-1]).any():
+                order = "dedup"
+                perm = self._newest_of_each_key(table, idx, perm, key)
+                if perm is None:
+                    return None
+            idx, ts_k, sid_k = idx[perm], ts_k[perm], sid_k[perm]
+        self._count_pack(order)
         val_np = arrow_column_to_numpy(
             memtrace.tracked_combine(table.column(value_column), "host_prep")
         )
-        return (
-            ts_np[idx],
-            sid[idx].astype(np.int32),
-            val_np[idx],
-        )
+        return ts_k, sid_k.astype(np.int32, copy=False), val_np[idx]
+
+    def _newest_of_each_key(self, table, idx, perm, key_s):
+        """The positions in `perm` (the survivors `idx` stably sorted by
+        their (sid, ts) key, `key_s` the sorted keys) of the newest write of
+        each key: the largest `__seq__`, and the last in concatenation
+        order among equal ones. None past the seq-rank budget."""
+        seq = arrow_column_to_numpy(
+            memtrace.tracked_combine(table.column(SEQ_COLUMN_NAME), "host_prep")
+        )[idx[perm]]
+        uniq_seq = np.unique(seq)
+        if len(uniq_seq) > (1 << self._PACK_SEQ_BITS):
+            return None
+        shift = np.uint64(self._PACK_SEQ_BITS)
+        ranked = (key_s << shift) | np.searchsorted(uniq_seq, seq).astype(np.uint64)
+        # stable over an order that is already the concatenation order
+        # within each key, so equal seqs keep it; it moves rows only within
+        # a key, so `key_s` still marks where each key's run ends
+        sub = np.argsort(ranked, kind="stable")
+        last = np.empty(len(sub), dtype=bool)
+        last[:-1] = key_s[:-1] != key_s[1:]
+        last[-1] = True
+        return perm[sub[last]]
+
+    @staticmethod
+    def _predicate_mask(predicate, table, lanes: dict) -> np.ndarray:
+        """The predicate over a decoded table as the merge route evaluates
+        it (`_merge_table`): numpy over the column lanes (`lanes` holds
+        those already converted) where every column it reads is numeric,
+        arrow compute where one is binary. A numpy leaf is one array
+        operation where arrow's is four calls, and each call lets go of
+        the GIL and takes it back: on a worker that shares it with three
+        others and the loop, each take can wait."""
+        cols = filter_ops.pred_columns(predicate)
+        if any(_is_binary_like(table.schema.field(c).type) for c in cols):
+            return filter_ops.eval_predicate_host(predicate, table)
+        for c in cols - lanes.keys():
+            lanes[c] = arrow_column_to_numpy(
+                memtrace.tracked_combine(table.column(c), "host_prep"))
+        return filter_ops.eval_predicate_np(predicate, lanes)
+
+    @staticmethod
+    def _count_pack(order: str) -> None:
+        agg_ops.PACK_TOTAL.labels(order).inc()
+        scanstats.note("pack_" + order)
 
     @staticmethod
     def _sharded_accumulate(

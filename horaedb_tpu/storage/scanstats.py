@@ -107,7 +107,8 @@ STAGE_SECONDS = GLOBAL_METRICS.histogram(
 # `fold_d2h` (the grids back, sliced and decoded); `fold_host` is the whole
 # fold where the host lane (reduceat) serves it. `pack_sort` is what comes
 # before them on the packed route (`_packed_downsample_pass`: the host
-# predicate, the key packing, one stable argsort, the gathers).
+# predicate, the kept rows' key, a sort only where they are out of order,
+# the gathers).
 FOLD_STAGES = ("fold_prep", "fold_h2d", "fold_kernel", "fold_d2h", "fold_host")
 
 for _lane in ("io_decode", "host_prep", "transfer", "kernel", "compile",

@@ -394,10 +394,14 @@ class TestFoldsAtOnce:
         monkeypatch.setattr(ParquetReader, "_packed_downsample_pass", sort_past_the_budget)
         monkeypatch.setattr(agg_ops, "fold_sorted", lambda *a, **k: folds.append(1))
         try:
-            with deadline_ctx.deadline_scope(deadline_ctx.Deadline(1.0, clock=lambda: now[0])):
+            with deadline_ctx.deadline_scope(deadline_ctx.Deadline(1.0, clock=lambda: now[0])), \
+                    scanstats.scan_stats() as st:
                 with pytest.raises(DeadlineExceeded) as err:
                     await self.pushdown(eng, ssts[:3], None, True)
             assert err.value.at == "device_lane" and not folds
+            # the three SSTs rewrite each other's cells: the pass that ran
+            # past the budget is the full one, its dedup step included
+            assert st.counts.get("pack_dedup") == 1
         finally:
             await eng.close()
 

@@ -367,6 +367,9 @@ async def test_served_tsbs_query_equals_the_reference(tmp_path, monkeypatch, sha
             explain = body["explain"]
             assert explain["agg_impls"] == [impl]
             assert explain["fold"]["folds"] >= 1 and explain["fold"]["rows_real"] > 0
+            # one packed pass a fold, and the loaded fleet rewrites no sample
+            pack = explain["fold"]["pack_order"]
+            assert sum(pack.values()) == explain["fold"]["folds"] and pack["dedup"] == 0
             assert all(c.split("x")[0] != "0" for c in explain["fold"]["classes"])
             assert "fold_kernel" in explain["stages_s"]
     finally:
