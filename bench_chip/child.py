@@ -7,6 +7,9 @@ that answers two things only the process holding the chip can: the
 device's memory statistics, and a jax.profiler trace on request. The
 parent (this process) never imports JAX. Taken from chip_smoke.py's
 Server; connections are per caller so that several workers can send.
+The child leads a session of its own: a stop signals the whole session,
+the server and whatever it started, and the child ends itself if this
+process dies first (serve_traced.py watches for that).
 """
 
 from __future__ import annotations
@@ -78,6 +81,7 @@ class Server:
     def __init__(self, out_dir: str, budget_s: float):
         self.out_dir = out_dir
         self.proc: subprocess.Popen | None = None
+        self._swept = False  # the child's session has had its last SIGKILL
         self.port = 0
         self._admin: Conn | None = None
         self._log = None
@@ -131,10 +135,13 @@ class Server:
         self._log = open(os.path.join(self.out_dir, "server.log"), "ab")
         env = dict(os.environ)  # JAX_COMPILATION_CACHE_DIR passes unchanged
         env["BENCH_CHIP_CONTROL"] = self._fifo
+        env["BENCH_CHIP_PARENT"] = str(os.getpid())
         self.proc = subprocess.Popen(
             [sys.executable, os.path.join(HERE, "trace", "serve_traced.py"),
              "--config", cfg],
-            cwd=ROOT, stdout=self._log, stderr=subprocess.STDOUT, env=env)
+            cwd=ROOT, stdout=self._log, stderr=subprocess.STDOUT, env=env,
+            start_new_session=True)
+        self._swept = False
         self._admin = Conn(self.port, 60.0)
         while True:
             self.alive()
@@ -152,11 +159,19 @@ class Server:
             raise Failed(f"the server died (exit code {rc}); see "
                          f"{os.path.join(self.out_dir, 'server.log')}")
 
+    def signal_session(self, sig: int) -> None:
+        """`sig` to the child's session: the server and whatever it started."""
+        try:
+            os.killpg(self.proc.pid, sig)
+        except ProcessLookupError:
+            pass  # none of them is left
+
     def kill(self) -> None:
         """SIGKILL: no shutdown hook runs, nothing buffered is flushed."""
         self._admin.close()
-        self.proc.send_signal(signal.SIGKILL)
+        self.signal_session(signal.SIGKILL)
         self.proc.wait()
+        self._swept = True
         self._log.close()
 
     def stop(self) -> int | None:
@@ -165,12 +180,15 @@ class Server:
         if self.proc is None:
             return None
         if self.proc.poll() is None:
-            self.proc.send_signal(signal.SIGTERM)
+            self.signal_session(signal.SIGTERM)
             try:
                 self.proc.wait(30)
             except subprocess.TimeoutExpired:
-                self.proc.kill()
+                self.signal_session(signal.SIGKILL)
                 self.proc.wait()
+        if not self._swept:  # what the server started and left behind; once, before its id is reused
+            self.signal_session(signal.SIGKILL)
+            self._swept = True
         if self._log is not None and not self._log.closed:
             self._log.close()
         return self.proc.returncode

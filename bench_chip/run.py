@@ -5,11 +5,25 @@
 
 The parent (this process) never imports JAX. Its one child is the normal
 server on the cell's chip, docs/example.toml as shipped apart from port
-and data directory. A run: build the C++ parser, start the child, make the
-fleet from --seed, go through the mix's set-up phases (load, settle,
-warm), measure for --seconds, read /metrics, /debug/kernels and
-/debug/traces, decide `correct`, stop the child, print one JSON line last.
-A platform other than `tpu` fails the run after every phase has passed.
+and data directory, in a session of its own that every stop signals whole.
+A run: build the C++ parser, start the child, make the fleet from --seed,
+go through the mix's set-up phases (load, settle, warm), measure for
+--seconds, read /metrics, /debug/kernels and /debug/traces, decide
+`correct`, stop the child, print one JSON line last. A platform other
+than `tpu`, or a thread of the run still alive at its end, fails the run
+after every phase has passed.
+
+A window may hold writes (a path under /api/v1/write) and queries, from
+closed loops, an open loop at a fixed rate (loop.open_loop; a request's
+latency runs from when it was due, `<class>_late_ms` says how late the
+sends fell), or both under one barrier (loop.run_loops). The summary
+gives `write_*` and `query_*` for each class the window holds (p50_ms,
+p95_ms, max_ms, rate, unit_rate, turnaround_ms); the unprefixed fields
+are over the class the traffic file's `"timed": "write" | "query"` names,
+or over every request where it names none, which a window of both
+classes may not do. A traced run reads up to SPAN_SAMPLE span trees for
+each root span name in the whole trace ring, so that a class that
+answers less often still has its trees.
 
 Everything that belongs to one cell, configuration, mix or metric is a
 file found by the name BENCHMARK.json gives; nothing here names one.
@@ -22,10 +36,12 @@ import importlib
 import json
 import os
 import shutil
+import signal
 import subprocess
 import sys
 import threading
 import time
+from collections import Counter
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -41,7 +57,8 @@ BUDGET_S = 1150.0
 # one compaction cycle (about 14.5 s), so that a traced span holds a chain of merges and not
 # only the quiet phase between two
 TRACE_SECONDS = 14.0
-SPAN_SAMPLE = 48          # span trees read back after a traced window
+SPAN_SAMPLE = 48          # span trees read back after a traced window, for each root name
+ROOT_SPANS = ("GET /api/v1/query", "POST /api/v1/write", "POST /api/v1/query")
 
 
 def log(phase: str, **fields) -> None:
@@ -108,16 +125,20 @@ def traced_window(server: Server, win_started: threading.Event, seconds: float,
 
 
 def span_trees(server: Server) -> list[dict]:
-    listed = server.get_json("/debug/traces", limit=256)["traces"]
+    """Up to SPAN_SAMPLE trees, newest first, for each root span name of
+    the window's requests, from a listing of the whole ring."""
+    listed = server.get_json("/debug/traces", limit=0)["traces"]
+    kept: Counter = Counter()
     out = []
     for t in listed:
-        if len(out) >= SPAN_SAMPLE:
-            break
-        if t["name"].startswith(("GET /api/v1/query", "POST /api/v1/write", "POST /api/v1/query")):
-            try:
-                out.append(server.get_json(f"/debug/traces/{t['trace_id']}"))
-            except Failed:
-                continue  # evicted from the ring between the two reads
+        name = t["name"]
+        if not name.startswith(ROOT_SPANS) or kept[name] >= SPAN_SAMPLE:
+            continue
+        try:
+            out.append(server.get_json(f"/debug/traces/{t['trace_id']}"))
+        except Failed:
+            continue  # evicted from the ring between the two reads
+        kept[name] += 1
     return out
 
 
@@ -212,7 +233,7 @@ def run(args, server: Server, out_dir: str) -> tuple[dict, int]:
         tracer.join()
         require("error" not in traced, f"tracing failed: {traced.get('error')}")
     metrics1, kernels1 = server.metrics(), server.get_json("/debug/kernels")
-    summary = loop.summary(win)
+    summary = loop.summary(win, traffic.get("timed"))
     summary["setup_s"] = setup_s
     log("window", **summary)
     trees = span_trees(server) if args.trace else []
@@ -318,14 +339,21 @@ def run_cell(argv: list[str]) -> tuple[dict | None, str]:
     out_dir = args.out or os.path.join(ROOT, ".bench_chip", args.workload)
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
+    before = set(threading.enumerate())
     server = Server(out_dir, BUDGET_S)
+    result, why = None, ""
     try:
         result, chips = run(args, server, out_dir)
     except Exception as e:  # noqa: BLE001 — every failure ends the run with no result
-        return None, f"{type(e).__name__}: {e}"
+        why = f"{type(e).__name__}: {e}"
     finally:
         server.stop()
         shutil.rmtree(os.path.join(out_dir, "data"), ignore_errors=True)
+    left = stray_threads(before)
+    if left:
+        return None, "; ".join(filter(None, [why, f"thread(s) of the run still alive at its end: {left}"]))
+    if why:
+        return None, why
     device = result["device"]
     if device["platform"] != "tpu" or device["count"] < chips:
         return result, (f"the server ran on {device['count']} device(s) of platform "
@@ -333,16 +361,34 @@ def run_cell(argv: list[str]) -> tuple[dict | None, str]:
     return result, ""
 
 
+def stray_threads(before=()) -> list[str]:
+    """Non-daemon threads other than the main one, not among `before`, still alive."""
+    return [t.name for t in threading.enumerate()
+            if t is not threading.main_thread() and t not in before and not t.daemon and t.is_alive()]
+
+
+def terminated(signum, frame) -> None:
+    """SIGTERM ends the run as an error does: the child is stopped on the way out."""
+    raise SystemExit(128 + signum)
+
+
 def main() -> int:
+    signal.signal(signal.SIGTERM, terminated)
     result, why = run_cell(sys.argv[1:])
     if "jax" in sys.modules:  # the parent must never hold the chip
         why = "the parent imported JAX"
+    rc = 0
     if why:
         held = f"; every phase passed, the result is withheld: {json.dumps(result)}" if result else ""
         print(f"bench_chip: no result: {why}{held}", file=sys.stderr)
-        return 3
-    print(json.dumps(result))
-    return 0
+        rc = 3
+    else:
+        print(json.dumps(result))
+    if stray_threads():  # the interpreter would wait for them at exit: a hang is worse
+        sys.stdout.flush()
+        sys.stderr.flush()
+        os._exit(rc)
+    return rc
 
 
 if __name__ == "__main__":

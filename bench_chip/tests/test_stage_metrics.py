@@ -43,8 +43,10 @@ def test_idle_named_on_the_recorded_reduction():
 def test_the_benchmark_lists_each_new_metric_last_and_with_a_file():
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         bench = json.load(f)
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == NEW
-    for m in bench["per_layer"][-len(NEW):]:
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    # by name: later PRs append entries of their own after these
+    assert [m["name"] for m in bench["per_layer"] if m["name"] in NEW] == NEW
+    for m in (per_layer[name] for name in NEW):
         assert m["workloads"] == ["rwfleet.catchup"]
         assert spec(m["name"])["reader"] in ("prom_delta", "idle_named")
         assert m["source"] == ("device_trace" if m["name"].startswith("device.") else "program_counter")

@@ -9,12 +9,16 @@ one side thread that blocks on a FIFO (BENCH_CHIP_CONTROL) and answers
 
 each with one JSON file written whole (rename). The program has no such
 hook; this wrapper is the benchmark's and goes when the server owns one.
+A second side thread ends this process's session, the server and what
+it started, once the process BENCH_CHIP_PARENT names is no longer its
+parent: a benchmark that dies leaves no server behind.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import signal
 import sys
 import threading
 import time
@@ -66,7 +70,17 @@ def _serve(fifo: str) -> None:
             os.replace(reply + ".tmp", reply)
 
 
+def _orphaned(parent: int) -> None:
+    while os.getppid() == parent:
+        time.sleep(1.0)
+    os.killpg(os.getpgrp(), signal.SIGKILL)
+
+
 if __name__ == "__main__":
+    parent = os.environ.get("BENCH_CHIP_PARENT")
+    if parent:
+        threading.Thread(target=_orphaned, args=(int(parent),), daemon=True,
+                         name="bench-chip-orphaned").start()
     control = os.environ.get("BENCH_CHIP_CONTROL")
     if control:
         threading.Thread(target=_serve, args=(control,), daemon=True,
